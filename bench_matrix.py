@@ -10,7 +10,8 @@ Cells:
                        (vs_tf_cpu.png — the reference's DL-family benchmark)
 
 Each cell prints one JSON line {"metric", "value", "unit", "vs_baseline"} and
-the full matrix is written to BENCH_MATRIX.json with device info.
+the full matrix is written to BENCH_MATRIX.json with device info.  Runs on a
+TPU or not at all (exit code non-zero, nothing measured).
 
 Usage: python bench_matrix.py [--quick] [--only fm|ffm|nn]
   --quick: 1/10th epochs/steps (CI smoke; vs_baseline scaled accordingly).
@@ -21,15 +22,11 @@ import json
 import sys
 import time
 
-from lightctr_tpu.utils.devicecheck import ensure_live_backend
+import jax
+import jax.numpy as jnp
+import numpy as np
 
-ensure_live_backend()
-
-import jax  # noqa: E402
-import jax.numpy as jnp  # noqa: E402
-import numpy as np  # noqa: E402
-
-from lightctr_tpu.data.synth import (  # noqa: E402
+from lightctr_tpu.data.synth import (
     REFERENCE_DENSE,
     REFERENCE_SPARSE,
     resolve_dense_csv,
@@ -72,19 +69,6 @@ def _best_of(fn, reps=3):
     return best
 
 
-def _native_cpu_trainers():
-    """(fm_native, ffm_native) when the host-fallback kernels apply (CPU
-    platform + native lib builds), else (None, None) — one probe shared by
-    the FM and FFM cells."""
-    if jax.devices()[0].platform != "cpu":
-        return None, None
-    from lightctr_tpu.native import bindings
-
-    if not bindings.available():
-        return None, None
-    return bindings.fm_train_fullbatch_native, bindings.ffm_train_fullbatch_native
-
-
 def bench_fm(epochs):
     from lightctr_tpu import TrainConfig
     from lightctr_tpu.data import load_libffm
@@ -97,49 +81,26 @@ def bench_fm(epochs):
     n_rows = len(arrays["labels"])
     cfg = TrainConfig(learning_rate=0.1, lambda_l2=0.001)
 
-    fm_train_fullbatch_native, _ = _native_cpu_trainers()
-    use_native = fm_train_fullbatch_native is not None
-    if not use_native:
-        dense = fm.densify(arrays, ds.feature_cnt)
-        dense = {k: jax.device_put(jnp.asarray(v)) for k, v in dense.items()}
-        jax.block_until_ready(dense)
+    dense = fm.densify(arrays, ds.feature_cnt)
+    dense = {k: jax.device_put(jnp.asarray(v)) for k, v in dense.items()}
+    jax.block_until_ready(dense)
 
     out = []
     for k in (8, 16, 32, 64):
         params = fm.init(jax.random.PRNGKey(0), ds.feature_cnt, k)
-        if use_native:
-            # host fallback: the native CSR kernel (parity-tested trajectory)
-            w0 = np.asarray(params["w"], np.float32)
-            v0 = np.asarray(params["v"], np.float32)
-            fm_train_fullbatch_native(
-                arrays, ds.feature_cnt, k, max(epochs // 20, 1),
-                cfg.learning_rate, cfg.lambda_l2, w0.copy(), v0.copy(),
-            )
+        tr = CTRTrainer(
+            params, fm.dense_logits, cfg, fused_fn=fm.dense_logits_with_l2
+        )
+        tr.warmup_fullbatch_scan(dense, epochs)
 
-            def one():
-                w, v = w0.copy(), v0.copy()
-                t0 = time.perf_counter()
-                losses = fm_train_fullbatch_native(
-                    arrays, ds.feature_cnt, k, epochs,
-                    cfg.learning_rate, cfg.lambda_l2, w, v,
-                )
-                dt = time.perf_counter() - t0
-                assert losses[-1] < losses[0], "diverged"
-                return dt
-        else:
-            tr = CTRTrainer(
-                params, fm.dense_logits, cfg, fused_fn=fm.dense_logits_with_l2
-            )
-            tr.warmup_fullbatch_scan(dense, epochs)
-
-            def one():
-                tr.reset(params)
-                t0 = time.perf_counter()
-                losses = tr.fit_fullbatch_scan(dense, epochs)
-                jax.block_until_ready(tr.params)
-                dt = time.perf_counter() - t0
-                assert losses[-1] < losses[0], "diverged"
-                return dt
+        def one():
+            tr.reset(params)
+            t0 = time.perf_counter()
+            losses = tr.fit_fullbatch_scan(dense, epochs)
+            jax.block_until_ready(tr.params)
+            dt = time.perf_counter() - t0
+            assert losses[-1] < losses[0], "diverged"
+            return dt
 
         dt = _best_of(one)
         ex_s = epochs * n_rows / dt
@@ -166,48 +127,26 @@ def bench_ffm(epochs):
     n_rows = len(arrays["labels"])
     cfg = TrainConfig(learning_rate=0.1, lambda_l2=0.001)
 
-    _, ffm_train_fullbatch_native = _native_cpu_trainers()
-    use_native = ffm_train_fullbatch_native is not None
-    if not use_native:
-        dense, perm, slices = ffm.densify(arrays, ds.feature_cnt, ds.field_cnt)
-        dense = {k: jax.device_put(jnp.asarray(v)) for k, v in dense.items()}
-        jax.block_until_ready(dense)
-        fused = ffm.make_dense_logits(slices)
+    dense, perm, slices = ffm.densify(arrays, ds.feature_cnt, ds.field_cnt)
+    dense = {k: jax.device_put(jnp.asarray(v)) for k, v in dense.items()}
+    jax.block_until_ready(dense)
+    fused = ffm.make_dense_logits(slices)
 
     out = []
     for k in (2, 4, 8, 16):
         p0 = ffm.init(jax.random.PRNGKey(0), ds.feature_cnt, ds.field_cnt, k)
-        if use_native:
-            w0 = np.asarray(p0["w"], np.float32)
-            v0 = np.asarray(p0["v"], np.float32)
-            ffm_train_fullbatch_native(
-                arrays, ds.feature_cnt, ds.field_cnt, k, max(epochs // 20, 1),
-                cfg.learning_rate, cfg.lambda_l2, w0.copy(), v0.copy(),
-            )
+        params = {"w": p0["w"][perm], "v": p0["v"][perm]}
+        tr = CTRTrainer(params, lambda p, b: fused(p, b)[0], cfg, fused_fn=fused)
+        tr.warmup_fullbatch_scan(dense, epochs)
 
-            def one():
-                w, v = w0.copy(), v0.copy()
-                t0 = time.perf_counter()
-                losses = ffm_train_fullbatch_native(
-                    arrays, ds.feature_cnt, ds.field_cnt, k, epochs,
-                    cfg.learning_rate, cfg.lambda_l2, w, v,
-                )
-                dt = time.perf_counter() - t0
-                assert losses[-1] < losses[0], "diverged"
-                return dt
-        else:
-            params = {"w": p0["w"][perm], "v": p0["v"][perm]}
-            tr = CTRTrainer(params, lambda p, b: fused(p, b)[0], cfg, fused_fn=fused)
-            tr.warmup_fullbatch_scan(dense, epochs)
-
-            def one():
-                tr.reset(params)
-                t0 = time.perf_counter()
-                losses = tr.fit_fullbatch_scan(dense, epochs)
-                jax.block_until_ready(tr.params)
-                dt = time.perf_counter() - t0
-                assert losses[-1] < losses[0], "diverged"
-                return dt
+        def one():
+            tr.reset(params)
+            t0 = time.perf_counter()
+            losses = tr.fit_fullbatch_scan(dense, epochs)
+            jax.block_until_ready(tr.params)
+            dt = time.perf_counter() - t0
+            assert losses[-1] < losses[0], "diverged"
+            return dt
 
         dt = _best_of(one)
         ex_s = epochs * n_rows / dt
@@ -238,11 +177,6 @@ def bench_nn(steps):
     rng = np.random.default_rng(1)
     cfg = TrainConfig(learning_rate=0.1, minibatch_size=50)
 
-    # XLA CPU's scan re-materializes loop state each iteration (~3x the
-    # dispatched step cost at LeNet sizes); the host dispatch loop is the
-    # right driver there, the on-device scan everywhere else
-    on_cpu = jax.devices()[0].platform == "cpu"
-
     out = []
     for batch in (50, 100, 200, 400):
         params = cnn.init(jax.random.PRNGKey(0), hidden=100, n_classes=10)
@@ -251,19 +185,12 @@ def bench_nn(steps):
             rng.integers(0, len(ds.features), size=(steps, batch)).astype(np.int32)
         ))
         jax.block_until_ready(idx)
-        if on_cpu:
-            # warm the gather-step compile
-            tr.fit_steps_loop(feats, labels, 1, batch, idx=idx[:1])
-        else:
-            tr.warmup_steps_scan(feats, labels, steps, batch)
+        tr.warmup_steps_scan(feats, labels, steps, batch)
 
         def one():
             tr.reset(params)
             t0 = time.perf_counter()
-            if on_cpu:
-                losses = tr.fit_steps_loop(feats, labels, steps, batch, idx=idx)
-            else:
-                losses = tr.fit_steps_scan(feats, labels, steps, batch, idx=idx)
+            losses = tr.fit_steps_scan(feats, labels, steps, batch, idx=idx)
             jax.block_until_ready(tr.params)
             dt = time.perf_counter() - t0
             assert np.isfinite(losses[-1]), "diverged"
@@ -299,6 +226,12 @@ def main():
     )
     scale = 10 if args.quick else 1
 
+    from lightctr_tpu.utils.compile_cache import configure_compile_cache
+    from lightctr_tpu.utils.devicecheck import require_tpu
+
+    device = require_tpu("bench_matrix")
+    configure_compile_cache()
+
     results = []
     if args.only in (None, "fm"):
         results += bench_fm(1000 // scale)
@@ -308,17 +241,11 @@ def main():
         results += bench_nn(5000 // scale)
 
     payload = {
-        "device": str(jax.devices()[0]),
+        "device": {"platform": device.platform, "kind": device.device_kind,
+                   "count": len(jax.devices())},
         "quick": args.quick,
         "results": results,
     }
-    if jax.devices()[0].platform == "cpu":
-        payload["note"] = (
-            "FM/FFM cells: native CSR kernels; NN cells: XLA CPU with the "
-            "host dispatch-loop driver (lax.scan on XLA CPU re-materializes "
-            "loop state, ~3x the dispatched step cost). All cells one host "
-            "core."
-        )
     with open(out_path, "w") as f:
         json.dump(payload, f, indent=2)
     print(f"wrote {out_path} ({len(results)} cells)", file=sys.stderr)

@@ -28,10 +28,9 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.flatten_util import ravel_pytree
 from jax.sharding import Mesh, PartitionSpec as P
-
-from lightctr_tpu.core.compat import shard_map
 
 
 def _ring_perm(n: int):

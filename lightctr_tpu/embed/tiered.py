@@ -534,12 +534,9 @@ class TieredEmbeddingStore(SSPGateMixin, WriteLogMixin):
             return True
         if env in ("0", "off", "false"):
             return False
-        try:
-            import jax
+        import jax
 
-            return jax.default_backend() == "tpu"
-        except Exception:  # jax absent/broken: host mode keeps working
-            return False
+        return jax.default_backend() == "tpu"
 
     @staticmethod
     def _dev_zeros(rows: int, width: int):

@@ -559,7 +559,7 @@ class SparseTableCTRTrainer(CTRTrainer):
         from jax.flatten_util import ravel_pytree
         from jax.sharding import PartitionSpec as P
 
-        from lightctr_tpu.core.compat import shard_map
+        from jax import shard_map
         from lightctr_tpu.dist.collectives import (
             _ag_exchange_rows,
             _ag_gather_ids,
@@ -900,7 +900,7 @@ class SparseTableCTRTrainer(CTRTrainer):
         from jax.flatten_util import ravel_pytree
         from jax.sharding import PartitionSpec as P
 
-        from lightctr_tpu.core.compat import shard_map
+        from jax import shard_map
         from lightctr_tpu.dist.collectives import (
             _ag_exchange_rows,
             _ag_gather_ids,

@@ -16,9 +16,9 @@ halving FLOPs at long T.  Forward-only: the production differentiable paths
 are ``full_attention`` (short T) and ``ring_attention`` (sharded long T);
 this kernel serves long-context inference/eval on one core.
 
-Validated compiled on TPU v5e against the ``full_attention`` oracle (see
-tests/test_flash_attention.py for the interpret-mode gate and
-tools/bench_pallas.py for on-chip timings).
+Gates against the ``full_attention`` oracle: tests/test_flash_attention.py
+(interpret mode), tests_tpu/test_compiled_kernels.py and ``chip_smoke.py``'s
+kernel phase (compiled, on the chip).
 """
 
 from __future__ import annotations
@@ -28,7 +28,9 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from lightctr_tpu.core.compat import pallas_modules, tpu_compiler_params
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
 from lightctr_tpu.ops.sparse_kernels import register_kernel, resolve_impl
 
 NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
@@ -50,7 +52,6 @@ def _flash_kernel(
     q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
     *, scale: float, causal: bool, block_q: int, block_k: int, nk: int
 ):
-    pl, _ = pallas_modules()
     qi = pl.program_id(1)
     kj = pl.program_id(2)
 
@@ -170,7 +171,6 @@ def _flash_pallas(
     block_k: int = 512,
     interpret: bool = False,
 ) -> jax.Array:
-    pl, pltpu = pallas_modules()
     b, t, h, d = q.shape
     block_q, block_k = _validate_blocks(t, block_q, block_k)
     scale = 1.0 / (d ** 0.5)
@@ -204,7 +204,7 @@ def _flash_pallas(
             pltpu.VMEM((block_q, LANES), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

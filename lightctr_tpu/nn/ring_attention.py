@@ -24,7 +24,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from lightctr_tpu.core.compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 NEG_INF = -1e30
@@ -48,9 +48,7 @@ def _ring_attention_local(
     # dtype (bf16 denominators round away terms after a few hundred adds);
     # mark them varying over the ring axis so the scan carry types match
     def _vary(x):
-        from lightctr_tpu.core.compat import pvary
-
-        return pvary(x, (axis_name,))
+        return jax.lax.pcast(x, (axis_name,), to="varying")
 
     m0 = _vary(jnp.full((b, h, tb), NEG_INF, jnp.float32))
     l0 = _vary(jnp.zeros((b, h, tb), jnp.float32))

@@ -29,21 +29,26 @@ for the TPU port:
     decode, fresh-error — one payload traversal.
 
 Every kernel ships a pure-XLA **reference twin** (literally the code the
-call sites ran before this module existed) and dispatch is capability
-gated — see :func:`resolve_impl`:
+call sites ran before this module existed) and dispatch is decided per
+kernel NAME — see :func:`resolve_impl`:
 
-  - ``pallas``   — compiled Mosaic kernels; picked automatically on TPU.
+  - ``pallas``   — compiled Mosaic kernels; what ``auto`` picks on a TPU for
+                   every kernel the registry does not deselect there.
   - ``interpret``— the same kernels under ``pallas_call(interpret=True)``
                    (CPU parity tests); forced by ``LIGHTCTR_KERNELS=interpret``.
-  - ``xla``      — the reference twin; the default off-TPU and the
-                   degrade path when the jax pin has no pallas at all
-                   (``core.compat.pallas_modules``).
+  - ``xla``      — the reference twin; the default off-TPU, and ON a TPU the
+                   implementation of every kernel registered with a
+                   ``deselected`` reason (the Mosaic compiler's words on why
+                   its Pallas form does not run at the width the trainer
+                   uses; ROADMAP S2 decides repair or deletion).
 
 ``LIGHTCTR_KERNELS`` = ``auto`` (default) | ``pallas`` | ``interpret`` |
-``xla``.  Every resolution is counted in
-``trainer_kernel_path_total{phase,impl}`` (once per trace, not per step —
-the pick is static inside jit), so ``tools/metrics_report.py --kernels``
-shows which implementation actually ran, measured rather than assumed.
+``xla``.  The pick is static: nothing falls from one implementation to
+another because a lowering, a compile or a run failed — that is an error.
+Every resolution is counted in ``trainer_kernel_path_total{phase,impl}``
+(once per trace, not per step — the pick is static inside jit), so
+``tools/metrics_report.py --kernels`` shows which implementation actually
+ran, measured rather than assumed.
 
 Modules register their kernels here (``optim/fused_adagrad``,
 ``nn/flash_attention`` self-register on import); the AST lint in
@@ -61,9 +66,10 @@ from typing import Callable, Dict, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from lightctr_tpu import obs
-from lightctr_tpu.core.compat import pallas_modules
 
 ENV_FLAG = "LIGHTCTR_KERNELS"
 
@@ -78,6 +84,9 @@ class KernelDef(NamedTuple):
     phase: str            # one of KERNEL_PHASES
     reference: Callable   # the pure-XLA twin (the pre-kernel call-site code)
     pallas: Callable      # pallas impl; MUST accept interpret=bool kwarg
+    #: set -> ``auto`` keeps the XLA twin on a TPU too; the string is the
+    #: reason (the compiler's message at the trainer's width)
+    deselected: Optional[str] = None
 
 
 #: name -> KernelDef.  The single source of truth the lint walks.
@@ -85,41 +94,43 @@ KERNELS: Dict[str, KernelDef] = {}
 
 
 def register_kernel(
-    name: str, *, phase: str, reference: Callable, pallas: Callable
+    name: str, *, phase: str, reference: Callable, pallas: Callable,
+    deselected: Optional[str] = None,
 ) -> None:
     """Register a fused kernel with its XLA reference twin.  Both are
-    mandatory — the dispatcher's CPU/old-jax degrade path IS the
-    reference, so a kernel without one could strand tier-1."""
+    mandatory — off-TPU the reference IS the implementation, so a kernel
+    without one could strand tier-1.  ``deselected`` takes the kernel out
+    of what ``auto`` selects on a TPU, by name, with the reason."""
     if phase not in KERNEL_PHASES:
         raise ValueError(f"unknown kernel phase {phase!r}")
     if not callable(reference) or not callable(pallas):
         raise ValueError(f"kernel {name!r} needs callable reference AND pallas")
     KERNELS[name] = KernelDef(
-        name=name, phase=phase, reference=reference, pallas=pallas
+        name=name, phase=phase, reference=reference, pallas=pallas,
+        deselected=deselected,
     )
 
 
 def resolve_impl(name: str) -> str:
-    """The capability gate: which implementation a dispatch call will run.
+    """Which implementation a dispatch call will run.
 
     ``LIGHTCTR_KERNELS=xla`` forces the reference; ``interpret`` forces the
     Pallas kernel under the interpreter (CPU parity testing); ``pallas``
-    forces compiled Mosaic; ``auto`` (default) compiles Pallas on TPU and
-    takes the reference everywhere else.  A jax pin without pallas modules
-    always resolves ``xla`` — degrade, never ImportError."""
+    forces compiled Mosaic; ``auto`` (default) compiles Pallas on a TPU for
+    every kernel not registered ``deselected`` and takes the reference
+    everywhere else."""
     if name not in KERNELS:
         raise KeyError(f"unregistered kernel {name!r}")
     mode = os.environ.get(ENV_FLAG, "auto").strip().lower() or "auto"
     if mode in ("xla", "off", "reference", "0"):
         return "xla"
-    pl_mod, _ = pallas_modules()
-    if pl_mod is None:
-        return "xla"
     if mode == "interpret":
         return "interpret"
     if mode == "pallas":
         return "pallas"
-    return "pallas" if jax.default_backend() == "tpu" else "xla"
+    if jax.default_backend() == "tpu" and KERNELS[name].deselected is None:
+        return "pallas"
+    return "xla"
 
 
 def _record(phase: str, impl: str) -> None:
@@ -130,8 +141,8 @@ def _record(phase: str, impl: str) -> None:
 
 def _resolve(name: str, impl: Optional[str] = None) -> Tuple[str, Callable]:
     """(impl, fn) for one dispatch: the telemetry counter records the pick
-    that actually runs (callers pass ``impl`` when a per-call capability
-    check already downgraded it)."""
+    that actually runs (callers pass ``impl`` when a static per-call rule
+    already chose the twin)."""
     kd = KERNELS[name]
     impl = impl or resolve_impl(name)
     _record(kd.phase, impl)
@@ -176,7 +187,6 @@ def _dedup_kernel(ids_ref, inv_ref, uids_ref, count_ref, first_ref,
     accumulation — O(K^2) compares on the VPU instead of a sort network)
     and scatters first-rank ids into the output slots; slot ``size`` is
     the dump slot for truncated/padded entries (sliced off outside)."""
-    pl, _ = pallas_modules()
     phase, b = pl.program_id(0), pl.program_id(1)
     start = b * bk
     x = ids_ref[pl.ds(start, bk), :]                       # [bk, 1]
@@ -225,7 +235,6 @@ def _dedup_kernel(ids_ref, inv_ref, uids_ref, count_ref, first_ref,
 
 
 def _dedup_pallas(ids: jax.Array, size: int, *, interpret: bool):
-    pl, _ = pallas_modules()
     k = ids.shape[0]
     ids32 = ids.astype(jnp.int32)
     bk = min(256, max(8, 1 << (k - 1).bit_length()))
@@ -244,15 +253,10 @@ def _dedup_pallas(ids: jax.Array, size: int, *, interpret: bool):
             jax.ShapeDtypeStruct((size + 1, 1), jnp.int32),  # uids + dump slot
             jax.ShapeDtypeStruct((1, 1), jnp.int32),       # distinct count
         ),
-        scratch_shapes=[_vmem_scratch((kp, 1), jnp.int32)],
+        scratch_shapes=[pltpu.VMEM((kp, 1), jnp.int32)],
         interpret=interpret,
     )(ids32.reshape(kp, 1))
     return (uids[:size, 0].astype(ids.dtype), inv[:k, 0], count[0, 0])
-
-
-def _vmem_scratch(shape, dtype):
-    _, pltpu = pallas_modules()
-    return pltpu.VMEM(shape, dtype)
 
 
 def dedup_ids(ids: jax.Array, size: Optional[int] = None):
@@ -295,7 +299,6 @@ def _merge_kernel(inv_ref, rows_ref, out_ref, *, m, bk, nseg):
     the merge is bit-identical to the reference twin).  Out-of-range
     segments (truncated ranks) and padded slots add exact zeros to row 0,
     matching ``segment_sum``'s drop semantics."""
-    pl, _ = pallas_modules()
     b = pl.program_id(0)
 
     @pl.when(b == 0)
@@ -316,7 +319,6 @@ def _merge_kernel(inv_ref, rows_ref, out_ref, *, m, bk, nseg):
 
 def _merge_pallas(rows: jax.Array, inv: jax.Array, num_segments: int,
                   *, interpret: bool):
-    pl, _ = pallas_modules()
     m = rows.shape[0]
     d = int(np.prod(rows.shape[1:])) if rows.ndim > 1 else 1
     flat = rows.reshape(m, d).astype(jnp.float32)
@@ -375,284 +377,120 @@ def _merge_apply_reference(
     return new_table, st.accum, sumsq
 
 
-def _apply_kernel(uids_ref, w_ref, a_ref, g_ref, w_out, a_out, ssq_ref,
-                  *, lr, eps, denom, s):
-    """Per-touched-row fused scaled-apply: the scalar-prefetched uid
-    steers the (1, dim) table/accum block windows (the canonical Pallas
-    gather pattern), so each gradient row is read once, scaled, squared
-    into the running health norm, and applied — no merged intermediate
-    ever lands in HBM.  Padded slots (uid 0 beyond slot 0, the dedup
-    convention) zero their gradient: the write-back is then an exact
-    no-op, the same arithmetic the reference's masked scatter-add does.
+#: rows per grid step of the row-DMA kernels (:func:`_apply_kernel`,
+#: :func:`_gather_kernel`): 8 is the smallest row block the TPU tiling
+#: rule admits for the gradient/output windows, and the number of row
+#: copies each step keeps in flight
+DMA_ROWS = 8
 
-    The caller rotates the slot order so ORIGINAL slot 0 runs LAST
-    (grid step i handles slot (i+1) % s): every other row is visited
-    exactly once, and the multiply-visited row 0 (pads + a possible real
-    id 0) sees all its no-op pad writes BEFORE the one real write — an
-    aliased block revisit must never read back its own earlier write."""
-    pl, _ = pallas_modules()
+
+def _apply_kernel(uids_ref, g_ref, w_in, a_in, w_out, a_out, ssq_ref,
+                  w_scr, a_scr, sems, *, lr, eps, denom, s, rb):
+    """Fused scaled Adagrad apply over touched rows, ``rb`` rows per grid
+    step.  Table and accumulator stay in HBM (``pl.ANY``) and alias their
+    outputs, so the update is in place at any vocabulary; each live row
+    is one async copy HBM -> its own VMEM slot, the fused update, and one
+    copy back.  The step's copies are all started before the first wait:
+    live rows are distinct (sorted unique uids), so nothing orders them.
+
+    Pad slots — uid 0 beyond slot 0, the dedup convention, and the block
+    round-up tail — carry zero gradient by contract and are skipped, so
+    row 0 is touched at most once (by slot 0, which is either the real id
+    0 or the smallest real id) and no row is ever written twice."""
+    del w_in, a_in  # aliased into w_out / a_out
     i = pl.program_id(0)
 
     @pl.when(i == 0)
     def _zero():
         ssq_ref[0, 0] = 0.0
 
-    g = g_ref[...]
-    if denom != 1.0:
-        g = g / denom
-    uid = uids_ref[i]
-    # original slot of this grid step is (i + 1) % s: slot 0 <=> i == s-1
-    g = g * jnp.where((uid == 0) & (i != s - 1), 0.0, 1.0)
-    ssq_ref[0, 0] += jnp.sum(g * g)
-    a_new = a_ref[...] + g * g
-    a_out[...] = a_new
-    w_out[...] = w_ref[...] - lr * g * jax.lax.rsqrt(a_new + eps)
-
-
-#: env override for the apply kernel's rows-per-grid-step: ``1`` = the
-#: scalar-prefetch-windowed per-row kernel, ``>1`` = the row-block kernel
-#: (:func:`_apply_block_kernel`) batching that many rows per grid step
-APPLY_ROWS_ENV = "LIGHTCTR_APPLY_ROWS"
-
-
-def apply_rows_per_step(interpret: bool) -> int:
-    """Rows the apply kernel batches per grid step.  Default: 8 under the
-    interpreter (grid-step overhead dominates there; the block variant is
-    validated bit-for-bit by the parity suite), 1 compiled.  Compiled
-    ``rb > 1`` is now CORRECT at any vocabulary — it lowers to
-    :func:`_apply_block_dma_kernel`, whose table/accum refs stay in ANY
-    (HBM) memory space with explicit per-row async-copy windows, instead
-    of the interpreter block kernel's full-VMEM refs (which cap vocab at
-    VMEM size compiled) — and is gated on real hardware by
-    tests_tpu/test_compiled_kernels.py.  It stays opt-in
-    (:data:`APPLY_ROWS_ENV`) until the compiled A/B column of
-    SPARSE_KERNEL_BENCH.json, which must come from a real-TPU run of
-    tools/sparse_kernel_bench.py, shows the grid-step amortization
-    beating the per-row kernel's simpler pipelining."""
-    env = os.environ.get(APPLY_ROWS_ENV, "").strip()
-    if env:
-        return max(1, int(env))
-    return 8 if interpret else 1
-
-
-def _apply_block_kernel(uids_ref, w_ref, a_ref, g_ref, w_out, a_out,
-                        ssq_ref, *, lr, eps, denom, s, rb):
-    """Row-block fused apply: ``rb`` touched rows per grid step (the PR 9
-    follow-up — the per-row kernel pays one grid step per row, pure
-    overhead at small dims).  Table/accum ride as FULL refs with dynamic
-    per-row loads/stores (the :func:`_merge_kernel` access pattern), so
-    grid steps shrink ``rb``-fold; step 0 seeds the outputs wholesale
-    (compiled aliasing makes that a self-copy, the interpreter needs it —
-    out buffers start uninitialized).  Same rotation contract as
-    :func:`_apply_kernel`: the caller rotates original slot 0 to run
-    LAST, so pad revisits of row 0 write pre-update values before the one
-    real write, which is correct under both aliasing semantics; slots
-    padded past ``s`` (block round-up) are skipped outright."""
-    pl, _ = pallas_modules()
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
-    def _seed():
-        ssq_ref[0, 0] = 0.0
-        w_out[...] = w_ref[...]
-        a_out[...] = a_ref[...]
-
-    def body(j, _):
-        p = i * rb + j
-
-        @pl.when(p < s)
-        def _row():
-            uid = uids_ref[p, 0]
-            g = g_ref[pl.ds(p, 1), :]
-            if denom != 1.0:
-                g = g / denom
-            # original slot of position p is (p + 1) % s: slot 0 <=> p==s-1
-            g = g * jnp.where((uid == 0) & (p != s - 1), 0.0, 1.0)
-            ssq_ref[0, 0] += jnp.sum(g * g)
-            a_new = a_ref[pl.ds(uid, 1), :] + g * g
-            a_out[pl.ds(uid, 1), :] = a_new
-            w_out[pl.ds(uid, 1), :] = w_ref[pl.ds(uid, 1), :] \
-                - lr * g * jax.lax.rsqrt(a_new + eps)
-
-        return 0
-
-    jax.lax.fori_loop(0, rb, body, 0)
-
-
-def _apply_block_dma_kernel(uids_ref, w_any, a_any, g_ref, w_out, a_out,
-                            ssq_ref, w_scr, a_scr, sems,
-                            *, lr, eps, denom, s, rb):
-    """Compiled-Mosaic row-block fused apply: ``rb`` touched rows per grid
-    step with table/accum refs in ANY (HBM) memory space — the PR 9/10
-    follow-up that makes ``LIGHTCTR_APPLY_ROWS > 1`` correct COMPILED,
-    not just under the interpreter.  The interpreter block kernel
-    (:func:`_apply_block_kernel`) rides full VMEM refs, which compiled
-    would cap the vocabulary at VMEM size; here each row is an explicit
-    async-copy window: HBM row -> VMEM scratch, fused update, VMEM ->
-    HBM write-back, sequential waits so a revisited row (the rotated
-    pad convention — original slot 0 runs LAST) always reads its own
-    prior write back.  Aliasing makes ``w_out``/``a_out`` the same HBM
-    buffers as the inputs, so untouched rows need no seeding pass and
-    the update is truly in place.  Same arithmetic as the other two
-    variants; gated bit-for-bit on hardware by
-    tests_tpu/test_compiled_kernels.py."""
-    pl, pltpu = pallas_modules()
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
-    def _zero():
-        ssq_ref[0, 0] = 0.0
-
-    def body(j, _):
-        p = i * rb + j
-
-        @pl.when(p < s)
-        def _row():
+    def each_live(fn):
+        def body(j, carry):
+            p = i * rb + j
             uid = uids_ref[p]
-            in_w = pltpu.make_async_copy(
-                w_out.at[pl.ds(uid, 1), :], w_scr, sems.at[0]
-            )
-            in_a = pltpu.make_async_copy(
-                a_out.at[pl.ds(uid, 1), :], a_scr, sems.at[1]
-            )
-            in_w.start()
-            in_a.start()
-            in_w.wait()
-            in_a.wait()
-            # g_ref is this grid step's (rb, d) window: row j, not p
-            g = g_ref[pl.ds(j, 1), :]
-            if denom != 1.0:
-                g = g / denom
-            # original slot of position p is (p + 1) % s: slot 0 <=> p==s-1
-            g = g * jnp.where((uid == 0) & (p != s - 1), 0.0, 1.0)
-            ssq_ref[0, 0] += jnp.sum(g * g)
-            a_new = a_scr[...] + g * g
-            a_scr[...] = a_new
-            w_scr[...] = w_scr[...] - lr * g * jax.lax.rsqrt(a_new + eps)
-            out_w = pltpu.make_async_copy(
-                w_scr, w_out.at[pl.ds(uid, 1), :], sems.at[0]
-            )
-            out_a = pltpu.make_async_copy(
-                a_scr, a_out.at[pl.ds(uid, 1), :], sems.at[1]
-            )
-            out_w.start()
-            out_a.start()
-            # sequential completion: the next row may BE this row (pad
-            # revisits of slot 0) — its read must see this write
-            out_w.wait()
-            out_a.wait()
 
-        return 0
+            @pl.when((p < s) & ((uid != 0) | (p == 0)))
+            def _row():
+                fn(j, uid)
 
-    jax.lax.fori_loop(0, rb, body, 0)
-    del w_any, a_any  # aliased into w_out/a_out; reads go through the outs
+            return carry
 
+        jax.lax.fori_loop(0, rb, body, 0)
 
-def _apply_block_dma(table, accum, uids_r, merged_r, lr, eps, denom, s, rb,
-                     vocab, d, shape):
-    """Launch :func:`_apply_block_dma_kernel` (compiled rb > 1 path)."""
-    pl, pltpu = pallas_modules()
-    sp = -(-s // rb) * rb
-    uids_p = jnp.pad(uids_r, (0, sp - s))
-    merged_p = jnp.pad(merged_r, ((0, sp - s), (0, 0)))
-    any_space = getattr(pltpu, "ANY", getattr(pl, "ANY", None))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(sp // rb,),
-        in_specs=[
-            pl.BlockSpec(memory_space=any_space),
-            pl.BlockSpec(memory_space=any_space),
-            pl.BlockSpec((rb, d), lambda i, u: (i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec(memory_space=any_space),
-            pl.BlockSpec(memory_space=any_space),
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((1, d), jnp.float32),
-            pltpu.VMEM((1, d), jnp.float32),
-            pltpu.SemaphoreType.DMA((2,)),
-        ],
-    )
-    w2, a2, ssq = pl.pallas_call(
-        partial(_apply_block_dma_kernel, lr=lr, eps=eps, denom=denom,
-                s=s, rb=rb),
-        grid_spec=grid_spec,
-        out_shape=(
-            jax.ShapeDtypeStruct((vocab, d), table.dtype),
-            jax.ShapeDtypeStruct((vocab, d), accum.dtype),
-            jax.ShapeDtypeStruct((1, 1), jnp.float32),
-        ),
-        input_output_aliases={1: 0, 2: 1},
-        interpret=False,
-    )(uids_p, table.reshape(vocab, d), accum.reshape(vocab, d), merged_p)
-    return w2.reshape(shape), a2.reshape(shape), ssq[0, 0]
+    def copies(j, uid, inbound):
+        pairs = ((w_out.at[pl.ds(uid, 1), :], w_scr.at[j], sems.at[0, j]),
+                 (a_out.at[pl.ds(uid, 1), :], a_scr.at[j], sems.at[1, j]))
+        return [
+            pltpu.make_async_copy(hbm, vmem, sem) if inbound
+            else pltpu.make_async_copy(vmem, hbm, sem)
+            for hbm, vmem, sem in pairs
+        ]
+
+    def update(j, uid):
+        del uid
+        g = g_ref[pl.ds(j, 1), :]
+        if denom != 1.0:
+            g = g / denom
+        ssq_ref[0, 0] += jnp.sum(g * g)
+        a_new = a_scr[j] + g * g
+        a_scr[j] = a_new
+        w_scr[j] = w_scr[j] - lr * g * jax.lax.rsqrt(a_new + eps)
+
+    each_live(lambda j, uid: [c.start() for c in copies(j, uid, True)])
+    each_live(lambda j, uid: [c.wait() for c in copies(j, uid, True)])
+    each_live(update)
+    each_live(lambda j, uid: [c.start() for c in copies(j, uid, False)])
+    each_live(lambda j, uid: [c.wait() for c in copies(j, uid, False)])
 
 
 def _merge_apply_pallas(
     table, accum, uids, rows, inv, lr, eps, denom, *, interpret: bool
 ):
-    pl, pltpu = pallas_modules()
     shape = table.shape
     vocab = shape[0]
-    d = int(np.prod(shape[1:])) if len(shape) > 1 else 1
+    d = int(np.prod(shape[1:]))
     s = uids.shape[0]
     if inv is not None:
-        merged = _merge_pallas(
-            rows.reshape(rows.shape[0], d), inv, s, interpret=interpret
-        )
+        # the segment merge is its own registered kernel with its own pick
+        merged = merge_rows(rows.reshape(rows.shape[0], d), inv, s)
     else:
-        merged = rows.reshape(s, d).astype(jnp.float32)
-    # rotate so original slot 0 is the LAST grid step (see _apply_kernel)
-    uids_r = jnp.roll(uids.astype(jnp.int32), -1)
-    merged_r = jnp.roll(merged, -1, axis=0)
-    rb = apply_rows_per_step(interpret)
-    if rb > 1 and s > 1 and not interpret:
-        # compiled row-block path: ANY-space refs + explicit DMA windows
-        # (full-VMEM refs would cap vocab at VMEM size under Mosaic)
-        return _apply_block_dma(table, accum, uids_r, merged_r, lr, eps,
-                                denom, s, rb, vocab, d, shape)
-    if rb > 1 and s > 1:
-        sp = -(-s // rb) * rb
-        uids_p = jnp.pad(uids_r, (0, sp - s)).reshape(sp, 1)
-        merged_p = jnp.pad(merged_r, ((0, sp - s), (0, 0)))
-        w2, a2, ssq = pl.pallas_call(
-            partial(_apply_block_kernel, lr=lr, eps=eps, denom=denom,
-                    s=s, rb=rb),
-            grid=(sp // rb,),
-            out_shape=(
-                jax.ShapeDtypeStruct((vocab, d), table.dtype),
-                jax.ShapeDtypeStruct((vocab, d), accum.dtype),
-                jax.ShapeDtypeStruct((1, 1), jnp.float32),
-            ),
-            input_output_aliases={1: 0, 2: 1},
-            interpret=interpret,
-        )(uids_p, table.reshape(vocab, d), accum.reshape(vocab, d), merged_p)
-        return w2.reshape(shape), a2.reshape(shape), ssq[0, 0]
-    spec_row = pl.BlockSpec((1, d), lambda i, u: (u[i], 0))
-    spec_seq = pl.BlockSpec((1, d), lambda i, u: (i, 0))
+        merged = rows.reshape(s, d)
+    rb = DMA_ROWS
+    sp = -(-s // rb) * rb
+    uids_p = jnp.pad(uids.astype(jnp.int32), (0, sp - s))
+    merged_p = jnp.pad(merged.astype(jnp.float32), ((0, sp - s), (0, 0)))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(s,),
-        in_specs=[spec_row, spec_row, spec_seq],
+        grid=(sp // rb,),
+        in_specs=[
+            pl.BlockSpec((rb, d), lambda i, u: (i, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
         out_specs=[
-            pl.BlockSpec((1, d), lambda i, u: (u[i], 0)),
-            pl.BlockSpec((1, d), lambda i, u: (u[i], 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pltpu.SMEM),
+        ],
+        scratch_shapes=[
+            # one (1, d) slab per in-flight row: the leading index is
+            # untiled, so every copy lands on a whole buffer
+            pltpu.VMEM((rb, 1, d), jnp.float32),
+            pltpu.VMEM((rb, 1, d), jnp.float32),
+            pltpu.SemaphoreType.DMA((2, rb)),
         ],
     )
     w2, a2, ssq = pl.pallas_call(
-        partial(_apply_kernel, lr=lr, eps=eps, denom=denom, s=s),
+        partial(_apply_kernel, lr=lr, eps=eps, denom=denom, s=s, rb=rb),
         grid_spec=grid_spec,
         out_shape=(
             jax.ShapeDtypeStruct((vocab, d), table.dtype),
             jax.ShapeDtypeStruct((vocab, d), accum.dtype),
             jax.ShapeDtypeStruct((1, 1), jnp.float32),
         ),
-        input_output_aliases={1: 0, 2: 1},
+        input_output_aliases={2: 0, 3: 1},
         interpret=interpret,
-    )(uids_r, table.reshape(vocab, d), accum.reshape(vocab, d), merged_r)
+    )(uids_p, merged_p, table.reshape(vocab, d), accum.reshape(vocab, d))
     return w2.reshape(shape), a2.reshape(shape), ssq[0, 0]
 
 
@@ -679,19 +517,20 @@ def merge_apply(
 
     Returns ``(table', accum', sumsq)``; ``sumsq`` is the merged rows'
     sum of squares (the health gradient-norm contribution) computed in
-    the same pass.  The trajectory is bit-identical to the reference
-    chain ``segment_sum -> /denom -> sparse_adagrad_update``; ``sumsq``
-    may differ in final-ulp accumulation order.
+    the same pass.  The trajectory matches the reference chain
+    ``segment_sum -> /denom -> sparse_adagrad_update`` to the last
+    FMA-contraction ulp; ``sumsq`` may differ in final-ulp accumulation
+    order.
 
     Padded id-0 slots are ZERO-GRADIENT BY CONTRACT, and for ``inv=None``
     payloads this dispatch enforces it before either impl runs: the coded
     reduce-scatter exchange leaves decoded dump-slot noise (half-bucket
     midpoints) in foreign shards' id-0 slots, and without the mask the
     reference would train real row 0 on that noise while the fused kernel
-    (whose aliased block revisits must stay no-op writes) drops it — the
-    enforced zero keeps every impl on the identical trajectory and keeps
-    codec noise off row 0.  Merged ``inv`` payloads need no mask: pad
-    segments are never referenced, their sums are exactly zero."""
+    (which skips pad slots) drops it — the enforced zero keeps every impl
+    on the identical trajectory and keeps codec noise off row 0.  Merged
+    ``inv`` payloads need no mask: pad segments are never referenced,
+    their sums are exactly zero."""
     if inv is None:
         k = uids.shape[0]
         valid = ~((uids == 0) & (jnp.arange(k) > 0))
@@ -710,10 +549,10 @@ def merge_apply(
 # row block runs: the tiered store's hot-tier pulls, the trainer's
 # hot-resident fast path, and the serving cache's device-block hits
 # (ISSUE 15: train and serve share ONE row path through this entry).
-# The Pallas twin is the scalar-prefetch windowed copy (the merge_apply
-# steering pattern): the prefetched index steers a (1, dim) source
-# window per grid step, so each row moves HBM -> VMEM -> HBM once with
-# no [n, vocab] one-hot or host round trip.  Indices MUST be in range
+# The Pallas twin is the read half of the merge_apply row-DMA pattern:
+# the block stays in HBM, the scalar-prefetched indices steer one async
+# row copy each, so a row moves HBM -> VMEM -> HBM once with no
+# [n, vocab] one-hot or host round trip.  Indices MUST be in range
 # (both impls clip rather than trap — jnp.take(mode="clip"), pinned
 # explicitly because take's default mode fills NaN).
 
@@ -725,32 +564,58 @@ def _gather_reference(block: jax.Array, idx: jax.Array):
     return jnp.take(block, idx, axis=0, mode="clip")
 
 
-def _gather_kernel(idx_ref, src_ref, out_ref):
-    del idx_ref  # consumed by the index maps
-    out_ref[...] = src_ref[...]
+def _gather_kernel(idx_ref, src, out_ref, scr, sems, *, rb):
+    """``rb`` row copies in flight per grid step: HBM row -> its own VMEM
+    slot, then one vector store into the step's ``(rb, d)`` output
+    window (the index array is padded to the block, so every slot is
+    live)."""
+    i = pl.program_id(0)
+
+    def copy(j):
+        return pltpu.make_async_copy(
+            src.at[pl.ds(idx_ref[i * rb + j], 1), :], scr.at[j], sems.at[j]
+        )
+
+    def start(j, carry):
+        copy(j).start()
+        return carry
+
+    def land(j, carry):
+        copy(j).wait()
+        out_ref[pl.ds(j, 1), :] = scr[j]
+        return carry
+
+    jax.lax.fori_loop(0, rb, start, 0)
+    jax.lax.fori_loop(0, rb, land, 0)
 
 
 def _gather_pallas(block: jax.Array, idx: jax.Array, *, interpret: bool):
-    pl, pltpu = pallas_modules()
     n = idx.shape[0]
     shape = block.shape
-    d = int(np.prod(shape[1:])) if len(shape) > 1 else 1
+    d = int(np.prod(shape[1:]))
     src = block.reshape(shape[0], d)
-    # clip like jnp.take: the index map window must stay in range
-    idx32 = jnp.clip(idx.astype(jnp.int32), 0, shape[0] - 1)
+    rb = DMA_ROWS
+    np_ = -(-n // rb) * rb
+    # clip like jnp.take: every copy window must stay in range
+    idx32 = jnp.pad(jnp.clip(idx.astype(jnp.int32), 0, shape[0] - 1),
+                    (0, np_ - n))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(n,),
-        in_specs=[pl.BlockSpec((1, d), lambda i, u: (u[i], 0))],
-        out_specs=pl.BlockSpec((1, d), lambda i, u: (i, 0)),
+        grid=(np_ // rb,),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((rb, d), lambda i, u: (i, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((rb, 1, d), block.dtype),
+            pltpu.SemaphoreType.DMA((rb,)),
+        ],
     )
     out = pl.pallas_call(
-        _gather_kernel,
+        partial(_gather_kernel, rb=rb),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n, d), block.dtype),
+        out_shape=jax.ShapeDtypeStruct((np_, d), block.dtype),
         interpret=interpret,
     )(idx32, src)
-    return out.reshape((n,) + shape[1:])
+    return out[:n].reshape((n,) + shape[1:])
 
 
 def gather_rows(block: jax.Array, idx: jax.Array):
@@ -781,7 +646,6 @@ def _qp_kernel(bnd_ref, x_ref, codes_ref, *, nbp, bc, code_bits):
     """Compare-count encode: ``searchsorted(boundaries, x, side='left')``
     == the number of boundaries strictly below x — a chunked broadcast
     compare-accumulate, bit-identical to the codec's binary search."""
-    pl, _ = pallas_modules()
     x = x_ref[...]                                         # [bp, 1]
 
     def body(c, acc):
@@ -807,54 +671,14 @@ def _qp_flatten(table, x):
     return bnd.reshape(1, nbp), flat, bc, nbp, dtype
 
 
-def _qp_search_kernel(bnd_ref, x_ref, codes_ref, *, nbp, nb):
-    """VMEM binary search: ``searchsorted(boundaries, x, side='left')``
-    over a +inf-padded power-of-two boundary table — log2(nbp)+1 gathers
-    per element instead of the compare-count sweep's nbp compares, which
-    is what makes 16-bit tables (65535 boundaries) worth VPU time.  The
-    branchless count-of-strictly-less form: at each static halving step
-    ``pos`` advances past the half whose last boundary is below x; the
-    +inf padding never counts, so the result is capped at ``nb`` by
-    construction."""
-    pl, _ = pallas_modules()
-    x = x_ref[...]                                         # [bp, 1]
-    bnd = bnd_ref[0, :]                                    # [nbp]
-    pos = jnp.zeros(x.shape, jnp.int32)
-    sz = nbp
-    while sz > 1:                                          # static unroll
-        half = sz // 2
-        probe = jnp.take(bnd, (pos + (half - 1)).reshape(-1),
-                         axis=0).reshape(x.shape)
-        pos = jnp.where(probe < x, pos + half, pos)
-        sz -= half
-    last = jnp.take(bnd, pos.reshape(-1), axis=0).reshape(x.shape)
-    pos = pos + (last < x).astype(jnp.int32)
-    del nb  # the +inf padding already bounds pos
-    codes_ref[...] = pos.astype(codes_ref.dtype)
-
-
 def _qp_pallas(table, x: jax.Array, *, interpret: bool):
-    pl, _ = pallas_modules()
     bnd, flat, bc, nbp, dtype = _qp_flatten(table, x)
     p = flat.shape[0]
     bp = min(1024, max(8, p))
     pp = -(-p // bp) * bp
     if pp != p:
         flat = jnp.pad(flat, ((0, pp - p), (0, 0)))
-    if table.bits > 8:
-        # wide tables: the VMEM binary-search kernel (a 2^16 boundary
-        # table is 256KB of VMEM; the compare-count sweep would pay
-        # 65535 compares per element where the search pays 17 gathers)
-        nbp2 = 1 << (int(table.boundaries.shape[0]) - 1).bit_length()
-        bnd2 = table.boundaries.astype(jnp.float32)
-        if nbp2 != bnd2.shape[0]:
-            bnd2 = jnp.pad(bnd2, (0, nbp2 - bnd2.shape[0]),
-                           constant_values=jnp.inf)
-        kernel = partial(_qp_search_kernel, nbp=nbp2,
-                         nb=int(table.boundaries.shape[0]))
-        bnd, nbp = bnd2.reshape(1, nbp2), nbp2
-    else:
-        kernel = partial(_qp_kernel, nbp=nbp, bc=bc, code_bits=table.bits)
+    kernel = partial(_qp_kernel, nbp=nbp, bc=bc, code_bits=table.bits)
     codes = pl.pallas_call(
         kernel,
         grid=(pp // bp,),
@@ -869,14 +693,20 @@ def _qp_pallas(table, x: jax.Array, *, interpret: bool):
     return codes[:p, 0].reshape(x.shape)
 
 
+def _wide_codes_impl(table) -> Optional[str]:
+    """Codes wider than 8 bits keep the XLA twin's binary search on every
+    backend: the compare-count sweep the pack kernels run would pay 2^bits
+    compares per element (and a 2^bits one-hot decode)."""
+    return "xla" if table.bits > 8 else None
+
+
 def quantize_pack(table, x: jax.Array) -> jax.Array:
     """Dispatch: float payload -> quantile codes, bit-identical to
     ``ops.quantize.compress`` (the wire pack every coded collective hop
     ships).  Codes up to 8 bits — the 4-bit sub-byte tables included —
-    ride the compare-count sweep; wider tables (16-bit) ride the VMEM
-    binary-search kernel (:func:`_qp_search_kernel`) instead of
-    resolving to the reference."""
-    _, fn = _resolve("quantize_pack")
+    ride the compare-count sweep; wider tables take the reference
+    (:func:`_wide_codes_impl`)."""
+    _, fn = _resolve("quantize_pack", impl=_wide_codes_impl(table))
     return fn(table, x)
 
 
@@ -913,7 +743,6 @@ def _qp_ef_kernel(bnd_ref, val_ref, rows_ref, car_ref, mask_ref,
     """One pass over the payload: val = rows + carried*mask; encode
     (compare-count); decode (chunked one-hot masked sum — exact: every
     non-selected term contributes a signed zero); fresh EF error."""
-    pl, _ = pallas_modules()
     rows = rows_ref[...]
     car = car_ref[...]
     m = mask_ref[...]
@@ -942,7 +771,6 @@ def _qp_ef_kernel(bnd_ref, val_ref, rows_ref, car_ref, mask_ref,
 
 
 def _qp_ef_pallas(table, rows, carried, mask, *, interpret: bool):
-    pl, _ = pallas_modules()
     bnd, flat, bc, nbp, dtype = _qp_flatten(table, rows)
     nv = int(table.values.shape[0])
     vc = min(256, max(8, nv))
@@ -994,10 +822,7 @@ def quantize_pack_ef(table, rows: jax.Array, carried: jax.Array,
     table slots.  One traversal instead of the reference's
     compensate/encode/decode/error chain.  8-bit-and-under codes take
     the Pallas path (see :func:`quantize_pack`)."""
-    impl = None
-    if table.bits > 8 and resolve_impl("quantize_pack_ef") != "xla":
-        impl = "xla"
-    _, fn = _resolve("quantize_pack_ef", impl=impl)
+    _, fn = _resolve("quantize_pack_ef", impl=_wide_codes_impl(table))
     return fn(table, rows, carried, mask)
 
 
@@ -1027,10 +852,9 @@ def _qp_ef_update_kernel(uids_ref, bnd_ref, vals_ref, rows_ref, mask_ref,
     (chunked one-hot) / fresh-error / CARRY WRITE-BACK are one pass and
     the residual scatter never runs as a separate HLO.  Padded slots
     (mask 0) write their carry window back unchanged — an identity
-    revisit, safe under either aliasing semantics; the caller still
-    rotates original slot 0 last (the merge_apply contract) so the one
-    real write of a multiply-visited row lands unmasked."""
-    pl, _ = pallas_modules()
+    revisit, safe under either aliasing semantics; the caller rotates
+    original slot 0 to run last, so the one real write of a
+    multiply-visited row lands after its pad revisits."""
     r = rows_ref[...]                                      # [1, d]
     m = mask_ref[...]                                      # [1, 1]
     car = res_ref[...]                                     # [1, d]
@@ -1063,7 +887,6 @@ def _qp_ef_update_kernel(uids_ref, bnd_ref, vals_ref, rows_ref, mask_ref,
 
 def _qp_ef_update_pallas(table, rows, uids, residual, mask,
                          *, interpret: bool):
-    pl, pltpu = pallas_modules()
     s = rows.shape[0]
     d = int(np.prod(rows.shape[1:])) if rows.ndim > 1 else 1
     vocab = residual.shape[0]
@@ -1084,8 +907,8 @@ def _qp_ef_update_pallas(table, rows, uids, residual, mask,
     vals = table.values.astype(jnp.float32)
     if nvp != nv:
         vals = jnp.pad(vals, (0, nvp - nv))
-    # rotate original slot 0 to run LAST (see _apply_kernel): pad
-    # revisits of a shared uid-0 window must precede the one real write
+    # rotate original slot 0 to run LAST: pad revisits of a shared uid-0
+    # window must precede the one real write
     uids_r = jnp.roll(uids.astype(jnp.int32), -1)
     flat_r = jnp.roll(flat, -1, axis=0)
     msk_r = jnp.roll(msk, -1, axis=0)
@@ -1143,25 +966,49 @@ def quantize_pack_ef_update(table, rows: jax.Array, uids: jax.Array,
         dtype = jnp.uint8 if table.bits <= 8 else jnp.uint16
         return (jnp.zeros(rows.shape, dtype), residual,
                 jnp.zeros(rows.shape, jnp.float32))
-    impl = None
-    if table.bits > 8 and resolve_impl("quantize_pack_ef_update") != "xla":
-        impl = "xla"
-    _, fn = _resolve("quantize_pack_ef_update", impl=impl)
+    _, fn = _resolve("quantize_pack_ef_update",
+                     impl=_wide_codes_impl(table))
     return fn(table, rows, uids, residual, mask)
 
 
-register_kernel("dedup_ids", phase="dedup",
-                reference=_dedup_reference, pallas=_dedup_pallas)
+# Deselection reasons are the TPU v5e's own words at the Criteo-shape
+# Wide&Deep width (K = 4096 x 39 = 159,744 ids, vocab 2^20, dim 32; chip
+# run of PR 21, CHANGES.md).  ROADMAP S2 decides repair or deletion.
+register_kernel(
+    "dedup_ids", phase="dedup",
+    reference=_dedup_reference, pallas=_dedup_pallas,
+    deselected="Pallas lowering: 'Cannot store scalars to VMEM' (the "
+               "per-id uids/count stores); also whole (K, 1) id arrays in "
+               "VMEM and O(K^2) compares",
+)
+_ROW_DMA_REASON = (
+    "Mosaic: 'Slice shape along dimension 1 must be aligned to tiling "
+    "(128), but is 32' — a row copy out of a (vocab, 32) HBM operand "
+    "(memref<1048576x128xf32, tiled<(1,128)>>) cannot address 32 lanes"
+)
 register_kernel("gather_rows", phase="gather",
-                reference=_gather_reference, pallas=_gather_pallas)
-register_kernel("merge_rows", phase="merge",
-                reference=_merge_reference, pallas=_merge_pallas)
+                reference=_gather_reference, pallas=_gather_pallas,
+                deselected=_ROW_DMA_REASON)
+register_kernel(
+    "merge_rows", phase="merge",
+    reference=_merge_reference, pallas=_merge_pallas,
+    deselected="XLA:TPU compile: 'Ran out of memory in memory space vmem. "
+               "Used 156.00M of 128.00M' at M = 159,744 (whole (M, 1) "
+               "segment map and [M, d] payload as VMEM windows, no "
+               "BlockSpec); compiles and matches at M = 2048",
+)
 register_kernel("merge_apply", phase="apply",
-                reference=_merge_apply_reference, pallas=_merge_apply_pallas)
+                reference=_merge_apply_reference, pallas=_merge_apply_pallas,
+                deselected=_ROW_DMA_REASON)
 register_kernel("quantize_pack", phase="pack",
                 reference=_qp_reference, pallas=_qp_pallas)
 register_kernel("quantize_pack_ef", phase="pack",
                 reference=_qp_ef_reference, pallas=_qp_ef_pallas)
-register_kernel("quantize_pack_ef_update", phase="pack",
-                reference=_qp_ef_update_reference,
-                pallas=_qp_ef_update_pallas)
+register_kernel(
+    "quantize_pack_ef_update", phase="pack",
+    reference=_qp_ef_update_reference, pallas=_qp_ef_update_pallas,
+    deselected="Pallas lowering: the (1, d) row windows break 'the last "
+               "two dimensions of your block shape are divisible by 8 and "
+               "128 respectively, or be equal to the respective dimensions "
+               "of the overall array'",
+)

@@ -26,6 +26,7 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
 
 from lightctr_tpu.ops.sparse_kernels import register_kernel, resolve_impl
 
@@ -61,9 +62,6 @@ def _adagrad_pallas(
     block: int,
     interpret: bool = False,
 ) -> Tuple[jax.Array, jax.Array]:
-    from lightctr_tpu.core.compat import pallas_modules
-
-    pl, _ = pallas_modules()
     shape = w.shape
     flat_w = w.reshape(-1)
     n = flat_w.shape[0]

@@ -162,15 +162,14 @@ def test_merge_apply_apply_only_and_1d_table(rng):
     np.testing.assert_array_equal(np.asarray(w1)[0], table[0])  # pad row
 
 
-def test_merge_apply_row_block_matches_windowed(rng, monkeypatch):
-    """The apply kernel's rows-per-grid-step knob (the PR 9 follow-up):
-    the row-block variant (``LIGHTCTR_APPLY_ROWS=8``, full-ref dynamic
-    RMW, rb rows per grid step) and the windowed per-row kernel (``=1``)
-    agree with the reference to the documented FMA ulp — across a size
-    that does NOT divide the block (padded tail slots must be skipped,
-    not applied), dedup pads, and a REAL id 0 whose rotated slot runs
-    last."""
+def test_merge_apply_block_tail_pads_and_real_id0(rng):
+    """The apply kernel batches ``DMA_ROWS`` rows per grid step: a size
+    that does NOT divide the block (the round-up tail must be skipped,
+    not applied), dedup pads (skipped — row 0 is written once), and a
+    REAL id 0 at slot 0 all agree with the reference to the documented
+    FMA ulp."""
     s, vocab, d = 11, 32, 3
+    assert s % sk.DMA_ROWS
     uids_np = np.zeros(s, np.int64)
     u = np.unique(rng.integers(1, vocab, size=s - 2))
     uids_np[1:1 + u.size] = u  # slot 0 stays id 0 — REAL here
@@ -182,24 +181,17 @@ def test_merge_apply_row_block_matches_windowed(rng, monkeypatch):
             jnp.asarray(rows), None)
     w0, a0, s0 = sk.KERNELS["merge_apply"].reference(
         *args, lr=0.1, eps=1e-7, denom=2.0)
-    outs = {}
-    for rb in ("1", "8"):
-        monkeypatch.setenv(sk.APPLY_ROWS_ENV, rb)
-        outs[rb] = sk.KERNELS["merge_apply"].pallas(
-            *args, lr=0.1, eps=1e-7, denom=2.0, interpret=True)
-    for rb, (w1, a1, s1) in outs.items():
-        np.testing.assert_allclose(np.asarray(w1), np.asarray(w0),
-                                   rtol=0, atol=2e-7, err_msg=f"rb={rb}")
-        np.testing.assert_allclose(np.asarray(a1), np.asarray(a0),
-                                   rtol=2e-6, atol=0, err_msg=f"rb={rb}")
-        np.testing.assert_allclose(float(s1), float(s0), rtol=1e-5)
-        untouched = np.setdiff1d(np.arange(vocab), uids_np)
-        np.testing.assert_array_equal(np.asarray(w1)[untouched],
-                                      table[untouched])
-    assert sk.apply_rows_per_step(True) == 8  # env still "8" here
-    monkeypatch.delenv(sk.APPLY_ROWS_ENV)
-    assert sk.apply_rows_per_step(True) == 8   # interpret default: block
-    assert sk.apply_rows_per_step(False) == 1  # compiled default: windowed
+    w1, a1, s1 = sk.KERNELS["merge_apply"].pallas(
+        *args, lr=0.1, eps=1e-7, denom=2.0, interpret=True)
+    np.testing.assert_allclose(np.asarray(w1), np.asarray(w0),
+                               rtol=0, atol=2e-7)
+    np.testing.assert_allclose(np.asarray(a1), np.asarray(a0),
+                               rtol=2e-6, atol=0)
+    np.testing.assert_allclose(float(s1), float(s0), rtol=1e-5)
+    assert np.asarray(w1)[0].tolist() != table[0].tolist()  # id 0 trained
+    untouched = np.setdiff1d(np.arange(vocab), uids_np)
+    np.testing.assert_array_equal(np.asarray(w1)[untouched],
+                                  table[untouched])
 
 
 # -- (c) quantize pack: bit-identical codes ------------------------------
@@ -217,36 +209,23 @@ def test_quantize_pack_bit_identical_to_codec(rng):
                                       err_msg=mode)
 
 
-def test_quantize_pack_16bit_rides_vmem_binary_search(monkeypatch, rng):
-    """Wide tables no longer resolve to the reference (the PR 9
-    follow-up): a 16-bit table dispatches the VMEM binary-search kernel
-    and its codes are bit-identical to ``quantize.compress`` — clip
-    edges, exact-boundary hits and out-of-range values included."""
-    monkeypatch.setenv(sk.ENV_FLAG, "interpret")
-    for bits, mode in ((16, "uniform"), (16, "log"), (12, "uniform")):
-        t = quantize.build_table(-1.0, 1.0, bits=bits, mode=mode)
-        x = jnp.asarray(np.concatenate([
-            np.linspace(-1.5, 1.5, 31, dtype=np.float32),
-            np.asarray(t.boundaries)[:7],          # exact boundary hits
-            np.array([0.0, -0.0, 1e-9], np.float32),
-        ]))
-        got = sk.quantize_pack(t, x)
-        np.testing.assert_array_equal(
-            np.asarray(got), np.asarray(quantize.compress(t, x)),
-            err_msg=f"bits={bits} mode={mode}",
-        )
-        assert got.dtype == jnp.uint16
-    # the dispatch records the interpret path, not an xla downgrade
+def test_quantize_pack_wide_tables_take_the_reference(monkeypatch):
+    """Codes wider than 8 bits keep the XLA twin whatever the mode (a
+    static rule on the table, counted as ``xla``): the compare-count
+    sweep would pay 2^bits compares per element."""
     from lightctr_tpu import obs
 
+    monkeypatch.setenv(sk.ENV_FLAG, "interpret")
     reg = obs.default_registry()
-    key = obs.labeled("trainer_kernel_path_total",
-                      phase="pack", impl="interpret")
+    key = obs.labeled("trainer_kernel_path_total", phase="pack", impl="xla")
     before = reg.snapshot()["counters"].get(key, 0)
-    sk.quantize_pack(quantize.build_table(-1.0, 1.0, bits=16),
-                     jnp.zeros((8,), jnp.float32))
-    after = reg.snapshot()["counters"].get(key, 0)
-    assert after == before + 1
+    t = quantize.build_table(-1.0, 1.0, bits=16)
+    x = jnp.asarray(np.linspace(-1.5, 1.5, 31, dtype=np.float32))
+    got = sk.quantize_pack(t, x)
+    assert got.dtype == jnp.uint16
+    np.testing.assert_array_equal(np.asarray(got),
+                                  np.asarray(quantize.compress(t, x)))
+    assert reg.snapshot()["counters"].get(key, 0) == before + 1
 
 
 def test_quantize_pack_ef_update_folds_the_residual_scatter(rng):
@@ -368,29 +347,23 @@ def test_resolve_impl_env_gates(monkeypatch):
         sk.resolve_impl("no_such_kernel")
 
 
-def test_missing_pallas_degrades_to_reference(monkeypatch, rng):
-    """The core/compat satellite: a jax pin with no pallas modules
-    resolves every kernel to the XLA reference — interpret mode included
-    — instead of ImportError."""
-    monkeypatch.setenv(sk.ENV_FLAG, "interpret")
-    monkeypatch.setattr(sk, "pallas_modules", lambda: (None, None))
-    assert sk.resolve_impl("dedup_ids") == "xla"
-    assert sk.resolve_impl("merge_apply") == "xla"
-    ids = jnp.asarray(rng.integers(0, 9, size=50).astype(np.int32))
-    u, inv, c = sk.dedup_ids(ids)     # must not raise
-    uu, ii = jnp.unique(ids, return_inverse=True, size=50, fill_value=0)
-    np.testing.assert_array_equal(np.asarray(u), np.asarray(uu))
+def test_auto_on_tpu_skips_deselected_kernels(monkeypatch):
+    """On a TPU ``auto`` compiles every kernel EXCEPT the ones the
+    registry deselects by name (with the compiler's reason); forcing
+    ``pallas`` still reaches them."""
+    import lightctr_tpu.nn.flash_attention    # noqa: F401 (self-registers)
+    import lightctr_tpu.optim.fused_adagrad   # noqa: F401
 
-
-def test_compat_compiler_params_degrade(monkeypatch):
-    """tpu_compiler_params returns the pallas_call default (None) when
-    the pin lacks pltpu entirely — the shim the ISSUE's small fix asks
-    for, beyond the CompilerParams rename it already covered."""
-    from lightctr_tpu.core import compat
-
-    monkeypatch.setattr(compat, "pallas_modules", lambda: (None, None))
-    assert compat.tpu_compiler_params(dimension_semantics=("parallel",)) \
-        is None
+    monkeypatch.delenv(sk.ENV_FLAG, raising=False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    picks = {name: sk.resolve_impl(name) for name in sk.KERNELS}
+    for name, kd in sk.KERNELS.items():
+        assert picks[name] == ("xla" if kd.deselected else "pallas"), name
+        assert kd.deselected is None or len(kd.deselected) > 20
+    assert picks["dedup_ids"] == picks["merge_apply"] == "xla"
+    assert picks["quantize_pack"] == picks["flash_attention"] == "pallas"
+    monkeypatch.setenv(sk.ENV_FLAG, "pallas")
+    assert sk.resolve_impl("dedup_ids") == "pallas"
 
 
 def test_dispatch_counts_kernel_path(monkeypatch, rng):

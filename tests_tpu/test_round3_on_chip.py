@@ -12,16 +12,9 @@ multi-chip behavior is covered on the virtual mesh and by dryrun_multichip).
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
-
-
-def _require_tpu():
-    if jax.devices()[0].platform == "cpu":
-        pytest.skip("needs an accelerator")
 
 
 def test_compressed_ring_trainer_compiles_on_chip():
-    _require_tpu()
     from lightctr_tpu import TrainConfig
     from lightctr_tpu.core.mesh import MeshSpec, make_mesh
     from lightctr_tpu.models import fm
@@ -56,7 +49,6 @@ def test_compressed_ring_trainer_compiles_on_chip():
 
 
 def test_sparse_sharded_trainer_compiles_on_chip():
-    _require_tpu()
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from lightctr_tpu import TrainConfig
@@ -98,7 +90,6 @@ def test_sparse_sharded_trainer_compiles_on_chip():
 
 
 def test_deepfm_dcn_compile_on_chip():
-    _require_tpu()
     from lightctr_tpu import TrainConfig
     from lightctr_tpu.models import deepfm, widedeep
     from lightctr_tpu.models.ctr_trainer import CTRTrainer

@@ -33,7 +33,7 @@ fold-in + gate automatically after writing their artifact, so a bench
 run refuses to quietly land a regression in its own trajectory.
 
 Usage:
-    python tools/bench_history.py fold BENCH_r05.json --run r05
+    python tools/bench_history.py fold SERVE_BENCH.json --run r06
     python tools/bench_history.py fold TIERED_BENCH.json
     python tools/bench_history.py gate --max-regress 0.2 --window 5
 """

@@ -26,6 +26,12 @@ This tool composes the whole story under real process-level faults:
 
 Run: ``python -m tools.chaos_harness [--scenario all] [--steps 30]``
 Progress goes to stderr; stdout is the ``CHAOS_HARNESS.json`` artifact.
+
+Host-side tool: the launcher pins the CPU platform
+(``utils.devicecheck.pin_cpu_platform``) before it starts a worker, the
+workers inherit the pin, and nothing here touches an accelerator — a chip
+belongs to one process at a time, so a launcher that held it would starve
+its own children.
 """
 
 from __future__ import annotations
@@ -587,6 +593,10 @@ def parity(report: dict, baseline: dict, tol: float = 5e-3) -> dict:
 
 
 def main(argv=None):
+    from lightctr_tpu.utils.devicecheck import pin_cpu_platform
+
+    pin_cpu_platform(1)
+
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--scenario", default="all",
                     help=f"one of {SCENARIOS + ('all', 'none')}")

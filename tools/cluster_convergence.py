@@ -24,6 +24,12 @@ SSP convergence); this tool proves the TOPOLOGY:
 Run:  python -m tools.cluster_convergence [--workers 4] [--epochs 30]
 Without ``--data`` and without the reference mounted, a learnable synthetic
 libffm file is generated (``lightctr_tpu.data.synth``).
+
+Host-side tool: the launcher pins the CPU platform
+(``utils.devicecheck.pin_cpu_platform``) before it starts a worker, the
+workers inherit the pin, and nothing here touches an accelerator — a chip
+belongs to one process at a time, so a launcher that held it would starve
+its own children.
 """
 
 from __future__ import annotations

@@ -18,6 +18,12 @@ PS-trained model (must beat the 0.82 bar set by the single-process
 rehearsal, CRITEO_SCALE.json).
 
 Run:  python -m tools.criteo_ps_soak [--rows 98304] [--workers 4]
+
+Host-side tool: the launcher pins the CPU platform
+(``utils.devicecheck.pin_cpu_platform``) before it starts a worker, the
+workers inherit the pin, and nothing here touches an accelerator — a chip
+belongs to one process at a time, so a launcher that held it would starve
+its own children.
 """
 
 from __future__ import annotations

@@ -30,6 +30,12 @@ Workers:
 Run:  python -m tools.ps_convergence --workers 4 --epochs 30
 Emits PS_CONVERGENCE.json: per-worker loss curves + final PS-trained
 metrics vs the single-process baseline (the loss/accuracy-parity artifact).
+
+Host-side tool: the launcher pins the CPU platform
+(``utils.devicecheck.pin_cpu_platform``) before it starts a worker, the
+workers inherit the pin, and nothing here touches an accelerator — a chip
+belongs to one process at a time, so a launcher that held it would starve
+its own children.
 """
 
 from __future__ import annotations

@@ -140,52 +140,6 @@ def run(steps: int = 20, out: str = "SPARSE_KERNEL_BENCH.json",
           f"{cells[-1]['t_fused_ms']}ms {cells[-1]['impl_fused']}",
           file=sys.stderr, flush=True)
 
-    # -- apply row-blocking A/B: 1 vs N rows per grid step ---------------
-    # Both sides are the PALLAS kernel (the reference has no grid), so the
-    # A/B runs under whatever pallas-capable mode is available: compiled
-    # Mosaic on a real TPU, the interpreter elsewhere (honestly labeled —
-    # it measures the grid-step overhead the blocking amortizes, which is
-    # exactly the quantity the variant exists to cut).
-    sb = 512 if interp else 2048
-    ub = np.zeros(sb, np.int64)
-    uq = np.unique(r.integers(1, tv, size=sb))
-    ub[:uq.size] = uq
-    pre_merged = np.zeros((sb, dim), np.float32)
-    pre_merged[:uq.size] = r.normal(size=(uq.size, dim))
-    uids_b, rows_b = jnp.asarray(ub), jnp.asarray(pre_merged)
-    ab_impl = sk.resolve_impl("merge_apply")
-    if ab_impl == "xla":
-        ab_impl = "interpret"  # the knob only exists on the pallas path
-
-    def _apply_at(rows_per_step: int) -> float:
-        os.environ[sk.APPLY_ROWS_ENV] = str(rows_per_step)
-        try:
-            fn = jax.jit(lambda t, a, g: sk.KERNELS["merge_apply"].pallas(
-                t, a, uids_b, g, None, 0.05, 1e-7, 1.0,
-                interpret=(ab_impl == "interpret")))
-            return _timeit(lambda: fn(table, accum, rows_b), steps)
-        finally:
-            del os.environ[sk.APPLY_ROWS_ENV]
-
-    t_row = _apply_at(1)
-    t_block = _apply_at(8)
-    cells.append({
-        "phase": "apply",
-        "kernel": "merge_apply",
-        "shape": f"S={sb} pre-merged rows of [{tv}, {dim}] (inv=None)",
-        "variant": "rows_per_step: 1 (windowed) vs 8 (row-block)",
-        "impl": ab_impl,
-        "t_row_ms": round(t_row, 4),
-        "t_block_ms": round(t_block, 4),
-        "block_speedup_x": round(t_row / max(t_block, 1e-9), 3),
-        **({"warning": "interpret mode times the CORRECTNESS path — the "
-                       "compiled-Mosaic column of this A/B must come from "
-                       "a real-TPU run"}
-           if ab_impl == "interpret" else {}),
-    })
-    print(f"apply row-block: {t_row:.2f}ms rb=1 vs {t_block:.2f}ms rb=8 "
-          f"({ab_impl})", file=sys.stderr, flush=True)
-
     # -- quantize pack: the coded-collective payload encode --------------
     p = (2048, dim) if interp else (16384, dim)
     payload = jnp.asarray((0.1 * r.normal(size=p)).astype(np.float32))
