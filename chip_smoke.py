@@ -28,9 +28,10 @@ vocabulary 2^20, embedding dim 32, hidden 64, batch 4096):
 It refuses to run without a TPU, falls back to nothing, and exits non-zero
 when any phase fails.  Seconds printed here are set-up facts (is it
 compiled, does a step take milliseconds or minutes), not performance
-numbers: the benchmark is ``bench.py``'s job.  The last line of standard
-output is one JSON object, ``{"ok": true, "device": {...}, ..., "claim":
-null}``.
+numbers: the benchmark is ``bench.py``'s job.  The facts of the run go out
+as one ``[summary] {..., "claim": null}`` line; the LAST line of standard
+output is exactly ``{"ok": true, "device": {"platform": "tpu", "kind": "...",
+"count": N}}`` and nothing else, printed only when every phase passed.
 
     python chip_smoke.py            # on a machine with a TPU
 
@@ -123,6 +124,16 @@ def require_tpu() -> Dict:
 def _bytes_in_use(dev) -> Optional[int]:
     stats = dev.memory_stats()
     return None if stats is None else int(stats["bytes_in_use"])
+
+
+def result_line(device: Dict) -> str:
+    """The last line of standard output: these keys and no others."""
+    return json.dumps({
+        "ok": True,
+        "device": {"platform": str(device["platform"]),
+                   "kind": str(device["kind"]),
+                   "count": int(device["count"])},
+    })
 
 
 # -- data ---------------------------------------------------------------------
@@ -696,9 +707,7 @@ def main() -> int:
                 "the two four-device layouts need 4")
     log(f"[done] all phases passed in {time.perf_counter() - t_start:.1f}s "
         "(set-up fact)")
-    print(json.dumps({
-        "ok": True,
-        "device": {k: device[k] for k in ("platform", "kind", "count")},
+    print("[summary] " + json.dumps({
         "versions": {k: device[k] for k in ("jax", "jaxlib", "libtpu")},
         "shape": dataclasses.asdict(shape),
         "compile_cache": {"dir": cache_dir,
@@ -706,6 +715,7 @@ def main() -> int:
         **summary,
         "claim": None,
     }), flush=True)
+    print(result_line(device), flush=True)
     return 0
 
 

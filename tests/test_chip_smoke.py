@@ -5,6 +5,7 @@ Pallas -> Mosaic lowering at a small and at the trainer's shape, which needs
 no chip (``lower(lowering_platforms=("tpu",))`` lowers, it never compiles or
 executes)."""
 
+import json
 import os
 import subprocess
 import sys
@@ -68,6 +69,15 @@ def test_script_refuses_to_run_off_a_tpu():
     assert proc.returncode != 0
     assert "'cpu'" in proc.stderr and "nothing was run" in proc.stderr
     assert proc.stdout == ""  # no phase ran, no result was printed
+
+
+def test_result_line_has_the_contract_keys_and_no_others():
+    facts = dict(chip_smoke.device_facts(), platform="tpu")
+    out = json.loads(chip_smoke.result_line(facts))
+    assert out == {"ok": True, "device": {
+        "platform": "tpu", "kind": facts["kind"], "count": len(jax.devices())}}
+    assert isinstance(out["device"]["count"], int)
+    assert "\n" not in chip_smoke.result_line(facts)
 
 
 def test_compile_cache_helper_places_the_cache(monkeypatch):
