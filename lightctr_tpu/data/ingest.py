@@ -64,6 +64,7 @@ from lightctr_tpu.data.streaming import (
 )
 from lightctr_tpu.native import bindings
 from lightctr_tpu.obs import resources as resources_mod
+from lightctr_tpu.obs import trace as trace_mod
 
 #: every metric series the compiled data plane writes (lint-enforced
 #: exact in tests/test_obs.py — no dark ingest counters)
@@ -966,16 +967,29 @@ def prefetch_batches(
         name, capacity=depth, registry=reg, monitor=monitor)
     stop_evt = threading.Event()
 
+    span = trace_mod.span
+    done = object()
+
     def _worker():
+        source = iter(inner)
         try:
-            for item in inner:
-                out = prepare(item) if prepare is not None else item
-                while not stop_evt.is_set():
-                    try:
-                        q.put((0, out), timeout=0.1)
-                        break
-                    except queue_mod.Full:
-                        continue
+            while True:
+                # the producer's time per batch: the source's next item
+                # (shard slice, shuffle, the model's host layout) plus
+                # ``prepare`` — a root span on this thread
+                with span("ingest/produce"):
+                    out = next(source, done)
+                    if out is not done and prepare is not None:
+                        out = prepare(out)
+                if out is done:
+                    break
+                with span("ingest/put_wait"):  # blocked on the full queue
+                    while not stop_evt.is_set():
+                        try:
+                            q.put((0, out), timeout=0.1)
+                            break
+                        except queue_mod.Full:
+                            continue
                 if stop_evt.is_set():
                     return
                 iq.note_enqueue()
@@ -1003,14 +1017,15 @@ def prefetch_batches(
     try:
         while True:
             t0 = time.perf_counter()
-            try:
-                kind, item = q.get_nowait()
-                waited = 0.0
-                was_ready = True
-            except queue_mod.Empty:
-                was_ready = False
-                kind, item = q.get()
-                waited = time.perf_counter() - t0
+            with span("ingest/get_wait"):
+                try:
+                    kind, item = q.get_nowait()
+                    waited = 0.0
+                    was_ready = True
+                except queue_mod.Empty:
+                    was_ready = False
+                    kind, item = q.get()
+                    waited = time.perf_counter() - t0
             iq.set_depth(q.qsize())
             if kind == 1:
                 return

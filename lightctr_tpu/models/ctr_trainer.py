@@ -628,47 +628,30 @@ class CTRTrainer:
                 self.params, self.opt_state, dev_batch
             )
             return loss
-        if trace_mod.enabled():
-            # separate path so the default (tracing-off) step pays exactly
-            # one extra branch — the overhead guard measures this path
-            return self._train_step_traced(batch, device_ready=device_ready)
+        # ONE instrumented body.  Each span is the shared null context
+        # unless a root is taken (sampling rate, or a recording profiler
+        # session): then the step is a tree in the span ring and on the
+        # profiler's host timeline, beside the device's ops
+        span = trace_mod.span
         t0 = time.perf_counter()
         sw = self.stepwatch
-        if sw is not None:
-            sw.mark("input")
-        dev_batch = batch if device_ready else self._put(batch)
-        if sw is not None:
-            sw.mark("exec")
-        self.params, self.opt_state, loss, health = self._step(
-            self.params, self.opt_state, dev_batch
-        )
-        self._record_step(time.perf_counter() - t0, dev_batch,
-                          health=health)
-        return loss
-
-    def _train_step_traced(self, batch: Dict[str, np.ndarray], *,
-                           device_ready: bool = False) -> float:
-        """Phase-spanned step: ``annotate`` puts the same names on the XLA
-        profiler timeline and the wire trace (obs/trace.py), and any PS
-        RPC issued under these phases stitches into this step's trace via
-        the wire trace header.  The sparse trainer's jit-time phases
-        (``sparse_tables/dedup_gather`` / ``sparse_exchange`` / ``apply``)
-        appear under ``trainer/exec`` on the first (tracing) step."""
-        t0 = time.perf_counter()
-        sw = self.stepwatch
-        with annotate("trainer/step", step=self._steps_seen + 1):
-            with annotate("trainer/input"):
+        with span("trainer/step", step=self._steps_seen + 1):
+            with span("trainer/input"):
                 if sw is not None:
                     sw.mark("input")
                 dev_batch = batch if device_ready else self._put(batch)
-            with annotate("trainer/exec"):
+            with span("trainer/exec"):
+                # dispatch of the jitted step, and any wait inside it (the
+                # runtime blocks the host once its queue of dispatched
+                # steps is full)
                 if sw is not None:
                     sw.mark("exec")
                 self.params, self.opt_state, loss, health = self._step(
                     self.params, self.opt_state, dev_batch
                 )
-        self._record_step(time.perf_counter() - t0, dev_batch,
-                          health=health)
+            with span("trainer/record"):
+                self._record_step(time.perf_counter() - t0, dev_batch,
+                                  health=health)
         return loss
 
     # -- telemetry ------------------------------------------------------
@@ -740,8 +723,14 @@ class CTRTrainer:
                 break
             self._observe_scalars(hm if on else None, pend.pop(0))
 
+    def _fetch_health(self, health) -> np.ndarray:
+        """The single host fetch of a queued health vector: the one place
+        the host waits for the device on purpose."""
+        with trace_mod.span("trainer/health_fetch"):
+            return np.asarray(health, np.float32)
+
     def _observe_scalars(self, hm, health) -> None:
-        vals = np.asarray(health, np.float32)  # the single host fetch
+        vals = self._fetch_health(health)
         if hm is not None:
             hm.observe(loss=float(vals[0]), grad_norm=float(vals[1]))
         self._feed_quality(vals, 2)

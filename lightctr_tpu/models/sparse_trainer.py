@@ -120,6 +120,7 @@ from lightctr_tpu.models.ctr_trainer import CTRTrainer, _health_pack
 from lightctr_tpu.obs import device as obs_device
 from lightctr_tpu.obs import health as health_mod
 from lightctr_tpu.obs import quality as quality_mod
+from lightctr_tpu.obs import trace as trace_mod
 from lightctr_tpu.ops.sparse_kernels import next_pow2 as _pow2_pad
 from lightctr_tpu.utils.profiling import annotate
 
@@ -1406,8 +1407,11 @@ class SparseTableCTRTrainer(CTRTrainer):
     def train_step(self, batch, **kw):
         self._last_step_fallback = False
         if self._hybrid_dp:
-            plan = self._exchange_plan(batch)
-            if not self._rs_batch_fits(batch, plan):
+            # host work on the batch's ids BEFORE the step: its own span,
+            # ahead of the trainer/step it plans for
+            with trace_mod.span("trainer/plan"):
+                fits = self._rs_batch_fits(batch, self._exchange_plan(batch))
+            if not fits:
                 self._last_step_fallback = True
                 self.telemetry.inc("trainer_rs_fallback_total")
                 primary, self._step = self._step, self._fallback_step_fn()
@@ -1481,7 +1485,7 @@ class SparseTableCTRTrainer(CTRTrainer):
         dropped; surface it loudly instead of silently.  Anything past
         the head scalars is the quality sketch (when armed), so the
         overflow slot is addressed by step family, not by length."""
-        vals = np.asarray(health, np.float32)
+        vals = self._fetch_health(health)
         if hm is not None:
             hm.observe(loss=float(vals[0]), grad_norm=float(vals[1]))
         head = 3 if (self._hybrid_dp or self._hier) else 2

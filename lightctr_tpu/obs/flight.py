@@ -131,7 +131,13 @@ def dump(reason: str, dir: Optional[str] = None) -> Optional[str]:
     """Write one flight bundle; returns its path (None on failure, or
     when COALESCED with a dump already in progress).  Safe to call from
     signal handlers, excepthooks, and health-anomaly triggers — never
-    raises."""
+    raises.  Its own span (file I/O on the caller's thread): a stall it
+    causes shows under the caller as ``flight/dump``."""
+    with trace_mod.span("flight/dump", reason=str(reason)):
+        return _dump(reason, dir)
+
+
+def _dump(reason: str, dir: Optional[str]) -> Optional[str]:
     if not _dump_lock.acquire(blocking=False):
         _coalesced[0] += 1
         return None

@@ -46,6 +46,7 @@ from typing import Callable, Dict, Optional, Tuple
 from lightctr_tpu.obs import events as events_mod
 from lightctr_tpu.obs import flight as flight_mod
 from lightctr_tpu.obs import gate
+from lightctr_tpu.obs import trace as trace_mod
 from lightctr_tpu.obs.registry import MetricsRegistry, default_registry, labeled
 
 _LOG = logging.getLogger(__name__)
@@ -702,6 +703,12 @@ class HealthMonitor:
         raises — a detector bug must not kill the training step."""
         if not signals or not enabled():
             return
+        # its own span, so that a slow detector or a flight dump on a
+        # transition shows under the caller as a child, not as self time
+        with trace_mod.span("health/observe"):
+            self._observe(signals)
+
+    def _observe(self, signals: Dict) -> None:
         transitions = []
         with self._lock:
             self.observations += 1

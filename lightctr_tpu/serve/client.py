@@ -27,9 +27,7 @@ from lightctr_tpu.dist.ps_server import (
     _recv_msg,
     _send_msg,
 )
-from lightctr_tpu.obs import gate as obs_gate
 from lightctr_tpu.obs import trace as obs_trace
-from lightctr_tpu.obs.registry import default_registry
 from lightctr_tpu.serve.server import STATUS_OK, STATUS_OVERLOADED
 
 
@@ -81,8 +79,6 @@ class PredictClient:
             reply = self._rpc(op, payload)
         if reply[:1] == STATUS_OVERLOADED:
             self.overloaded += 1
-            if obs_gate.enabled():
-                default_registry().inc("serve_client_overloaded_total")
             raise ServerOverloaded(
                 f"server {self.address} shed a {b}-row predict"
             )

@@ -71,6 +71,7 @@ from lightctr_tpu.obs.registry import (
     histogram_quantile,
     labeled,
 )
+from lightctr_tpu.ops.sparse_kernels import next_pow2 as _next_pow2
 from lightctr_tpu.serve.cache import HotEmbeddingCache
 
 _LOG = logging.getLogger(__name__)
@@ -88,13 +89,18 @@ class _Pending:
     thread blocks on until the scorer distributes results."""
 
     __slots__ = ("arrays", "n", "t_in", "deadline", "event", "scores",
-                 "status")
+                 "status", "ctx", "t_in_ns")
 
     def __init__(self, arrays: Dict, n: int, t_in: float, deadline: float):
         self.arrays = arrays
         self.n = n
         self.t_in = t_in
         self.deadline = deadline
+        # the request's span context (None unless its trace is recorded)
+        # and its arrival on the span clock: the scorer thread records the
+        # ``serve/queue_wait`` interval this thread only starts
+        self.ctx = obs_trace.current_context()
+        self.t_in_ns = time.time_ns() if self.ctx is not None else 0
         self.event = threading.Event()
         self.scores: Optional[np.ndarray] = None
         self.status = "pending"   # -> ok | shed | error
@@ -253,21 +259,16 @@ class PredictionServer:
 
     def _serve(self, conn: socket.socket):
         reg = self.registry
-        out_count = [0]
-
-        def send(data: bytes) -> None:
-            conn.sendall(data)
-            out_count[0] += len(data)
+        span = obs_trace.span
 
         def reply(body: bytes) -> None:
-            send(struct.pack("<IB", len(body), 0) + body)
+            conn.sendall(struct.pack("<IB", len(body), 0) + body)
 
         try:
             while True:
                 raw_type, payload = _recv_msg(conn,
                                               cap=MAX_PREDICT_FRAME_BYTES)
                 msg_type = raw_type & ~wire.TRACE_FLAG & 0xFF
-                frame_bytes = 5 + len(payload)
                 telem = obs_gate.enabled()
                 t0 = time.perf_counter() if telem else 0.0
                 try:
@@ -275,42 +276,48 @@ class PredictionServer:
                     if raw_type & wire.TRACE_FLAG:
                         rctx, used = wire.split_trace_ctx(payload)
                         payload = payload[used:]
-                    span_cm = contextlib.nullcontext()
-                    if msg_type != MSG_CLOSE and (
-                            rctx is not None or obs_trace.enabled()):
-                        span_cm = obs_trace.span(
+                    # the frame's span: a continuation of the client's
+                    # trace where the frame carries one, else a root
+                    # (taken, like every root, only while tracing is on)
+                    span_cm = (
+                        obs_trace.span(
                             "serve/" + _OP_NAMES.get(msg_type, "unknown"),
-                            remote=rctx, n_bytes=len(payload),
-                        )
+                            remote=rctx, n_bytes=len(payload))
+                        if msg_type != MSG_CLOSE else contextlib.nullcontext()
+                    )
                     with span_cm:
                         if msg_type in (MSG_PREDICT, MSG_PREDICT_BATCH):
-                            arrays, used = wire.unpack_predict_batch(payload)
-                            if used != len(payload):
-                                raise ValueError(
-                                    f"predict frame length mismatch: "
-                                    f"{used} of {len(payload)} bytes"
-                                )
-                            # layout validation AT ADMISSION: a frame that
-                            # does not match this model rejects alone (its
-                            # connection's protocol error) instead of
-                            # poisoning the micro-batch it would join
-                            arrays = self.model.canonicalize_request(arrays)
-                            n = int(arrays["fids"].shape[0])
-                            if msg_type == MSG_PREDICT and n != 1:
-                                raise ValueError(
-                                    f"MSG_PREDICT carries one row, got {n}"
-                                    " (use MSG_PREDICT_BATCH)"
-                                )
+                            with span("serve/decode"):
+                                arrays, used = wire.unpack_predict_batch(
+                                    payload)
+                                if used != len(payload):
+                                    raise ValueError(
+                                        f"predict frame length mismatch: "
+                                        f"{used} of {len(payload)} bytes"
+                                    )
+                                # layout validation AT ADMISSION: a frame
+                                # that does not match this model rejects
+                                # alone (its connection's protocol error)
+                                # instead of poisoning the micro-batch it
+                                # would join
+                                arrays = self.model.canonicalize_request(
+                                    arrays)
+                                n = int(arrays["fids"].shape[0])
+                                if msg_type == MSG_PREDICT and n != 1:
+                                    raise ValueError(
+                                        f"MSG_PREDICT carries one row, got "
+                                        f"{n} (use MSG_PREDICT_BATCH)"
+                                    )
                             item = self._admit(arrays, n)
                             if item is None:
                                 self._shed("queue_full", n)
-                                reply(STATUS_OVERLOADED)
                             else:
                                 # generous rendezvous bound: the scorer
                                 # sheds on the DEADLINE; this only guards
                                 # against a wedged scorer thread
                                 item.event.wait(self.deadline_s + 30.0)
-                                if item.status == "ok":
+                            with span("serve/reply"):
+                                if item is not None and item.status == "ok":
                                     reply(STATUS_OK
                                           + wire.pack_values(item.scores)[0])
                                 else:
@@ -329,9 +336,6 @@ class PredictionServer:
                             reg.inc(labeled("serve_requests_total", op=op))
                             reg.observe(labeled("serve_op_seconds", op=op),
                                         time.perf_counter() - t0)
-                            reg.inc("serve_bytes_received_total", frame_bytes)
-                            reg.inc("serve_bytes_sent_total", out_count[0])
-                            out_count[0] = 0
                 except (ValueError, struct.error):
                     reply(b"\xff")
                     if telem:
@@ -344,14 +348,20 @@ class PredictionServer:
 
     # -- the scorer ---------------------------------------------------------
 
-    def _collect(self) -> List[_Pending]:
+    def _collect(self):
         """Block for the first request, then gather up to ``max_batch``
-        rows, waiting at most ``max_wait_s`` past the first arrival."""
-        with self._cond:
+        rows, waiting at most ``max_wait_s`` past the first arrival:
+        ``(batch, rows left queued)``.  Two spans, each around its own
+        hold of the queue lock (only this thread pops, so the queue can
+        only grow between them): ``serve/collect_idle`` is the wait for
+        work and no cost, ``serve/collect_fill`` the batching delay."""
+        span = obs_trace.span
+        with span("serve/collect_idle"), self._cond:
             while not self._queue and not self._stop.is_set():
                 self._cond.wait(timeout=0.1)
             if self._stop.is_set() and not self._queue:
-                return []
+                return [], 0
+        with span("serve/collect_fill"), self._cond:
             t_limit = time.monotonic() + self.max_wait_s
             while (sum(i.n for i in self._queue) < self.max_batch
                    and not self._stop.is_set()):
@@ -371,12 +381,7 @@ class PredictionServer:
             depth = self._queue_rows
             if obs_gate.enabled():
                 self.registry.gauge_set("serve_queue_rows", depth)
-        if batch:
-            now = time.monotonic()
-            for item in batch:
-                self._rq.note_wait(now - item.t_in)
-        self._rq.set_depth(depth)
-        return batch
+        return batch, depth
 
     @staticmethod
     def _concat(items: List[_Pending]) -> Dict:
@@ -402,10 +407,10 @@ class PredictionServer:
         while not self._stop.is_set():
             batch: List[_Pending] = []
             try:
-                batch = self._collect()
+                batch, depth = self._collect()
                 if not batch:
                     continue
-                self._score_batch(batch)
+                self._score_batch(batch, depth)
             except Exception:
                 # the scorer must survive anything — fail the in-flight
                 # requests, keep serving the next batch
@@ -415,66 +420,97 @@ class PredictionServer:
                         item.status = "error"
                         item.event.set()
 
-    def _score_batch(self, batch: List[_Pending]) -> None:
+    def _score_batch(self, batch: List[_Pending], depth: int) -> None:
+        """One cycle's work on a popped batch: every stage is a child of
+        ``serve/batch``, so whatever holds this thread has a name."""
         reg = self.registry
         telem = obs_gate.enabled()
+        span = obs_trace.span
         now = time.monotonic()
-        live: List[_Pending] = []
-        for item in batch:
-            if now > item.deadline:
-                # its caller's budget is spent: scoring it would only tax
-                # the requests behind it (deadline-aware drop)
-                item.status = "shed"
-                self._shed("deadline", item.n)
-                item.event.set()
-            else:
-                live.append(item)
-        if not live:
-            return
-        arrays = self._concat(live)
-        n_rows = int(arrays["fids"].shape[0])
-        t0 = time.perf_counter()
-        if self.score_delay_s:
-            time.sleep(self.score_delay_s)
-        try:
-            with obs_trace.span("serve/score", rows=n_rows,
-                                requests=len(live)):
-                if self.model.row_leaves:
-                    scores = self._score_ps_backed(arrays)
-                else:
-                    scores = self.model.score(arrays)
-        except (ConnectionError, OSError, RuntimeError, ValueError):
-            _LOG.warning("serve batch failed (PS unreachable?)",
-                         exc_info=True)
-            for item in live:
-                item.status = "error"
-                self._shed("backend_error", item.n)
-                item.event.set()
-            return
-        dt = time.perf_counter() - t0
-        ofs = 0
-        t_done = time.monotonic()
-        for item in live:
-            item.scores = scores[ofs:ofs + item.n]
-            ofs += item.n
-            item.status = "ok"
+        popped_ns = time.time_ns()
+        with span("serve/batch", requests=len(batch)) as sp:
+            # each recorded request's wait, admit -> popped, under ITS
+            # trace and carrying THIS batch's id
+            bid = f"{sp.span_id:016x}" if sp is not None else None
+            for item in batch:
+                if item.ctx is not None:
+                    obs_trace.record("serve/queue_wait", item.t_in_ns,
+                                     popped_ns, item.ctx, batch=bid)
+            with span("serve/telemetry"):
+                # the resource plane's feed: the saturation detector can
+                # trip here, and a trip is a flight dump (file I/O)
+                for item in batch:
+                    self._rq.note_wait(now - item.t_in)
+                self._rq.set_depth(depth)
+            live: List[_Pending] = []
+            with span("serve/shed_scan"):
+                for item in batch:
+                    if now > item.deadline:
+                        # its caller's budget is spent: scoring it would
+                        # only tax the requests behind it (deadline-aware
+                        # drop)
+                        item.status = "shed"
+                        self._shed("deadline", item.n)
+                        item.event.set()
+                    else:
+                        live.append(item)
+            if not live:
+                return
+            with span("serve/concat"):
+                arrays = self._concat(live)
+            n_rows = int(arrays["fids"].shape[0])
+            if sp is not None:
+                sp.set(rows=n_rows, padded_rows=_next_pow2(n_rows))
+            t0 = time.perf_counter()
+            if self.score_delay_s:
+                time.sleep(self.score_delay_s)
+            try:
+                with span("serve/score", rows=n_rows, requests=len(live)):
+                    if self.model.row_leaves:
+                        scores = self._score_ps_backed(arrays)
+                    else:
+                        scores = self.model.score(arrays)
+            except (ConnectionError, OSError, RuntimeError, ValueError):
+                _LOG.warning("serve batch failed (PS unreachable?)",
+                             exc_info=True)
+                for item in live:
+                    item.status = "error"
+                    self._shed("backend_error", item.n)
+                    item.event.set()
+                return
+            dt = time.perf_counter() - t0
+            with span("serve/scatter"):
+                ofs = 0
+                for item in live:
+                    item.scores = scores[ofs:ofs + item.n]
+                    ofs += item.n
+                    item.status = "ok"
+                    item.event.set()
+            t_done = time.monotonic()
             if telem:
-                reg.observe("serve_predict_seconds", t_done - item.t_in)
-            item.event.set()
-        if telem:
-            reg.inc("serve_batches_total")
-            reg.inc("serve_scored_rows_total", n_rows)
-            reg.observe("serve_batch_rows", float(n_rows),
-                        buckets=_BATCH_BUCKETS)
-            reg.observe("serve_score_seconds", dt)
-        self._batches_scored += 1
-        if self.drift is not None:
-            self._feed_drift(arrays, scores)
-        if self._batches_scored % self._slo_feed_every == 0:
-            self._feed_slo()
-        if (self.ps is not None and self.version_poll_s
-                and t_done - self._last_version_poll > self.version_poll_s):
-            self.refresh_version()
+                with span("serve/telemetry"):
+                    for item in live:
+                        reg.observe("serve_predict_seconds",
+                                    t_done - item.t_in)
+                    reg.inc("serve_batches_total")
+                    reg.inc("serve_scored_rows_total", n_rows)
+                    reg.observe("serve_batch_rows", float(n_rows),
+                                buckets=_BATCH_BUCKETS)
+                    reg.observe("serve_score_seconds", dt)
+            self._batches_scored += 1
+            # spans at the CALL sites: whatever stands in for these
+            # methods is covered too
+            if self.drift is not None:
+                with span("serve/feed_drift"):
+                    self._feed_drift(arrays, scores)
+            if self._batches_scored % self._slo_feed_every == 0:
+                with span("serve/feed_slo"):
+                    self._feed_slo()
+            if (self.ps is not None and self.version_poll_s
+                    and t_done - self._last_version_poll
+                    > self.version_poll_s):
+                with span("serve/refresh_version"):
+                    self.refresh_version()
 
     def _score_ps_backed(self, arrays: Dict) -> np.ndarray:
         """The hot sparse path: dedup -> cache -> pull misses -> score on

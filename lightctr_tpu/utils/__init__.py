@@ -1,10 +1,9 @@
-from lightctr_tpu.utils.profiling import annotate, trace, wall_clock
+from lightctr_tpu.utils.profiling import annotate, trace
 from lightctr_tpu.utils.system import host_memory_usage, device_memory_stats
 
 __all__ = [
     "annotate",
     "trace",
-    "wall_clock",
     "host_memory_usage",
     "device_memory_stats",
 ]

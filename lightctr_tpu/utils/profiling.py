@@ -5,21 +5,20 @@ counters (``clock_start``/``clock_cycles``, time.h:81-99) and DEBUG printf
 tracing; on TPU the right tool is ``jax.profiler`` traces viewed in
 Perfetto/TensorBoard.
 
-``trace(dir)`` wraps a region (and emits a ``trace_capture`` event through
-the obs event log so captures are discoverable from telemetry);
-``wall_clock()`` reproduces the reference's train-wall-clock counter pair;
-``annotate(name)`` tags a sub-region on EVERY timeline at once — the XLA
-profiler's host track, the HLO metadata, and the obs span tracer
-(obs/trace.py) — so a region carries the same name in a Perfetto device
-trace and in a cross-process wire trace.
+``trace(dir)`` wraps a region in a profiler session (and emits a
+``trace_capture`` event through the obs event log so captures are
+discoverable from telemetry); while it records, every ``obs.trace.span``
+of the program is an event on the profiler's host timeline, beside the
+device's ops.  ``annotate(name)`` is ``obs.trace.span`` plus
+``jax.named_scope``, for the regions whose body runs while ``jit`` traces
+(the name then reaches the HLO metadata too).
 """
 
 from __future__ import annotations
 
 import contextlib
 import logging
-import time
-from typing import Iterator, Optional
+from typing import Iterator
 
 from lightctr_tpu.obs import events as _events
 from lightctr_tpu.obs import trace as _trace
@@ -93,59 +92,24 @@ def trace(log_dir: str, create_perfetto_link: bool = False) -> Iterator[None]:
                          exc_info=True)
 
 
-class wall_clock:
-    """clock_start/clock_cycles parity (time.h:81-99): seconds since start.
-    As a context manager, the elapsed time freezes at block exit so a later
-    ``cycles()`` reports the timed region, not everything since."""
-
-    def __init__(self):
-        self._t0: Optional[float] = None
-        self._t1: Optional[float] = None
-
-    def start(self) -> None:
-        self._t0 = time.perf_counter()
-        self._t1 = None
-
-    def cycles(self) -> float:
-        if self._t0 is None:
-            raise RuntimeError("start() first")
-        end = self._t1 if self._t1 is not None else time.perf_counter()
-        return end - self._t0
-
-    def __enter__(self) -> "wall_clock":
-        self.start()
-        return self
-
-    def __exit__(self, *exc) -> None:
-        self._t1 = time.perf_counter()
-
-
 @contextlib.contextmanager
 def annotate(name: str, **attrs) -> Iterator[None]:
-    """Named sub-region for traces: tags ALL timelines — the host timeline
-    (``jax.profiler.TraceAnnotation``), the device/HLO metadata
-    (``jax.named_scope``, so the region name survives into compiled-program
-    profiles even though the body runs at trace time), and the obs span
-    tracer (a span when tracing is sampled, ``attrs`` attached) — one name
-    across XLA profiler traces and cross-process wire traces.
+    """Named sub-region for code that (also) runs while ``jit`` traces:
+    ``jax.named_scope`` puts the name into the HLO metadata, so it
+    survives into compiled-program profiles as the device ops' scope even
+    though the body runs at trace time; around it ``obs.trace.span`` does
+    what it does for every span (the ring, and a ``TraceAnnotation`` on
+    the profiler's host timeline while a session records).  Host-only
+    regions call ``obs.trace.span`` directly.
 
     No-op-safe: usable on CPU, inside ``jit`` tracing, and in processes
-    where jax (or its profiler) is unavailable — instrumented library code
-    must never crash because profiling isn't."""
-    jstack = contextlib.ExitStack()
+    where jax is unavailable — instrumented library code must never crash
+    because profiling isn't."""
     try:
         import jax
 
-        jstack.enter_context(jax.named_scope(name))
-        jstack.enter_context(jax.profiler.TraceAnnotation(name))
+        scope = jax.named_scope(name)
     except Exception:
-        # unwind whatever DID enter (a half-entered named_scope left open
-        # would push jax's thread-local name stack one level forever)
-        jstack.close()
-        jstack = None
-    try:
-        with _trace.span(name, **attrs):
-            yield
-    finally:
-        if jstack is not None:
-            jstack.close()
+        scope = contextlib.nullcontext()
+    with _trace.span(name, **attrs), scope:
+        yield

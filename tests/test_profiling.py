@@ -1,52 +1,15 @@
-"""utils/profiling: wall_clock freeze semantics, no-op-safe annotate (now
-also a span emitter), and trace()'s trace_capture event."""
-
-import time
+"""utils/profiling: no-op-safe annotate (obs.trace.span + named_scope) and
+trace()'s trace_capture event (tests/test_span_layer.py holds what a
+recording session does to spans)."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
 
 from lightctr_tpu import obs
 from lightctr_tpu.obs import trace as obs_trace
 from lightctr_tpu.utils import profiling
-from lightctr_tpu.utils.profiling import annotate, wall_clock
-
-
-def test_wall_clock_counts_elapsed():
-    w = wall_clock()
-    w.start()
-    time.sleep(0.02)
-    c = w.cycles()
-    assert c >= 0.015
-    # still running: a later read grows
-    time.sleep(0.01)
-    assert w.cycles() > c
-
-
-def test_wall_clock_freezes_at_context_exit():
-    with wall_clock() as w:
-        time.sleep(0.02)
-    frozen = w.cycles()
-    assert frozen >= 0.015
-    time.sleep(0.02)
-    # block exit froze the reading: it reports the timed region, not
-    # everything since (time.h:81-99 parity semantics)
-    assert w.cycles() == frozen
-
-
-def test_wall_clock_cycles_before_start_raises():
-    w = wall_clock()
-    with pytest.raises(RuntimeError):
-        w.cycles()
-
-
-def test_wall_clock_restart_resets():
-    with wall_clock() as w:
-        time.sleep(0.01)
-    w.start()
-    assert w.cycles() < 0.01  # the frozen end is cleared by start()
+from lightctr_tpu.utils.profiling import annotate
 
 
 def test_annotate_is_noop_safe_on_cpu():

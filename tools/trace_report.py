@@ -24,6 +24,11 @@ view:
       #    -> last pull satisfied) stitched from the hier client/shard
       #    spans that share trace context over the wire
 
+  python -m tools.trace_report SPANS.jsonl --stalls 50
+      # -> for every ``serve/queue_wait`` of 50 ms or more: what the
+      #    thread that popped the request (the scorer) was inside for
+      #    that interval, by innermost span, and what no span covers
+
 A directory argument expands to every ``trace-*.jsonl`` inside it (the
 per-process files one run leaves behind).  Reads are tolerant of torn
 tails — a crashed writer's half-line is skipped, not fatal.
@@ -241,6 +246,98 @@ def summarize_rounds(spans: List[Dict], epoch=None) -> Dict:
     return report
 
 
+#: the interval ``--stalls`` explains: recorded by the thread that ends it
+STALL_SPAN = "serve/queue_wait"
+
+
+def _interval_ns(s: Dict):
+    """A span's ``(start, end)`` in ns (older records carry only
+    ``ts`` / ``dur_s``)."""
+    if "start_ns" in s:
+        return int(s["start_ns"]), int(s["end_ns"])
+    start = int(float(s.get("ts", 0.0)) * 1e9)
+    return start, start + int(float(s.get("dur_s", 0.0)) * 1e9)
+
+
+def summarize_stalls(spans: List[Dict], min_ms: float, top: int = 10) -> Dict:
+    """For every ``serve/queue_wait`` of at least ``min_ms``: what the
+    thread that recorded it — the scorer, which popped the request — was
+    inside during the wait.  Each moment counts for the INNERMOST span
+    open on that thread (a span's time less its children's, both cut to
+    the interval); what no span covers is ``(no span)``: the thread was
+    in no instrumented region, i.e. between spans or not scheduled."""
+    import bisect
+
+    threads: Dict = {}
+    for s in spans:
+        if s["name"] != STALL_SPAN:
+            threads.setdefault((s.get("pid"), s.get("tid")), []).append(
+                _interval_ns(s) + (s,))
+    index = {}
+    for key, rows in threads.items():
+        rows.sort(key=lambda r: r[0])
+        index[key] = (rows, [r[0] for r in rows],
+                      max(r[1] - r[0] for r in rows))
+
+    def inside(key, t0: int, t1: int):
+        rows, starts, longest = index.get(key, ([], [], 0))
+        lo = bisect.bisect_left(starts, t0 - longest)
+        hi = bisect.bisect_right(starts, t1)
+        cut = {}        # span id -> (span, ns of it inside [t0, t1])
+        for a, b, s in rows[lo:hi]:
+            part = min(b, t1) - max(a, t0)
+            if part > 0:
+                cut[s["span"]] = (s, part)
+        own = {k: part for k, (_, part) in cut.items()}
+        covered = 0
+        for sid, (s, part) in cut.items():
+            if s.get("parent") in own:
+                own[s["parent"]] -= part
+            else:
+                covered += part
+        by_name: Dict[str, float] = {}
+        for sid, ns in own.items():
+            name = cut[sid][0]["name"]
+            by_name[name] = by_name.get(name, 0.0) + max(0, ns) / 1e6
+        by_name["(no span)"] = max(0, (t1 - t0) - covered) / 1e6
+        longest_in = sorted(cut.values(), key=lambda c: -c[1])[:3]
+        return by_name, [
+            {"name": s["name"], "dur_ms": round(float(s["dur_s"]) * 1e3, 3),
+             **({"attrs": s["attrs"]} if "attrs" in s else {})}
+            for s, _ in longest_in]
+
+    stalls, held_ms, held_n = [], {}, {}
+    waits = [s for s in spans if s["name"] == STALL_SPAN]
+    for w in waits:
+        t0, t1 = _interval_ns(w)
+        wait_ms = (t1 - t0) / 1e6
+        if wait_ms < min_ms:
+            continue
+        by_name, longest = inside((w.get("pid"), w.get("tid")), t0, t1)
+        ranked = sorted(by_name.items(), key=lambda kv: -kv[1])
+        for name, ms in ranked:
+            held_ms[name] = held_ms.get(name, 0.0) + ms
+        held_n[ranked[0][0]] = held_n.get(ranked[0][0], 0) + 1
+        stalls.append({
+            "trace": w.get("trace"), "wait_ms": round(wait_ms, 3),
+            "ts": w.get("ts"), "batch": (w.get("attrs") or {}).get("batch"),
+            "held_by": ranked[0][0],
+            "inside_ms": {k: round(v, 3) for k, v in ranked if v > 0},
+            "longest_spans": longest,
+        })
+    stalls.sort(key=lambda r: -r["wait_ms"])
+    return {
+        "stall_span": STALL_SPAN, "min_ms": min_ms,
+        "waits": len(waits), "stalls": len(stalls),
+        # how many stalls each span held the largest part of, and the
+        # milliseconds of all stalls spent inside each
+        "held_by": dict(sorted(held_n.items(), key=lambda kv: -kv[1])),
+        "inside_ms": {k: round(v, 3) for k, v in
+                      sorted(held_ms.items(), key=lambda kv: -kv[1]) if v > 0},
+        "worst": stalls[:top],
+    }
+
+
 def summarize_flight(path: str) -> Dict:
     """Flight bundle -> postmortem report."""
     recs = read_jsonl(path)
@@ -351,6 +448,9 @@ def main(argv=None) -> int:
     ap.add_argument("--rounds", action="store_true",
                     help="per-round hierarchical-exchange timelines: host "
                          "arrival offsets, straggler, critical path")
+    ap.add_argument("--stalls", type=float, metavar="MS", default=None,
+                    help="for every serve/queue_wait of MS or more: what "
+                         "the scorer thread was inside meanwhile")
     ap.add_argument("--epoch", type=int, default=None,
                     help="with --rounds: only this rendezvous epoch")
     ap.add_argument("--top", type=int, default=10,
@@ -364,6 +464,11 @@ def main(argv=None) -> int:
         if not args.paths:
             ap.error("--rounds needs span JSONL paths/directories")
         report = summarize_rounds(load_spans(args.paths), epoch=args.epoch)
+    elif args.stalls is not None:
+        if not args.paths:
+            ap.error("--stalls needs span JSONL paths/directories")
+        report = summarize_stalls(load_spans(args.paths), args.stalls,
+                                  top=args.top)
     else:
         if not args.paths:
             ap.error("give span JSONL paths/directories, or --flight BUNDLE")
