@@ -177,6 +177,11 @@ EXCHANGE_SERIES = (
     "trainer_tiered_fast_rows_total",    # rows through the aliased apply
     "trainer_tiered_pushed_rows_total",  # non-resident rows via push_batch
     "trainer_tiered_stale_tickets_total",  # adopt refused: residency moved
+    # the sized XLA apply (ops.sparse_kernels.apply_ladder): live rows a
+    # step against the slots of the rung it took; their ratio is the live
+    # share (metrics_report --kernels)
+    "trainer_apply_live_rows_total",     # {table}
+    "trainer_apply_slots_total",         # {table}
 )
 
 
@@ -288,6 +293,9 @@ class SparseTableCTRTrainer(CTRTrainer):
         # JSONs cannot disagree
         self.exchange_bytes_per_step: Dict[str, int] = {}
         self._exchange_logged = False
+        # the step's per-table distinct counts, handed from
+        # _health_signals to _count_apply_slots
+        self._last_touch = None
         # reduce-scatter capacity safety net: rs capacities are EXPECTED
         # sizes with slack (dist.collectives.rs_default_caps), so every
         # batch is checked HOST-side (rs_fits) before dispatch and one
@@ -448,9 +456,11 @@ class SparseTableCTRTrainer(CTRTrainer):
     @staticmethod
     def _dedup_and_gather(spec, params, batch):
         """Steps 1-3 of the module recipe: per-table batch-id dedup,
-        position rewrite, and the O(touched) row gather.  Shared by the
-        single-program step and the per-replica hybrid step (where
-        ``batch`` is the replica's local shard).
+        position rewrite, and the O(touched) row gather — of the live
+        prefix of the slots, on the apply's ladder
+        (``sparse_kernels.gather_live``).  Shared by the single-program
+        step and the per-replica hybrid step (where ``batch`` is the
+        replica's local shard).
 
         Tables listing the IDENTICAL field tuple run the dedup once and
         share the resulting ``(uids, inv)`` — their position rewrites
@@ -481,7 +491,15 @@ class SparseTableCTRTrainer(CTRTrainer):
                     m = batch[f].size
                     batch2[f] = inv[ofs:ofs + m].reshape(batch[f].shape)
                     ofs += m
-            rows = {k: jnp.take(tables[k], uids[k], axis=0) for k in spec}
+            # the forward gather reads the live prefix of the slots, on
+            # the apply's ladder; the rows behind the rung are zeros, and
+            # ``inv`` never points past the live prefix
+            rows = {
+                k: sparse_kernels.gather_live(
+                    tables[k],
+                    *sparse_kernels.live_plan(uids[k], tables[k].shape[0]))
+                for k in spec
+            }
         return tables, dense, batch2, uids, rows
 
     def _make_step(self):
@@ -523,11 +541,11 @@ class SparseTableCTRTrainer(CTRTrainer):
                 from lightctr_tpu.ops import sparse_kernels
 
                 for k in spec:
-                    # fused touched-row apply through the kernel registry:
-                    # the XLA reference twin IS the sparse_adagrad_update
-                    # recipe (uids already unique; padded id-0 repeats
-                    # carry zero gradient), the Pallas variant applies it
-                    # in one pass per row
+                    # touched-row apply through the kernel registry: the
+                    # XLA twin is sparse_adagrad_update's arithmetic over
+                    # the live prefix of uids (already sorted unique;
+                    # padded id-0 repeats carry zero gradient and are
+                    # dropped), the Pallas variant one pass per row
                     tables[k], new_accum[k], _ = sparse_kernels.merge_apply(
                         tables[k],
                         opt_state["accum"][k],
@@ -1542,9 +1560,11 @@ class SparseTableCTRTrainer(CTRTrainer):
 
     def _health_signals(self, batch) -> Dict:
         """Per-table touched-uid counts for the skew detector — the same
-        id streams ``_dedup_and_gather`` dedups in-jit, counted host-side
-        (cheap: a few thousand int32 ids).  Skipped entirely unless a
-        table_skew detector is installed."""
+        id streams ``_dedup_and_gather`` dedups in-jit, fetched back from
+        the device and counted host-side with ``np.unique``: at a
+        Criteo-shape batch that is 159,744 int32 ids a table and 5.8 ms a
+        step (``train_telemetry_ms_per_step``, PERF.md).  Skipped entirely
+        unless a table_skew detector is installed."""
         hm = self.health
         if hm is None or not hm.wants("table_touch"):
             return {}
@@ -1558,10 +1578,33 @@ class SparseTableCTRTrainer(CTRTrainer):
                 "ids": int(ids.size),
                 "vocab": int(self.params[k].shape[0]),
             }
+        self._last_touch = touch
         return {"table_touch": touch}
+
+    def _count_apply_slots(self) -> None:
+        """How often the sized apply engages: live rows against the slots
+        of the rung taken (``sparse_kernels.ladder_slots``, the function
+        the device's switch indexes), from the distinct counts
+        :meth:`_health_signals` made on the host this step — no fetch of
+        its own.  Only where the one-program step's XLA apply runs: the
+        exchange steps apply the merged global ids, which the host never
+        counts."""
+        from lightctr_tpu.ops import sparse_kernels
+
+        touch, self._last_touch = self._last_touch, None
+        if (not touch or self._hybrid_dp or self._hier
+                or sparse_kernels.resolve_impl("merge_apply") != "xla"):
+            return
+        reg = self.telemetry
+        for k, t in touch.items():
+            reg.inc(obs.labeled("trainer_apply_live_rows_total", table=k),
+                    t["unique"])
+            reg.inc(obs.labeled("trainer_apply_slots_total", table=k),
+                    sparse_kernels.ladder_slots(t["ids"], t["unique"]))
 
     def _record_step(self, dt: float, batch, health=None) -> None:
         super()._record_step(dt, batch, health=health)
+        self._count_apply_slots()
         policy, xbytes = self._live_exchange_dicts()
         if not ((self._hybrid_dp or self._hier) and policy):
             return

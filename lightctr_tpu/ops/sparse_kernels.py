@@ -22,14 +22,18 @@ for the TPU port:
     never materialized merged-then-applied (the fold of
     ``optim/fused_adagrad``'s row update into the merge).  Emits the
     merged sum-of-squares so the trainer's health gradient norm rides the
-    same pass.
+    same pass.  Its XLA twin — what a TPU runs at the trainer's widths —
+    works on the live prefix of the dedup slots and not on all K of them
+    (:func:`apply_ladder`, :func:`live_plan`; docs/KERNELS.md "The sized
+    apply").
   - :func:`quantize_pack` / :func:`quantize_pack_ef` — quantile-codec
     payload packing (the wire codes of ``ops.quantize``) with the error-
     feedback residual folded into the same pass: compensate, encode,
     decode, fresh-error — one payload traversal.
 
-Every kernel ships a pure-XLA **reference twin** (literally the code the
-call sites ran before this module existed) and dispatch is decided per
+Every kernel ships a pure-XLA **reference twin** (the code the call sites
+ran before this module existed; ``merge_apply``'s has since been sized by
+the live prefix) and dispatch is decided per
 kernel NAME — see :func:`resolve_impl`:
 
   - ``pallas``   — compiled Mosaic kernels; what ``auto`` picks on a TPU for
@@ -60,7 +64,7 @@ CPU-safe twin cannot land.
 from __future__ import annotations
 
 import os
-from functools import partial
+from functools import lru_cache, partial
 from typing import Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
@@ -347,6 +351,105 @@ def merge_rows(rows: jax.Array, inv: jax.Array, num_segments: int):
     return fn(rows, inv, num_segments)
 
 
+#: the live-prefix ladder (:func:`apply_ladder`): rungs in sixteenths of the
+#: slot count K, closer together at the low end where a Criteo-shape batch
+#: lands (28% of its slots distinct); at most 8 rungs, so a table's apply
+#: compiles 2 x 9 small branches
+_LADDER_SIXTEENTHS = (2, 3, 4, 5, 6, 8, 12, 16)
+#: under this many slots the ladder is the one rung K: the tiered store's
+#: few hundred slots and a test's few dozen gain nothing from a switch
+LADDER_MIN_SLOTS = 8192
+
+
+@lru_cache(maxsize=None)
+def apply_ladder(k: int) -> Tuple[int, ...]:
+    """The slot counts the sized apply may take at ``k`` dedup slots,
+    ascending, the last one ``k``: a pure function of ``k``, so the host
+    (``trainer_apply_slots_total``) and the device name the same rung."""
+    if k < LADDER_MIN_SLOTS:
+        return (k,)
+    # multiples of 128: whole lane tiles of the index vector
+    return tuple(sorted({min(k, -(-k * r // (16 * 128)) * 128)
+                         for r in _LADDER_SIXTEENTHS}))
+
+
+def ladder_slots(k: int, count: int) -> int:
+    """Slots of the rung the apply takes for ``count`` live slots of ``k``:
+    the smallest rung that holds them."""
+    return next(s for s in apply_ladder(k) if s >= count)
+
+
+def live_plan(uids: jax.Array, vocab: int):
+    """``(idx, branch)`` for one dedup-convention id vector ``uids`` [K].
+
+    A slot is live when it is slot 0 or its id is not 0; ``count`` is the
+    last live slot + 1 (one pass over K int32).  ``idx`` is ``uids`` with
+    every pad slot sent past the table (``vocab + slot``: ascending, so a
+    scatter with ``mode="drop"`` skips it and a sorted, unique live prefix
+    stays sorted and unique over any rung).  ``branch`` indexes
+    ``apply_ladder(K)`` by the smallest rung holding ``count`` — or is
+    ``len(ladder)``, the undeclared full-K branch, when ``idx`` is not
+    strictly ascending (the reduce-scatter exchange hands per-owner
+    sorted segments with pads between them): sortedness is observed,
+    never assumed."""
+    k = uids.shape[0]
+    # int64 id streams (the billion-row regime) keep their width
+    slot = jnp.arange(k, dtype=jnp.promote_types(uids.dtype, jnp.int32))
+    live = (uids != 0) | (slot == 0)
+    count = jnp.max(jnp.where(live, slot + 1, 0))
+    idx = jnp.where(live, uids.astype(slot.dtype), vocab + slot)
+    ladder = apply_ladder(k)
+    rung = jnp.sum(count > jnp.asarray(ladder, jnp.int32))
+    ascending = jnp.all(idx[1:] > idx[:-1])
+    return idx, jnp.where(ascending, rung, len(ladder)).astype(jnp.int32)
+
+
+def _ladder_branches(k: int, rung: Callable) -> list:
+    """``rung(slots, ordered)`` for every rung of ``apply_ladder(k)``,
+    then the undeclared full-K branch ``live_plan`` names for ids it saw
+    out of order."""
+    return [rung(s, True) for s in apply_ladder(k)] + [rung(k, False)]
+
+
+def gather_live(block: jax.Array, idx: jax.Array, branch: jax.Array):
+    """``block[idx]`` over the rung ``branch`` names, zero rows behind it
+    ([K, ...] whatever the rung).  ``block`` is used once in each branch,
+    which is what lets XLA keep a donated table in place around the
+    switch (docs/KERNELS.md, "Reading tools/aot_step.py")."""
+    k = idx.shape[0]
+
+    def rung(s, ordered):
+        def f(block, idx):
+            # pads sit past the table: clip reads its last row for them,
+            # and no caller reads a pad slot's row
+            rows = jnp.take(block, idx[:s], axis=0, mode="clip",
+                            indices_are_sorted=ordered)
+            return jnp.pad(rows, ((0, k - s),) + ((0, 0),) * (rows.ndim - 1))
+        return f
+
+    return jax.lax.switch(branch, _ladder_branches(k, rung), block, idx)
+
+
+def _scatter_live(table, accum, idx, delta, acc, branch):
+    """``table[idx] += delta ; accum[idx] = acc`` over the rung ``branch``
+    names.  Every live index is distinct and every pad is past the table
+    and dropped, so each scatter says ``unique_indices``; the rungs also
+    say ``indices_are_sorted`` (``live_plan`` saw it), which spares the
+    sort XLA otherwise puts before a scatter."""
+    k = idx.shape[0]
+
+    def rung(s, ordered):
+        def f(table, accum, idx, delta, acc):
+            kw = dict(mode="drop", indices_are_sorted=ordered,
+                      unique_indices=True)
+            return (table.at[idx[:s]].add(delta[:s], **kw),
+                    accum.at[idx[:s]].set(acc[:s], **kw))
+        return f
+
+    return jax.lax.switch(branch, _ladder_branches(k, rung),
+                          table, accum, idx, delta, acc)
+
+
 def _merge_apply_reference(
     table: jax.Array,
     accum: jax.Array,
@@ -357,24 +460,29 @@ def _merge_apply_reference(
     eps: float,
     denom: float,
 ):
-    """Literally the pre-kernel trainer sequence: segment-merge (when
-    ``inv`` is given), scale, health sum-of-squares, then the
-    ``sparse_adagrad_update`` recipe — the separate-HLO chain the fused
-    kernel collapses."""
-    from lightctr_tpu.embed.table import SparseAdagradState, \
-        sparse_adagrad_update
-
+    """The XLA apply: segment-merge (when ``inv`` is given), scale, health
+    sum-of-squares, then ``embed.table.sparse_adagrad_update``'s
+    arithmetic — ``acc = a[u] + g^2 ; w[u] -= lr g rsqrt(acc + eps) ;
+    a[u] = acc`` — over the live prefix of ``uids`` and not over all K
+    slots: the accumulator rows come from one switch over the ladder
+    (:func:`gather_live`), the arithmetic runs at K, one more switch
+    scatters into table and accumulator (:func:`_scatter_live`).  The ids
+    are trusted to be unique, as the contract states them, so nothing is
+    deduplicated a second time."""
+    k = uids.shape[0]
     if inv is not None:
-        merged = jax.ops.segment_sum(rows, inv, num_segments=uids.shape[0])
+        merged = jax.ops.segment_sum(rows, inv, num_segments=k)
     else:
         merged = rows
     if denom != 1.0:
         merged = merged / denom
     sumsq = jnp.sum(merged * merged)
-    new_table, st = sparse_adagrad_update(
-        table, SparseAdagradState(accum=accum), uids, merged, lr, eps=eps
-    )
-    return new_table, st.accum, sumsq
+    g = merged.reshape((k,) + table.shape[1:]).astype(table.dtype)
+    idx, branch = live_plan(uids, table.shape[0])
+    acc = gather_live(accum, idx, branch) + g * g
+    delta = -lr * g * jax.lax.rsqrt(acc + eps)
+    new_table, new_accum = _scatter_live(table, accum, idx, delta, acc, branch)
+    return new_table, new_accum, sumsq
 
 
 #: rows per grid step of the row-DMA kernels (:func:`_apply_kernel`,

@@ -194,6 +194,250 @@ def test_merge_apply_block_tail_pads_and_real_id0(rng):
                                   table[untouched])
 
 
+# -- (b') the sized XLA apply: live prefix, ladder, observed order ---------
+
+#: the smallest K with a real ladder, and its rungs
+_LK = sk.LADDER_MIN_SLOTS
+_RUNGS = sk.apply_ladder(_LK)
+
+
+def _live_case(rng, count, *, vocab=20000, shape=(4,), with_zero=False,
+               with_inv=False, interleave=False):
+    """Dedup-convention inputs at K = ``_LK`` with ``count`` live slots:
+    ``(table, accum, uids, rows, inv)`` as numpy arrays (``inv`` None
+    unless ``with_inv``)."""
+    k = _LK
+    ids = np.sort(rng.choice(np.arange(1, vocab), size=count, replace=False))
+    if with_zero:
+        ids[0] = 0
+    uids = np.zeros(k, np.int32)
+    g = np.zeros((k,) + shape, np.float32)
+    if interleave:
+        # what the reduce-scatter exchange hands over: per-owner sorted
+        # segments, id-0 pads (and whatever the codec decoded there)
+        # between them
+        slots = np.sort(rng.choice(np.arange(1, k), size=count - 1,
+                                   replace=False))
+        slots = np.concatenate([[0], slots])
+        half = count // 2
+        ids = np.concatenate([np.sort(ids[:half]), np.sort(ids[half:])])
+        g[:] = rng.normal(size=g.shape)          # noise in the pad slots
+    else:
+        slots = np.arange(count)
+    uids[slots] = ids
+    g[slots] = rng.normal(size=(count,) + shape)
+    # |w| < 2 keeps one ulp of a weight under the parity tests' atol
+    table = rng.uniform(-1, 1, size=(vocab,) + shape).astype(np.float32)
+    accum = np.abs(rng.normal(size=(vocab,) + shape)).astype(np.float32)
+    if not with_inv:
+        return table, accum, uids, g, None
+    # every live slot's row split over three payload rows; pad segments
+    # are never referenced
+    inv = np.repeat(slots, 3).astype(np.int32)
+    rows = np.repeat(g[slots], 3, axis=0) * \
+        rng.normal(size=(3 * count,) + (1,) * len(shape)).astype(np.float32)
+    perm = rng.permutation(3 * count)
+    return table, accum, uids, rows.astype(np.float32)[perm], inv[perm]
+
+
+def _sized_apply_cases():
+    cases = [("count1", dict(count=1))]
+    for s in _RUNGS:
+        cases.append((f"edge{s}", dict(count=s)))
+        if s < _LK:
+            cases.append((f"edge{s}+1", dict(count=s + 1)))
+    cases += [
+        ("real_id0", dict(count=_RUNGS[2] - 7, with_zero=True)),
+        ("table_1d", dict(count=_RUNGS[1] + 5, shape=())),
+        ("table_1d_id0", dict(count=_RUNGS[0], shape=(), with_zero=True)),
+        ("with_inv", dict(count=_RUNGS[0] + 9, with_inv=True)),
+        ("with_inv_1d", dict(count=_RUNGS[3], shape=(), with_inv=True)),
+        ("interleaved_pads", dict(count=_RUNGS[1], interleave=True)),
+        ("interleaved_pads_1d", dict(count=777, shape=(), interleave=True)),
+        ("embed_mesh", dict(count=_RUNGS[2] + 1, mesh=True)),
+    ]
+    return [pytest.param(kw, id=name) for name, kw in cases]
+
+
+@pytest.mark.parametrize("case", _sized_apply_cases())
+def test_sized_apply_matches_sparse_adagrad_update(case):
+    """The XLA apply — live prefix, one rung of the ladder, no second
+    dedup — against ``embed.table.sparse_adagrad_update`` (the chain it
+    replaced: ``jnp.unique`` + ``segment_sum`` + K-slot scatter-adds) on
+    the same inputs, to ``test_merge_apply_parity``'s tolerances: at one
+    live slot, at every rung's edge and one past it, with a real id 0,
+    for ``w[V]`` and ``[V, d]``, with ``inv``, with interleaved pads
+    (which must take the undeclared branch), and with the table sharded
+    over ``embed`` on four devices."""
+    from lightctr_tpu.embed.table import SparseAdagradState, \
+        sparse_adagrad_update
+
+    kw = dict(case)
+    mesh = kw.pop("mesh", False)
+    count = kw["count"]
+    rng = np.random.default_rng(count)
+    table, accum, uids, rows, inv = _live_case(rng, **kw)
+    lr, eps, denom = 0.1, 1e-7, 2.0
+
+    # the chain the apply replaced, fed what merge_apply's dispatch hands
+    # its implementations (pad slots zeroed for inv=None payloads)
+    if inv is not None:
+        merged = jax.ops.segment_sum(jnp.asarray(rows), jnp.asarray(inv),
+                                     num_segments=_LK)
+    else:
+        pad = (uids == 0) & (np.arange(_LK) > 0)
+        merged = jnp.asarray(rows * (~pad).reshape((-1,) + (1,) * (rows.ndim - 1)))
+    w0, st = sparse_adagrad_update(
+        jnp.asarray(table), SparseAdagradState(accum=jnp.asarray(accum)),
+        jnp.asarray(uids), merged / denom, lr, eps=eps)
+    a0 = st.accum
+
+    args = [jnp.asarray(table), jnp.asarray(accum), jnp.asarray(uids),
+            jnp.asarray(rows), None if inv is None else jnp.asarray(inv)]
+    fn = jax.jit(lambda w, a, u, r, i: sk.merge_apply(
+        w, a, u, r, i, lr=lr, eps=eps, denom=denom))
+    if mesh:
+        from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+        m = Mesh(np.array(jax.devices()[:4]), ("embed",))
+        rowwise = NamedSharding(m, P("embed", *([None] * (table.ndim - 1))))
+        args[0] = jax.device_put(args[0], rowwise)
+        args[1] = jax.device_put(args[1], rowwise)
+        args[2] = jax.device_put(args[2], NamedSharding(m, P()))
+        args[3] = jax.device_put(args[3], NamedSharding(m, P()))
+    w1, a1, s1 = fn(*args)
+    if mesh:
+        assert w1.sharding.is_equivalent_to(rowwise, table.ndim)
+
+    np.testing.assert_allclose(np.asarray(w1), np.asarray(w0),
+                               rtol=0, atol=2e-7)
+    np.testing.assert_allclose(np.asarray(a1), np.asarray(a0),
+                               rtol=2e-6, atol=0)
+    np.testing.assert_allclose(float(s1), float(jnp.sum(
+        (merged / denom) ** 2)), rtol=1e-5)
+    untouched = np.setdiff1d(np.arange(table.shape[0]), uids)
+    np.testing.assert_array_equal(np.asarray(w1)[untouched], table[untouched])
+    np.testing.assert_array_equal(np.asarray(a1)[untouched], accum[untouched])
+    if not kw.get("with_zero"):
+        # id 0 was never live: neither a pad nor noise in a pad may move it
+        np.testing.assert_array_equal(np.asarray(w1)[0], table[0])
+        np.testing.assert_array_equal(np.asarray(a1)[0], accum[0])
+
+    # the rung the device takes is the one the host names from the count
+    _, branch = sk.live_plan(jnp.asarray(uids), table.shape[0])
+    if kw.get("interleave"):
+        assert int(branch) == len(_RUNGS)       # observed unsorted
+    else:
+        assert _RUNGS[int(branch)] == sk.ladder_slots(_LK, count)
+        assert count <= _RUNGS[int(branch)]
+        assert int(branch) == 0 or _RUNGS[int(branch) - 1] < count
+
+
+def test_apply_ladder_is_a_function_of_k_alone():
+    """One rung under the small-K floor; above it at most eight ascending
+    rungs ending at K; and the host function behind
+    ``trainer_apply_slots_total`` names the rung the device's switch
+    takes, over a sweep of live counts."""
+    import inspect
+
+    assert list(inspect.signature(sk.apply_ladder).parameters) == ["k"]
+    for k in (1, 8, 24, 160, 4096, sk.LADDER_MIN_SLOTS - 1):
+        assert sk.apply_ladder(k) == (k,)
+        assert sk.ladder_slots(k, 1) == sk.ladder_slots(k, k) == k
+    for k in (sk.LADDER_MIN_SLOTS, 10_000, 159_744, 4096 * 39 * 2 + 3):
+        ladder = sk.apply_ladder(k)
+        assert 2 <= len(ladder) <= 8 and ladder[-1] == k
+        assert list(ladder) == sorted(set(ladder))
+    assert sk.apply_ladder(159_744)[3:5] == (49_920, 59_904)
+
+    k, vocab = 10_000, 50_000
+    ladder = sk.apply_ladder(k)
+    plan = jax.jit(lambda u: sk.live_plan(u, vocab)[1])
+    counts = sorted({1, 2, k - 1, k, *ladder, *(s + 1 for s in ladder[:-1]),
+                     *np.random.default_rng(0).integers(1, k, size=12)})
+    for count in counts:
+        uids = np.zeros(k, np.int32)
+        uids[:count] = np.arange(1, count + 1)
+        assert ladder[int(plan(jnp.asarray(uids)))] == \
+            sk.ladder_slots(k, int(count)), count
+
+
+def test_forward_gather_reads_the_live_prefix(rng):
+    """``_dedup_and_gather`` above the small-K floor: every slot ``inv``
+    can name holds its table row, the slots behind the rung hold zeros,
+    for a ``[V, d]`` and a ``w[V]`` table on one id stream."""
+    from lightctr_tpu.models.sparse_trainer import SparseTableCTRTrainer
+
+    vocab, b, f = 30_000, 256, 39
+    fids = rng.integers(0, 2500, size=(b, f)).astype(np.int32)
+    params = {"v": jnp.asarray(rng.normal(size=(vocab, 3)), jnp.float32),
+              "w": jnp.asarray(rng.normal(size=(vocab,)), jnp.float32)}
+    _, _, batch2, uids, rows = jax.jit(
+        lambda p, bt: SparseTableCTRTrainer._dedup_and_gather(
+            {"v": ("fids",), "w": ("fids",)}, p, bt)
+    )(params, {"fids": jnp.asarray(fids)})
+    u, inv = np.unique(fids.reshape(-1), return_inverse=True)
+    rung = sk.ladder_slots(b * f, u.size)
+    assert u.size <= rung < b * f
+    np.testing.assert_array_equal(np.asarray(batch2["fids"]).reshape(-1), inv)
+    for k in ("v", "w"):
+        got = np.asarray(rows[k])
+        assert got.shape == (b * f,) + params[k].shape[1:]
+        np.testing.assert_array_equal(got[:u.size], np.asarray(params[k])[u])
+        assert not got[rung:].any()
+
+
+def test_trainer_counts_live_rows_and_rung_slots():
+    """``trainer_apply_live_rows_total`` / ``trainer_apply_slots_total``:
+    incremented from the host's own distinct counts and the ladder
+    function, per table, and printed as the live share by
+    ``metrics_report --kernels``."""
+    from lightctr_tpu import TrainConfig, obs
+    from lightctr_tpu.models import fm
+    from lightctr_tpu.models.sparse_trainer import SparseTableCTRTrainer
+    from lightctr_tpu.obs import health
+    from tools import metrics_report
+
+    vocab, b, f = 40_000, 256, 39                 # K = 9,984 ids a stream
+    rng = np.random.default_rng(3)
+    tr = SparseTableCTRTrainer(
+        fm.init(jax.random.PRNGKey(0), vocab, 4), fm.logits,
+        TrainConfig(learning_rate=0.05), sparse_tables={"w": ["fids"],
+                                                        "v": ["fids"]},
+        fused_fn=fm.logits_with_l2)
+    tr.telemetry = obs.MetricsRegistry()
+    tr.health = health.HealthMonitor(registry=obs.MetricsRegistry())
+    health.ensure_trainer_detectors(tr.health, tables=True)
+    live = slots = 0
+    try:
+        with obs.override(True):
+            for hot in (300, 6000):
+                fids = rng.integers(1, hot, size=(b, f)).astype(np.int32)
+                tr.train_step({
+                    "fids": fids,
+                    "fields": np.tile(np.arange(f, dtype=np.int32), (b, 1)),
+                    "vals": np.ones((b, f), np.float32),
+                    "mask": np.ones((b, f), np.float32),
+                    "labels": (rng.random(b) > 0.5).astype(np.float32)})
+                n = int(np.unique(fids).size)
+                live += n
+                slots += sk.ladder_slots(b * f, n)
+            tr.flush_health()
+    finally:
+        tr.health.close()
+    counters = tr.telemetry.snapshot()["counters"]
+    for table in ("w", "v"):
+        assert counters[obs.labeled("trainer_apply_live_rows_total",
+                                    table=table)] == live
+        assert counters[obs.labeled("trainer_apply_slots_total",
+                                    table=table)] == slots
+    assert slots < 2 * b * f                      # the ladder engaged
+    report = metrics_report.summarize_kernels(tr.telemetry.snapshot())
+    assert report["apply"]["v"] == {
+        "live_rows": live, "slots": slots,
+        "live_share": round(live / slots, 4)}
+
+
 # -- (c) quantize pack: bit-identical codes ------------------------------
 
 

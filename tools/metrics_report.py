@@ -343,25 +343,43 @@ def summarize_kernels(doc) -> dict:
     counter increments once per dispatch at trace time (the pick is
     static inside jit), so this answers "which implementation actually
     ran" — the honesty check docs/KERNELS.md's bench methodology leans
-    on."""
+    on.  Beside it, per table, how the sized XLA apply engaged:
+    ``trainer_apply_live_rows_total`` over ``trainer_apply_slots_total``
+    is the share of the slots it worked on that held a live row (the rest
+    is the rung's round-up; 1 - slots / ids is what the ladder spared)."""
     snap = doc.get("telemetry", doc) if isinstance(doc, dict) else doc
     counters = snap.get("counters", {})
     phases: dict = {}
     total_by_impl: dict = {}
-    prefix = "trainer_kernel_path_total{"
-    for name, val in counters.items():
-        if not name.startswith(prefix):
-            continue
-        labels = dict(
+    apply: dict = {}
+
+    def _labels(name: str, prefix: str) -> dict:
+        return dict(
             part.split("=", 1)
             for part in name[len(prefix):-1].replace('"', "").split(",")
         )
+
+    prefix = "trainer_kernel_path_total{"
+    for name, val in counters.items():
+        for what in ("live_rows", "slots"):
+            p = f"trainer_apply_{what}_total{{"
+            if name.startswith(p):
+                table = _labels(name, p).get("table", "?")
+                apply.setdefault(table, {})[what] = int(val)
+        if not name.startswith(prefix):
+            continue
+        labels = _labels(name, prefix)
         phase = labels.get("phase", "?")
         impl = labels.get("impl", "?")
         phases.setdefault(phase, {})[impl] = \
             phases.get(phase, {}).get(impl, 0) + int(val)
         total_by_impl[impl] = total_by_impl.get(impl, 0) + int(val)
+    for entry in apply.values():
+        if entry.get("slots"):
+            entry["live_share"] = round(
+                entry.get("live_rows", 0) / entry["slots"], 4)
     return {
+        "apply": dict(sorted(apply.items())),
         "phases": {p: dict(sorted(v.items())) for p, v in
                    sorted(phases.items())},
         "dispatches_by_impl": dict(sorted(total_by_impl.items())),
