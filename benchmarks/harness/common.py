@@ -50,6 +50,17 @@ def make_mesh(cfg: Dict, chips: int):
     return mesh, shardings
 
 
+def row_writers(cfg: Dict, chips: int) -> int:
+    """Over how many chips the update of a batch's touched rows is divided.
+    On a mesh the rows are sharded over ``embed`` and replicated over
+    ``data``: every row lives on each data replica and each must write it,
+    so a chip's least bytes are the total over the ``embed`` shards, not
+    over the chips.  A configuration that states no mesh: the cell's chips."""
+    if "mesh" not in cfg:
+        return chips
+    return int(cfg["mesh"].get("embed", 1))
+
+
 def memory(chips: int) -> Dict[str, int]:
     """Of the fullest of the cell's chips: the process's peak of live
     buffers (on a TPU the constructor's copies, not the step's compiler

@@ -2,9 +2,11 @@
 
 Input is either an ``.xplane.pb`` the JAX profiler wrote (``load_xplane``)
 or the same content as plain lists (the small recorded trace the tests
-keep): per device a list of operation events, and a list of the host spans
-the benchmark placed (``jax.profiler.TraceAnnotation`` names starting with
-``bench/``).  Times are nanoseconds on the profile's own clock, which host
+keep): per device a list of operation events, and a list of the host spans:
+the benchmark's own (``jax.profiler.TraceAnnotation`` names starting with
+``bench/``) and the program's on the training path (``trainer/``,
+``ingest/``; ``lightctr_tpu/obs/trace.py`` writes them into the same
+profiler session).  Times are nanoseconds on the profile's own clock, which host
 and device planes share.
 
 An operation event is ``(name, scope, start_ns, dur_ns)``: ``name`` the HLO
@@ -28,10 +30,18 @@ COLLECTIVE_RE = re.compile(
 #: device lines that hold one event per executed HLO operation (the
 #: "Async XLA Ops" line repeats copies that run beside them)
 OP_LINES = ("XLA Ops",)
+#: host spans kept: the benchmark's own, and the program's that name what
+#: the host does in a training step and in its input pipeline
+SPAN_PREFIXES = ("bench/", "trainer/", "ingest/")
+WINDOW = "bench/window"
 
 
-def load_xplane(path: str, span_prefix: str = "bench/") -> Dict:
-    """``{"devices": {plane: [Event]}, "spans": [Span]}`` from an xplane file."""
+def load_xplane(path: str) -> Dict:
+    """``{"devices": {plane: [Event]}, "spans": [Span]}`` from an xplane file.
+    The spans are those of the one thread that placed ``bench/window``, the
+    thread that drives the steps: the ingest worker's (``ingest/produce``,
+    ``ingest/put_wait``) run beside it, and an idle gap is named by what the
+    driving thread was doing (``ingest/get_wait`` is its own)."""
     from . import xplane
 
     planes = xplane.read_planes(
@@ -48,9 +58,11 @@ def load_xplane(path: str, span_prefix: str = "bench/") -> Dict:
                 devices[plane] = ops
         else:
             for events in lines.values():
-                spans.extend((name, start, start + dur)
-                             for name, _, start, dur in events
-                             if name.startswith(span_prefix))
+                found = [(name, start, start + dur)
+                         for name, _, start, dur in events
+                         if name.startswith(SPAN_PREFIXES)]
+                if any(name == WINDOW for name, _, _ in found):
+                    spans = found
     return {"devices": devices, "spans": sorted(spans, key=lambda sp: sp[1])}
 
 
@@ -114,7 +126,7 @@ def is_collective(ev: Event) -> bool:
 # -- the reduction ------------------------------------------------------------
 
 
-def window_of(spans: Sequence[Span], name: str = "bench/window") -> Tuple[float, float]:
+def window_of(spans: Sequence[Span], name: str = WINDOW) -> Tuple[float, float]:
     for n, s, e in spans:
         if n == name:
             return s, e
@@ -122,17 +134,18 @@ def window_of(spans: Sequence[Span], name: str = "bench/window") -> Tuple[float,
 
 
 def reduce_trace(trace: Dict, top: int = 10) -> Dict:
-    """Busy and idle time, per-operation sums, exposed collective time and
-    the idle gaps by what the host was doing, over the ``bench/window``
+    """Busy and idle time, per-operation sums, collective time with the
+    part of it no other operation covers (exposed), and the idle gaps by
+    what the host was doing, over the ``bench/window``
     span.  Seconds, averaged over the devices that ran anything."""
     t0, t1 = window_of(trace["spans"])
-    spans = [s for s in trace["spans"] if s[0] != "bench/window"]
+    spans = [s for s in trace["spans"] if s[0] != WINDOW]
     # from here on an event's name is its label, worked out once
     per_dev = {d: [(op_label(n, sc), sc, s, dur) for n, sc, s, dur in clip(evs, t0, t1)]
                for d, evs in trace["devices"].items()}
     per_dev = {d: evs for d, evs in per_dev.items() if evs}
     n_dev = max(1, len(per_dev))
-    busy_ns = exposed_ns = 0.0
+    busy_ns = coll_ns = exposed_ns = 0.0
     op_ns: Dict[str, float] = {}
     gaps_ns: Dict[str, float] = {}
     for i, (dev, evs) in enumerate(sorted(per_dev.items())):
@@ -140,6 +153,7 @@ def reduce_trace(trace: Dict, top: int = 10) -> Dict:
         busy_ns += total(busy)
         coll = union(_iv(e for e in evs if is_collective(e)))
         comp = union(_iv(e for e in evs if not is_collective(e)))
+        coll_ns += total(coll)
         exposed_ns += total(subtract(coll, comp))
         for key, _, _, d in evs:
             op_ns[key] = op_ns.get(key, 0.0) + d
@@ -151,6 +165,7 @@ def reduce_trace(trace: Dict, top: int = 10) -> Dict:
         "window_s": (t1 - t0) / 1e9,
         "busy_s": busy_ns / n_dev / 1e9,
         "devices": len(per_dev),
+        "collective_s": coll_ns / n_dev / 1e9,
         "exposed_collective_s": exposed_ns / n_dev / 1e9,
         "device_ops": [[k, v / n_dev / 1e9] for k, v in rank(op_ns)],
         "idle_gaps": [[k, v / 1e9] for k, v in rank(gaps_ns)],
@@ -167,7 +182,8 @@ def op_label(name: str, scope: str) -> str:
 
 def host_timeline(spans: Sequence[Span]) -> List[Tuple[float, float, str]]:
     """``(start, end, label)`` pieces, in order and not overlapping: at each
-    moment the innermost (shortest) benchmark span that covers it."""
+    moment the innermost (shortest) span that covers it; the benchmark's own
+    lose their ``bench/`` prefix, the program's keep their names."""
     cuts = sorted({t for _, s, e in spans for t in (s, e)})
     by_start = sorted(spans, key=lambda sp: sp[1])
     out, active, j = [], [], 0

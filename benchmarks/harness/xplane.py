@@ -68,7 +68,10 @@ def read_planes(path: str, want_plane: Callable[[str], bool],
                 keep_stats: Tuple[str, ...] = ("tf_op", "hlo_category")
                 ) -> Dict[str, Dict[str, List[Tuple]]]:
     """``{plane: {line: [(display_name, stats, start_ns, dur_ns)]}}`` for the
-    planes and lines asked for; ``stats`` holds the metadata's ``keep_stats``."""
+    planes and lines asked for; ``stats`` holds the metadata's ``keep_stats``.
+    A host plane's lines are threads and may share a name (every Python
+    thread is ``python3``): a name seen before gets ``#<n>`` appended, so
+    that two threads' events stay apart."""
     with open(path, "rb") as f:
         space = f.read()
     out: Dict[str, Dict[str, List[Tuple]]] = {}
@@ -126,6 +129,8 @@ def read_planes(path: str, want_plane: Callable[[str], bool],
                     events.append(v)
             if not want_line(name, line_name):
                 continue
+            if line_name in plane_out:
+                line_name = f"{line_name}#{len(plane_out)}"
             rows = plane_out.setdefault(line_name, [])
             for ev in events:
                 mid = off_ps = dur_ps = 0

@@ -11,11 +11,12 @@ import pytest
 from benchmarks.harness import datagen, reference
 from benchmarks.harness.manifest import Manifest
 
-from helpers import REPO, TINY_FM, TINY_WD
+from helpers import REPO, tiny_config
 
 MAN = Manifest(REPO)
-CASES = {"widedeep": dict(TINY_WD, vocab=1 << 14, batch=512, dim=16, hidden=32),
-         "fm": dict(TINY_FM, vocab=1 << 14, batch=512, factors=16)}
+CASES = {"widedeep": tiny_config("criteo-widedeep", vocab=1 << 14, batch=512,
+                                 dim=16, hidden=32),
+         "fm": tiny_config("criteo-fm-k64", vocab=1 << 14, batch=512, factors=16)}
 
 
 def evidence(model_name, seed):

@@ -41,7 +41,7 @@ def test_a_cell_config_traffic_and_metric_are_added_by_files_alone(tmp_path):
     bench = os.path.join(root, "benchmarks")
     before = {p: os.path.getmtime(os.path.join(dp, p))
               for dp, _, fs in os.walk(bench) for p in fs}
-    cfg = dict(helpers.TINY_FM, factors=4)
+    cfg = helpers.tiny_config("criteo-fm-k64", factors=4)
     json.dump(cfg, open(os.path.join(bench, "configs", "throwaway-fm.json"), "w"))
     traffic = json.load(open(os.path.join(bench, "traffic", "train-zipf.json")))
     traffic["rows"]["exponent"] = 0.4

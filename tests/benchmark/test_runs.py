@@ -11,7 +11,7 @@ import helpers
 from helpers import REPO, drive
 
 CELLS = [w["name"] for w in json.load(
-    open(os.path.join(REPO, "BENCHMARK.json")))["workloads"]] + ["wd-x4-train-zipf"]
+    open(os.path.join(REPO, "BENCHMARK.json")))["workloads"]]
 
 
 @pytest.fixture(scope="module")
