@@ -913,8 +913,8 @@ def _ag_gather_ids(uids: jax.Array, axis_name: str):
     batch-field tuples) gather and dedup the ids ONCE — the row half
     (:func:`_ag_merge_rows`) reuses ``inv`` per table.  The dedup routes
     through the kernel registry (``ops.sparse_kernels.dedup_ids``): the
-    fused sort-free kernel on TPU, the identical ``jnp.unique`` contract
-    everywhere else."""
+    ``jnp.unique`` contract, on a TPU too by its XLA twin of three sorts
+    (the Pallas rank kernel is deselected there)."""
     from lightctr_tpu.ops import sparse_kernels
 
     all_ids = jax.lax.all_gather(uids, axis_name, tiled=True)

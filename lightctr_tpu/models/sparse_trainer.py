@@ -467,9 +467,10 @@ class SparseTableCTRTrainer(CTRTrainer):
         coincide by construction (the __init__ overlap check guarantees
         no other sharing shape exists), so dedup FLOPs are paid per
         distinct id stream, not per table.  The dedup itself rides the
-        kernel registry (``ops.sparse_kernels.dedup_ids``): the fused
-        sort-free Pallas kernel on TPU, the identical ``jnp.unique``
-        contract everywhere else."""
+        kernel registry (``ops.sparse_kernels.dedup_ids``): on a TPU and
+        off it the XLA twin, three sorts with payloads and a scan under
+        the ``jnp.unique`` contract (the sort-free Pallas kernel does not
+        lower at this width and is deselected; docs/KERNELS.md)."""
         from lightctr_tpu.ops import sparse_kernels
 
         tables = {k: params[k] for k in spec}

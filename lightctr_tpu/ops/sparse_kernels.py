@@ -10,12 +10,15 @@ these in one pass; ∇SD (PAPERS.md, 2303.07030) makes the same case for
 sparse formats as first-class compiled objects.  This module is that layer
 for the TPU port:
 
-  - :func:`dedup_ids` — unique+inverse over an id stream.  Pallas variant
-    is SORT-FREE: a blocked rank kernel (rank = #distinct values less than
-    x, via first-occurrence flags) that emits the exact ``jnp.unique(...,
-    size=K, fill_value=0)`` contract — sorted unique ids, full-rank
-    inverse (ranks may exceed ``size`` when truncated, exactly like
-    ``jnp.unique``), plus the distinct count.
+  - :func:`dedup_ids` — unique+inverse over an id stream: the exact
+    ``jnp.unique(..., size=K, fill_value=0)`` contract — sorted unique
+    ids, full-rank inverse (ranks may exceed ``size`` when truncated,
+    exactly like ``jnp.unique``), plus the distinct count.  Pallas
+    variant is SORT-FREE: a blocked rank kernel (rank = #distinct values
+    less than x, via first-occurrence flags); it does not lower at the
+    trainer's width and is deselected on a TPU.  Its XLA twin — what
+    every TPU run takes — is three sorts with payloads and one scan, and
+    no K-sized gather or scatter (docs/KERNELS.md "The dedup").
   - :func:`merge_rows` — duplicate-id segment merge (``segment_sum``).
   - :func:`merge_apply` — one-pass segment-merge + scaled Adagrad apply
     over touched rows: gradient rows are read once and the merged rows are
@@ -33,8 +36,8 @@ for the TPU port:
 
 Every kernel ships a pure-XLA **reference twin** (the code the call sites
 ran before this module existed; ``merge_apply``'s has since been sized by
-the live prefix) and dispatch is decided per
-kernel NAME — see :func:`resolve_impl`:
+the live prefix, ``dedup_ids``' rewritten as sorts) and dispatch is
+decided per kernel NAME — see :func:`resolve_impl`:
 
   - ``pallas``   — compiled Mosaic kernels; what ``auto`` picks on a TPU for
                    every kernel the registry does not deselect there.
@@ -173,14 +176,36 @@ def next_pow2(n: int, floor: int = 8) -> int:
 
 
 def _dedup_reference(ids: jax.Array, size: int):
-    """The exact call every dedup site ran before: sorted unique padded
-    with id 0, full-rank inverse, plus the distinct count (``max(inv)+1``
-    — ``jnp.unique``'s inverse is the rank among ALL distinct values even
-    when ``size`` truncates the unique array, so the count needs no extra
-    sort)."""
-    u, inv = jnp.unique(ids, return_inverse=True, size=size, fill_value=0)
-    inv = inv.reshape(-1).astype(jnp.int32)
-    return u, inv, (jnp.max(inv) + 1).astype(jnp.int32)
+    """Sorted unique padded with id 0, full-rank inverse and the distinct
+    count, from three sorts and one scan: no K-sized gather, scatter or
+    scatter-add (on a v5e each costs 5-9 sorts of the same K; PERF.md
+    section 5).  Output for output what
+    ``jnp.unique(ids, return_inverse=True, size=size, fill_value=0)``
+    gives — the inverse is the rank among ALL distinct values even when
+    ``size`` truncates the unique array, so the count is the last rank + 1.
+
+    No sort is stable (a stable sort carries one more operand on a TPU,
+    0.15 ms of 0.58 a stream at K = 159,744) and none needs to be: ties
+    are either impossible or between elements the outputs cannot tell
+    apart."""
+    k = ids.shape[0]
+    # the sorted ids come out of the sort as an operand, not by ids[perm];
+    # equal ids get the same rank, so their order in perm moves nothing
+    s, perm = jax.lax.sort((ids, jax.lax.iota(jnp.int32, k)), num_keys=1,
+                           is_stable=False)
+    first = jnp.concatenate([jnp.ones((1,), jnp.bool_), s[1:] != s[:-1]])
+    rank = jnp.cumsum(first.astype(jnp.int32)) - 1
+    count = rank[-1] + 1
+    # ranks back in the order of the positions (zeros.at[perm].set(rank));
+    # perm is a permutation: no ties
+    _, inv = jax.lax.sort((perm, rank), num_keys=1, is_stable=False)
+    # first occurrences ahead of the repeats, ascending: two keys and no
+    # sentinel, so an id of any value and either width sorts right; only
+    # repeats of one id tie
+    _, packed = jax.lax.sort((~first, s), num_keys=2, is_stable=False)
+    n = min(size, k)
+    u = jnp.where(jax.lax.iota(jnp.int32, n) < count, packed[:n], 0)
+    return jnp.pad(u, (0, size - n)), inv, count
 
 
 def _dedup_kernel(ids_ref, inv_ref, uids_ref, count_ref, first_ref,
@@ -270,7 +295,9 @@ def dedup_ids(ids: jax.Array, size: Optional[int] = None):
     to ``len(ids)`` (no truncation); with ``size < count`` the unique
     array truncates while ``inv`` keeps full ranks — identical to
     ``jnp.unique`` (callers like the rs shard merge read the count to
-    tally overflow)."""
+    tally overflow).  On a TPU ``auto`` takes the XLA twin
+    (:func:`_dedup_reference`: three sorts and a scan); the Pallas rank
+    kernel is deselected there since PR 21."""
     ids = ids.reshape(-1)
     k = ids.shape[0]
     if size is None:
@@ -282,7 +309,7 @@ def dedup_ids(ids: jax.Array, size: Optional[int] = None):
     if jnp.dtype(ids.dtype).itemsize > 4 and resolve_impl("dedup_ids") != "xla":
         # the rank kernel compares in int32 — ids that may not fit (int64
         # streams in the billion-row-vocab regime) take the reference,
-        # where jnp.unique is exact at any width
+        # whose sorts are exact at any width
         impl = "xla"
     _, fn = _resolve("dedup_ids", impl=impl)
     return fn(ids, size)

@@ -5,6 +5,8 @@ bit-identical to the existing codec), property tests over duplicate-heavy
 and empty id streams, and trajectory parity through
 ``SparseTableCTRTrainer.fit``."""
 
+from functools import partial
+
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -24,8 +26,19 @@ def _dedup_both(ids, size=None):
 
 def _assert_dedup_equal(ref, got):
     for a, b, what in zip(ref, got, ("uids", "inv", "count")):
+        assert a.dtype == b.dtype and a.shape == b.shape, what
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
                                       err_msg=what)
+
+
+def _unique_oracle(ids, size):
+    """The literal call ``_dedup_reference`` was until PR 29, kept here as
+    the oracle the three-sort body is held to, output for output."""
+    ids = jnp.asarray(ids).reshape(-1)
+    u, inv = jnp.unique(ids, return_inverse=True, size=size, fill_value=0)
+    inv = inv.reshape(-1).astype(jnp.int32)
+    count = (jnp.max(inv) + 1).astype(jnp.int32) if ids.shape[0] else jnp.int32(0)
+    return u, inv, count
 
 
 # -- (a) dedup: exact jnp.unique contract --------------------------------
@@ -35,12 +48,8 @@ def test_dedup_matches_unique_random(rng):
     ids = rng.integers(0, 500, size=777).astype(np.int32)
     ref, got = _dedup_both(ids)
     _assert_dedup_equal(ref, got)
-    # and against jnp.unique directly (the reference IS the old call)
-    u, inv = jnp.unique(jnp.asarray(ids), return_inverse=True,
-                        size=777, fill_value=0)
-    np.testing.assert_array_equal(np.asarray(ref[0]), np.asarray(u))
-    np.testing.assert_array_equal(np.asarray(ref[1]),
-                                  np.asarray(inv).reshape(-1))
+    # and against jnp.unique directly (the reference WAS that call)
+    _assert_dedup_equal(_unique_oracle(ids, 777), ref)
 
 
 def test_dedup_duplicate_heavy_and_degenerate_streams(rng):
@@ -83,6 +92,83 @@ def test_dedup_truncation_keeps_full_ranks(rng):
     _assert_dedup_equal(ref, got)
     assert int(ref[2]) == 50
     assert int(np.asarray(ref[1]).max()) == 49  # ranks beyond the cut
+
+
+def _power_law_ids(k, vocab, seed):
+    """A head-heavy stream: most slots repeat a few hundred ids, the rest
+    spread over the vocabulary, id 0 among them."""
+    r = np.random.default_rng(seed)
+    ids = (vocab * r.random(k) ** 6).astype(np.int64).clip(0, vocab - 1)
+    ids[::97] = 0
+    return ids.astype(np.int32)
+
+
+_INT32_MAX = np.iinfo(np.int32).max
+
+#: name -> (ids, size, x64): the streams the XLA twin is held to
+#: ``jnp.unique`` on, bit for bit
+_ORACLE_CASES = {
+    "all_distinct": (np.random.default_rng(1).permutation(
+        np.arange(1, 1025)).astype(np.int32), None, False),
+    "all_equal": (np.full(300, 7, np.int32), None, False),
+    "id0_present": (np.random.default_rng(2).choice(
+        [0, 1, 7, 90], size=500).astype(np.int32), None, False),
+    "id0_absent": (np.random.default_rng(3).choice(
+        [3, 9, 11], size=500).astype(np.int32), None, False),
+    "all_zero": (np.zeros(64, np.int32), None, False),
+    "power_law_8192": (_power_law_ids(8192, 1 << 25, 4), None, False),
+    "power_law_16384": (_power_law_ids(16384, 1 << 20, 5), None, False),
+    "size_below_count": (np.random.default_rng(6).permutation(
+        np.arange(1, 51)).astype(np.int32), 10, False),
+    "size_above_k": (np.random.default_rng(7).integers(
+        0, 40, size=64).astype(np.int32), 200, False),
+    "k1": (np.array([42], np.int32), None, False),
+    "k1_size3": (np.array([0], np.int32), 3, False),
+    "k0": (np.zeros((0,), np.int32), 4, False),
+    "int32_extremes": (np.array(
+        [_INT32_MAX, 0, -5, _INT32_MAX, -(2 ** 31), 3, -5], np.int32),
+        None, False),
+    "int64_above_2_31": (np.random.default_rng(8).choice(
+        np.array([0, 5, 2 ** 31, 2 ** 31 + 1, 2 ** 40 + 3, 2 ** 62],
+                 np.int64), size=400), None, True),
+}
+
+
+@pytest.mark.parametrize("jitted", [False, True], ids=["eager", "jit"])
+@pytest.mark.parametrize("case", sorted(_ORACLE_CASES))
+def test_dedup_xla_twin_is_jnp_unique_bit_for_bit(case, jitted, monkeypatch):
+    """The three-sort body against the literal ``jnp.unique`` call, through
+    the dispatcher (so K = 0 takes its early return): values, dtypes and
+    shapes of uids, the full-rank inverse and the count."""
+    ids, size, x64 = _ORACLE_CASES[case]
+    monkeypatch.setenv(sk.ENV_FLAG, "xla")
+    size = ids.shape[0] if size is None else size
+    with jax.enable_x64(x64):
+        dedup = partial(sk.dedup_ids, size=size)
+        got = (jax.jit(dedup) if jitted else dedup)(jnp.asarray(ids))
+        assert got[0].dtype == ids.dtype     # an int64 stream keeps its width
+        _assert_dedup_equal(_unique_oracle(ids, size), got)
+
+
+def test_dedup_xla_twin_compiles_to_three_sorts_and_nothing_k_sized_else():
+    """What PR 29 bought, guarded without a chip: at K = 8,192 (the ladder
+    live) the jitted dedup's optimized HLO holds three sorts and no
+    gather and no scatter — ``jnp.unique`` compiles here to one sort, two
+    gathers and two scatters, and on a v5e each of those K-sized passes
+    costs 5-9 sorts."""
+    def hlo(fn):
+        return jax.jit(fn).lower(
+            jax.ShapeDtypeStruct((8192,), jnp.int32)).compile().as_text()
+
+    def count(text):
+        return {op: text.count(f" {op}(")
+                for op in ("sort", "gather", "scatter")}
+
+    new = hlo(lambda ids: sk.KERNELS["dedup_ids"].reference(ids, 8192))
+    assert count(new) == {"sort": 3, "gather": 0, "scatter": 0}
+    # the counter sees what it is there to see: the old body's passes
+    old = hlo(lambda ids: _unique_oracle(ids, 8192))
+    assert count(old)["gather"] > 0 and count(old)["scatter"] > 0
 
 
 # -- (b) merge + fused merge-apply ---------------------------------------
