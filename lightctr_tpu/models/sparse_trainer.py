@@ -31,10 +31,12 @@ a no-op ``add``.
 
 Scope: Adagrad (the reference PS's workhorse); single-device, data-sharded
 batches, and PS-style ``param_shardings`` (tables row-sharded over the
-``embed`` axis: the touched-row gather/scatter compose with GSPMD — XLA
-inserts the cross-shard collectives around the O(touched) row ops, which
-is exactly the reference's worker→PS-shard pull/push topology,
-pull.h:50-99 / distributed_algo_abst.h:176-280).
+``embed`` axis: each shard gathers and applies the rows it owns, on the
+rung of the ladder its own rows need, inside a ``shard_map`` over that
+axis, and one ``psum`` joins the gathered rows — the reference's
+worker→PS-shard pull/push topology, pull.h:50-99 /
+distributed_algo_abst.h:176-280; the rest of the step stays one GSPMD
+program).
 
 Multi-device replicated data parallelism (``mesh`` given, no
 ``param_shardings``) runs an EXPLICIT hybrid exchange instead of letting
@@ -108,12 +110,15 @@ path (see docs/KERNELS.md).
 
 from __future__ import annotations
 
+from functools import partial
 from typing import Dict, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
+from jax import shard_map
+from jax.sharding import PartitionSpec as P
 
 from lightctr_tpu import obs
 from lightctr_tpu.models.ctr_trainer import CTRTrainer, _health_pack
@@ -180,8 +185,8 @@ EXCHANGE_SERIES = (
     # the sized XLA apply (ops.sparse_kernels.apply_ladder): live rows a
     # step against the slots of the rung it took; their ratio is the live
     # share (metrics_report --kernels)
-    "trainer_apply_live_rows_total",     # {table}
-    "trainer_apply_slots_total",         # {table}
+    "trainer_apply_live_rows_total",     # {table[, shard]}
+    "trainer_apply_slots_total",         # {table[, shard]}
 )
 
 
@@ -293,8 +298,8 @@ class SparseTableCTRTrainer(CTRTrainer):
         # JSONs cannot disagree
         self.exchange_bytes_per_step: Dict[str, int] = {}
         self._exchange_logged = False
-        # the step's per-table distinct counts, handed from
-        # _health_signals to _count_apply_slots
+        # the step's per-table distinct counts and sorted distinct ids,
+        # handed from _health_signals to _count_apply_slots
         self._last_touch = None
         # reduce-scatter capacity safety net: rs capacities are EXPECTED
         # sizes with slack (dist.collectives.rs_default_caps), so every
@@ -453,14 +458,32 @@ class SparseTableCTRTrainer(CTRTrainer):
             groups.setdefault(tuple(fields), []).append(k)
         return groups
 
+    def _row_shards(self) -> Dict[str, str]:
+        """{table: mesh axis} for the tables whose rows ``param_shardings``
+        shards over one mesh axis of more than one device: there the
+        gather and the apply run per shard (docs/KERNELS.md, "On a mesh").
+        Read off the sharding, so no mesh, a replicated table or an axis
+        of size 1 gives ``{}`` and the step traces as on one device."""
+        if self.mesh is None or not isinstance(self._param_sharding, dict):
+            return {}
+        out = {}
+        for k in self._spec:
+            spec = getattr(self._param_sharding.get(k), "spec", None)
+            axis = spec[0] if spec else None
+            if isinstance(axis, str) and self.mesh.shape[axis] > 1:
+                out[k] = axis
+        return out
+
     @staticmethod
-    def _dedup_and_gather(spec, params, batch):
+    def _dedup_and_gather(spec, params, batch, mesh=None, row_shards=None):
         """Steps 1-3 of the module recipe: per-table batch-id dedup,
         position rewrite, and the O(touched) row gather — of the live
         prefix of the slots, on the apply's ladder
-        (``sparse_kernels.gather_live``).  Shared by the single-program
-        step and the per-replica hybrid step (where ``batch`` is the
-        replica's local shard).
+        (``sparse_kernels.gather_live``); a table in ``row_shards``
+        (``{table: axis}`` of ``mesh``) gathers each shard's own rows on
+        the shard's own rung (``sparse_kernels.gather_shards``).  Shared
+        by the single-program step and the per-replica hybrid step (where
+        ``batch`` is the replica's local shard).
 
         Tables listing the IDENTICAL field tuple run the dedup once and
         share the resulting ``(uids, inv)`` — their position rewrites
@@ -478,6 +501,18 @@ class SparseTableCTRTrainer(CTRTrainer):
         batch2 = dict(batch)
         uids = {}
         groups = SparseTableCTRTrainer._field_groups(spec)
+
+        def gather(k):
+            axis = row_shards.get(k) if row_shards else None
+            if axis is None:
+                return sparse_kernels.gather_live(
+                    tables[k],
+                    *sparse_kernels.live_plan(uids[k], tables[k].shape[0]))
+            return shard_map(
+                partial(sparse_kernels.gather_shards, axis_name=axis),
+                mesh=mesh, in_specs=(P(axis), P()), out_specs=P(),
+            )(tables[k], uids[k])
+
         with annotate("sparse_tables/dedup_gather", tables=len(spec),
                       id_streams=len(groups)):
             for fields, keys in groups.items():
@@ -495,12 +530,7 @@ class SparseTableCTRTrainer(CTRTrainer):
             # the forward gather reads the live prefix of the slots, on
             # the apply's ladder; the rows behind the rung are zeros, and
             # ``inv`` never points past the live prefix
-            rows = {
-                k: sparse_kernels.gather_live(
-                    tables[k],
-                    *sparse_kernels.live_plan(uids[k], tables[k].shape[0]))
-                for k in spec
-            }
+            rows = {k: gather(k) for k in spec}
         return tables, dense, batch2, uids, rows
 
     def _make_step(self):
@@ -510,10 +540,33 @@ class SparseTableCTRTrainer(CTRTrainer):
         spec = self._spec
         lr, eps = self.cfg.learning_rate, self._eps
         dedup_and_gather = self._dedup_and_gather
+        mesh, row_shards = self.mesh, self._row_shards()
+
+        def apply(k, table, accum, u, g):
+            """Touched-row apply through the kernel registry: the XLA twin
+            is sparse_adagrad_update's arithmetic over the live prefix of
+            uids (already sorted unique; padded id-0 repeats carry zero
+            gradient and are dropped), the Pallas variant one pass per
+            row.  Row-sharded tables apply per shard: each its own rows,
+            on its own rung, every ``data`` replica of a shard alike."""
+            from lightctr_tpu.ops import sparse_kernels
+
+            axis = row_shards.get(k)
+
+            def one(table, accum, u, g):
+                return sparse_kernels.merge_apply(
+                    table, accum, u, g, None, lr=lr, eps=eps,
+                    shard_axis=axis)[:2]
+
+            if axis is not None:
+                one = shard_map(one, mesh=mesh,
+                                in_specs=(P(axis), P(axis), P(), P()),
+                                out_specs=(P(axis), P(axis)))
+            return one(table, accum, u, g)
 
         def step(params, opt_state, batch):
             tables, dense, batch2, uids, rows = dedup_and_gather(
-                spec, params, batch
+                spec, params, batch, mesh, row_shards
             )
 
             def loss_on(rows, dense):
@@ -539,23 +592,10 @@ class SparseTableCTRTrainer(CTRTrainer):
 
             new_accum = {}
             with annotate("sparse_tables/apply"):
-                from lightctr_tpu.ops import sparse_kernels
-
                 for k in spec:
-                    # touched-row apply through the kernel registry: the
-                    # XLA twin is sparse_adagrad_update's arithmetic over
-                    # the live prefix of uids (already sorted unique;
-                    # padded id-0 repeats carry zero gradient and are
-                    # dropped), the Pallas variant one pass per row
-                    tables[k], new_accum[k], _ = sparse_kernels.merge_apply(
-                        tables[k],
-                        opt_state["accum"][k],
-                        uids[k],
-                        g_rows[k],
-                        None,
-                        lr=lr,
-                        eps=eps,
-                    )
+                    tables[k], new_accum[k] = apply(
+                        k, tables[k], opt_state["accum"][k], uids[k],
+                        g_rows[k])
 
             params = {**dense, **tables}
             health = self._append_sketch(
@@ -1569,39 +1609,54 @@ class SparseTableCTRTrainer(CTRTrainer):
         hm = self.health
         if hm is None or not hm.wants("table_touch"):
             return {}
-        touch = {}
+        touch, distinct = {}, {}
         for k, fields in self._spec.items():
             ids = np.concatenate(
                 [np.asarray(batch[f]).reshape(-1) for f in fields]
             )
+            distinct[k] = np.unique(ids)
             touch[k] = {
-                "unique": int(np.unique(ids).size),
+                "unique": int(distinct[k].size),
                 "ids": int(ids.size),
                 "vocab": int(self.params[k].shape[0]),
             }
-        self._last_touch = touch
+        self._last_touch = touch, distinct
         return {"table_touch": touch}
 
     def _count_apply_slots(self) -> None:
         """How often the sized apply engages: live rows against the slots
         of the rung taken (``sparse_kernels.ladder_slots``, the function
-        the device's switch indexes), from the distinct counts
-        :meth:`_health_signals` made on the host this step — no fetch of
-        its own.  Only where the one-program step's XLA apply runs: the
-        exchange steps apply the merged global ids, which the host never
-        counts."""
+        the device's switch indexes), from the sorted distinct ids
+        :meth:`_health_signals` made on the host this step — no fetch and
+        no ``np.unique`` of its own.  A row-sharded table counts per
+        shard (label ``shard``): each shard takes the rung that holds the
+        distinct ids in its own row range, one ``searchsorted`` of the
+        shard bounds.  Only where the one-program step's XLA apply runs:
+        the exchange steps apply the merged global ids, which the host
+        never counts."""
         from lightctr_tpu.ops import sparse_kernels
 
-        touch, self._last_touch = self._last_touch, None
-        if (not touch or self._hybrid_dp or self._hier
+        last, self._last_touch = self._last_touch, None
+        if (not last or self._hybrid_dp or self._hier
                 or sparse_kernels.resolve_impl("merge_apply") != "xla"):
             return
+        touch, distinct = last
         reg = self.telemetry
+        row_shards = self._row_shards()
         for k, t in touch.items():
-            reg.inc(obs.labeled("trainer_apply_live_rows_total", table=k),
-                    t["unique"])
-            reg.inc(obs.labeled("trainer_apply_slots_total", table=k),
-                    sparse_kernels.ladder_slots(t["ids"], t["unique"]))
+            per = [({"table": k}, t["unique"])]
+            if k in row_shards:
+                n = self.mesh.shape[row_shards[k]]
+                cuts = np.searchsorted(
+                    distinct[k], np.arange(1, n) * (t["vocab"] // n))
+                per = [({"table": k, "shard": i}, int(rows))
+                       for i, rows in enumerate(np.diff(
+                           np.concatenate([[0], cuts, [t["unique"]]])))]
+            for labels, rows in per:
+                reg.inc(obs.labeled("trainer_apply_live_rows_total",
+                                    **labels), rows)
+                reg.inc(obs.labeled("trainer_apply_slots_total", **labels),
+                        sparse_kernels.ladder_slots(t["ids"], rows))
 
     def _record_step(self, dt: float, batch, health=None) -> None:
         super()._record_step(dt, batch, health=health)

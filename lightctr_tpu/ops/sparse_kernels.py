@@ -28,7 +28,8 @@ for the TPU port:
     same pass.  Its XLA twin — what a TPU runs at the trainer's widths —
     works on the live prefix of the dedup slots and not on all K of them
     (:func:`apply_ladder`, :func:`live_plan`; docs/KERNELS.md "The sized
-    apply").
+    apply"), and on a mesh on each row shard's own run of them
+    (:func:`shard_plan`, :func:`gather_shards`).
   - :func:`quantize_pack` / :func:`quantize_pack_ef` — quantile-codec
     payload packing (the wire codes of ``ops.quantize``) with the error-
     feedback residual folded into the same pass: compensate, encode,
@@ -66,6 +67,7 @@ CPU-safe twin cannot land.
 
 from __future__ import annotations
 
+import math
 import os
 from functools import lru_cache, partial
 from typing import Callable, Dict, NamedTuple, Optional, Tuple
@@ -406,29 +408,74 @@ def ladder_slots(k: int, count: int) -> int:
     return next(s for s in apply_ladder(k) if s >= count)
 
 
-def live_plan(uids: jax.Array, vocab: int):
-    """``(idx, branch)`` for one dedup-convention id vector ``uids`` [K].
+def _slide(x: jax.Array, start, back: bool = False) -> jax.Array:
+    """``x`` slid ``start`` slots along axis 0, zeros coming in: ``out[j]
+    = x[start + j]``, or ``x[j - start]`` with ``back``.  A pad and a
+    dynamic slice (``start`` may be traced, in ``[0, K]``)."""
+    k = x.shape[0]
+    wide = jnp.pad(x, ((k, 0) if back else (0, k),)
+                   + ((0, 0),) * (x.ndim - 1))
+    return jax.lax.dynamic_slice_in_dim(wide, k - start if back else start, k)
 
-    A slot is live when it is slot 0 or its id is not 0; ``count`` is the
-    last live slot + 1 (one pass over K int32).  ``idx`` is ``uids`` with
-    every pad slot sent past the table (``vocab + slot``: ascending, so a
-    scatter with ``mode="drop"`` skips it and a sorted, unique live prefix
-    stays sorted and unique over any rung).  ``branch`` indexes
-    ``apply_ladder(K)`` by the smallest rung holding ``count`` — or is
-    ``len(ladder)``, the undeclared full-K branch, when ``idx`` is not
-    strictly ascending (the reduce-scatter exchange hands per-owner
-    sorted segments with pads between them): sortedness is observed,
-    never assumed."""
+
+def shard_plan(uids: jax.Array, rows: int, lo=None):
+    """``(idx, branch, start, count)`` for the table rows ``[lo, lo +
+    rows)`` of one dedup-convention id vector ``uids`` [K]; ``lo=None`` is
+    the whole table (``start`` is then the int 0 and nothing is slid).
+
+    A slot is live when it is slot 0 or its id is not 0, and a shard's own
+    when its id is also in the shard's range.  ``uids`` come sorted, so the
+    own slots are one run ``[start, start + count)`` of the live prefix;
+    the plan brings that run to slot 0: ``idx[j]`` is the LOCAL row
+    ``uids[start + j] - lo`` for ``j < count`` and past the local table
+    behind it (``rows + start + j``: ascending, so a scatter with
+    ``mode="drop"`` skips it and a sorted, unique run stays sorted and
+    unique over any rung).  The caller slides what it pairs with ``idx`` by
+    the same ``start``.  ``branch`` indexes ``apply_ladder(K)`` by the
+    smallest rung holding ``count`` — or is ``len(ladder)``, the undeclared
+    full-K branch, when ``idx`` is not strictly ascending (the
+    reduce-scatter exchange hands per-owner sorted segments with pads
+    between them; own slots that are not one ascending run read so too).
+    Then nothing is slid: ``start`` is 0 and ``idx`` names every own slot
+    where it stands.  Sortedness is observed, never assumed."""
     k = uids.shape[0]
     # int64 id streams (the billion-row regime) keep their width
     slot = jnp.arange(k, dtype=jnp.promote_types(uids.dtype, jnp.int32))
     live = (uids != 0) | (slot == 0)
-    count = jnp.max(jnp.where(live, slot + 1, 0))
-    idx = jnp.where(live, uids.astype(slot.dtype), vocab + slot)
+    ids = uids.astype(slot.dtype)
+    if lo is None:
+        start = 0
+        idx = jnp.where(live, ids, rows + slot)
+        count = jnp.max(jnp.where(live, slot + 1, 0))
+        ascending = jnp.all(idx[1:] > idx[:-1])
+    else:
+        local = ids - jnp.asarray(lo, slot.dtype)
+        own = live & (local >= 0) & (local < rows)
+        start = jnp.sum(live & (local < 0), dtype=jnp.int32)
+        count = jnp.sum(own, dtype=jnp.int32)
+        idx = jnp.where(own, local, rows + slot)
+        # the run at slot 0; the pads that come in behind slot K go on
+        # ascending past the table.  Ascending with every own slot in it:
+        # the run it was taken for
+        run = jax.lax.dynamic_slice_in_dim(
+            jnp.concatenate([idx, rows + k + slot]), start, k)
+        ascending = (jnp.all(run[1:] > run[:-1])
+                     & (jnp.sum(run < rows, dtype=jnp.int32) == count))
+        idx = jnp.where(ascending, run, idx)
+        start = jnp.where(ascending, start, 0)
     ladder = apply_ladder(k)
     rung = jnp.sum(count > jnp.asarray(ladder, jnp.int32))
-    ascending = jnp.all(idx[1:] > idx[:-1])
-    return idx, jnp.where(ascending, rung, len(ladder)).astype(jnp.int32)
+    branch = jnp.where(ascending, rung, len(ladder)).astype(jnp.int32)
+    return idx, branch, start, count
+
+
+def live_plan(uids: jax.Array, vocab: int):
+    """``(idx, branch)`` of :func:`shard_plan` for a whole table of
+    ``vocab`` rows: ``idx`` is ``uids`` with every pad slot sent past the
+    table (``vocab + slot``), ``branch`` the smallest rung of
+    ``apply_ladder(K)`` that holds the live prefix (one pass over K
+    int32), or the undeclared branch for ids seen out of order."""
+    return shard_plan(uids, vocab)[:2]
 
 
 def _ladder_branches(k: int, rung: Callable) -> list:
@@ -438,23 +485,63 @@ def _ladder_branches(k: int, rung: Callable) -> list:
     return [rung(s, True) for s in apply_ladder(k)] + [rung(k, False)]
 
 
-def gather_live(block: jax.Array, idx: jax.Array, branch: jax.Array):
+def gather_live(block: jax.Array, idx: jax.Array, branch: jax.Array,
+                zero_pads: bool = False):
     """``block[idx]`` over the rung ``branch`` names, zero rows behind it
     ([K, ...] whatever the rung).  ``block`` is used once in each branch,
     which is what lets XLA keep a donated table in place around the
-    switch (docs/KERNELS.md, "Reading tools/aot_step.py")."""
+    switch (docs/KERNELS.md, "Reading tools/aot_step.py").  A pad slot
+    inside the rung reads the table's last row (clip) — no caller reads a
+    pad slot's row — unless ``zero_pads`` asks for zeros there too."""
     k = idx.shape[0]
+    mode = dict(mode="fill", fill_value=0) if zero_pads else dict(mode="clip")
 
     def rung(s, ordered):
         def f(block, idx):
-            # pads sit past the table: clip reads its last row for them,
-            # and no caller reads a pad slot's row
-            rows = jnp.take(block, idx[:s], axis=0, mode="clip",
-                            indices_are_sorted=ordered)
+            rows = jnp.take(block, idx[:s], axis=0,
+                            indices_are_sorted=ordered, **mode)
             return jnp.pad(rows, ((0, k - s),) + ((0, 0),) * (rows.ndim - 1))
         return f
 
     return jax.lax.switch(branch, _ladder_branches(k, rung), block, idx)
+
+
+def gather_shards(block: jax.Array, uids: jax.Array, axis_name: str):
+    """``table[uids]`` in ``uids`` order ([K, ...], zero rows behind the
+    live prefix) where ``block`` is this device's shard of the table's
+    rows along the mapped axis ``axis_name``: call it inside a
+    ``shard_map`` with ``uids`` replicated.  Each shard gathers its own
+    run of the slots on its own rung (:func:`shard_plan`; that switch
+    holds no collective) with zeros wherever a slot is not its own —
+    the shards' rows are summed next, and clip's last row must not leak
+    into the sum.  The join is a second switch, over the rung the WHOLE
+    live prefix takes — its index comes from the replicated ``uids`` and
+    is the same on every device: there the run is slid back to where it
+    stands in ``uids`` and one ``psum`` adds the shards."""
+    k, rows = uids.shape[0], block.shape[0]
+    lo = jax.lax.axis_index(axis_name) * rows
+    idx, branch, start, _ = shard_plan(uids, rows, lo)
+    part = gather_live(block, idx, branch, zero_pads=True)
+    tail = block.shape[1:]
+    width = math.prod(tail)
+
+    def rung(s, ordered):
+        del ordered
+
+        def f(part, start):
+            # every run ends inside the whole prefix's rung, so the slide
+            # and the sum are made at s, not at K — and flat, up to the
+            # zeros behind the rung: XLA:TPU all-reduces an [s, 32]
+            # operand row-major with its 32 lanes padded to 128, four
+            # times the bytes, and moves a reshape that only wraps the
+            # psum out of the way (docs/KERNELS.md, "On a mesh")
+            own = _slide(part[:s].reshape(-1), start * width, back=True)
+            joined = jax.lax.psum(own, axis_name)
+            return jnp.pad(joined, (0, (k - s) * width)).reshape((k,) + tail)
+        return f
+
+    _, whole = live_plan(uids, rows * jax.lax.axis_size(axis_name))
+    return jax.lax.switch(whole, _ladder_branches(k, rung), part, start)
 
 
 def _scatter_live(table, accum, idx, delta, acc, branch):
@@ -486,6 +573,7 @@ def _merge_apply_reference(
     lr: float,
     eps: float,
     denom: float,
+    shard_axis: Optional[str] = None,
 ):
     """The XLA apply: segment-merge (when ``inv`` is given), scale, health
     sum-of-squares, then ``embed.table.sparse_adagrad_update``'s
@@ -495,7 +583,13 @@ def _merge_apply_reference(
     (:func:`gather_live`), the arithmetic runs at K, one more switch
     scatters into table and accumulator (:func:`_scatter_live`).  The ids
     are trusted to be unique, as the contract states them, so nothing is
-    deduplicated a second time."""
+    deduplicated a second time.
+
+    With ``shard_axis`` (see :func:`merge_apply`) ``table`` and ``accum``
+    are one shard's rows: the plan is the shard's own
+    (:func:`shard_plan`), the gradient rows are slid by its ``start`` to
+    pair with it, and the same arithmetic runs on the shard's run of the
+    slots over the shard's rung.  ``sumsq`` stays the whole payload's."""
     k = uids.shape[0]
     if inv is not None:
         merged = jax.ops.segment_sum(rows, inv, num_segments=k)
@@ -505,7 +599,14 @@ def _merge_apply_reference(
         merged = merged / denom
     sumsq = jnp.sum(merged * merged)
     g = merged.reshape((k,) + table.shape[1:]).astype(table.dtype)
-    idx, branch = live_plan(uids, table.shape[0])
+    if shard_axis is None:
+        idx, branch = live_plan(uids, table.shape[0])
+    else:
+        lo = jax.lax.axis_index(shard_axis) * table.shape[0]
+        idx, branch, start, _ = shard_plan(uids, table.shape[0], lo)
+        # rows behind the run are other shards' gradients: their slots
+        # are past the table, and the scatters drop them
+        g = _slide(g, start)
     acc = gather_live(accum, idx, branch) + g * g
     delta = -lr * g * jax.lax.rsqrt(acc + eps)
     new_table, new_accum = _scatter_live(table, accum, idx, delta, acc, branch)
@@ -639,6 +740,7 @@ def merge_apply(
     lr: float,
     eps: float = 1e-7,
     denom: float = 1.0,
+    shard_axis: Optional[str] = None,
 ):
     """Dispatch: one-pass segment-merge + scaled Adagrad apply over the
     touched rows of ``table``/``accum``.
@@ -649,6 +751,14 @@ def merge_apply(
     per-uid rows [S, ...] (the reduce-scatter path, whose merge happened
     owner-side mid-exchange).  ``denom`` scales the merged rows
     (``merged / denom`` — the exchange's mean) before the apply.
+
+    ``shard_axis`` names the mapped mesh axis the table's rows are sharded
+    over, for a call inside a ``shard_map``: ``table`` / ``accum`` are
+    then this device's rows ``[i * V_e, (i + 1) * V_e)``, ``uids`` and
+    ``rows`` the replicated global ones, and each shard applies the rows
+    it owns on the rung that holds them (docs/KERNELS.md, "On a mesh").
+    A static rule takes the XLA twin there: the Pallas kernel reads whole-
+    table ids.
 
     Returns ``(table', accum', sumsq)``; ``sumsq`` is the merged rows'
     sum of squares (the health gradient-norm contribution) computed in
@@ -672,6 +782,9 @@ def merge_apply(
         rows = rows * valid.astype(rows.dtype).reshape(
             (-1,) + (1,) * (rows.ndim - 1)
         )
+    if shard_axis is not None:
+        _, fn = _resolve("merge_apply", "xla")
+        return fn(table, accum, uids, rows, inv, lr, eps, denom, shard_axis)
     _, fn = _resolve("merge_apply")
     return fn(table, accum, uids, rows, inv, lr, eps, denom)
 

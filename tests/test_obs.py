@@ -867,6 +867,35 @@ def test_metrics_report_kernels_section(tmp_path, capsys, monkeypatch):
     assert report["fused_active"] is False
 
 
+@pytest.mark.parametrize("sharded", [False, True], ids=["table", "shards"])
+def test_metrics_report_kernels_apply_section(sharded, tmp_path, capsys):
+    """--kernels' ``apply`` section: live rows over the slots of the rung
+    taken, per table — and per row shard, beside the table's sums, on a
+    snapshot whose two counters carry the ``shard`` label."""
+    import tools.metrics_report as metrics_report
+
+    reg = obs.MetricsRegistry()
+    per = {"0": (22_870, 29_952), "1": (22_523, 29_952)} if sharded \
+        else {None: (45_393, 49_920)}
+    for shard, (live, slots) in per.items():
+        labels = dict(table="embed", **({"shard": shard} if sharded else {}))
+        reg.inc(obs.labeled("trainer_apply_live_rows_total", **labels), live)
+        reg.inc(obs.labeled("trainer_apply_slots_total", **labels), slots)
+    path = tmp_path / "snap.json"
+    path.write_text(json.dumps(reg.snapshot()))
+    assert metrics_report.main(["--kernels", str(path)]) == 0
+    entry = json.loads(capsys.readouterr().out)["apply"]["embed"]
+    if not sharded:
+        assert entry == {"live_rows": 45_393, "slots": 49_920,
+                         "live_share": 0.9093}
+        return
+    assert entry["shards"] == {
+        "0": {"live_rows": 22_870, "slots": 29_952, "live_share": 0.7636},
+        "1": {"live_rows": 22_523, "slots": 29_952, "live_share": 0.752}}
+    assert (entry["live_rows"], entry["slots"], entry["live_share"]) == (
+        45_393, 59_904, 0.7578)
+
+
 # -- exchange telemetry lints + report (ISSUE 10) ----------------------------
 
 

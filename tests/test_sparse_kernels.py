@@ -288,12 +288,20 @@ _RUNGS = sk.apply_ladder(_LK)
 
 
 def _live_case(rng, count, *, vocab=20000, shape=(4,), with_zero=False,
-               with_inv=False, interleave=False):
+               with_inv=False, interleave=False, per_shard=None):
     """Dedup-convention inputs at K = ``_LK`` with ``count`` live slots:
     ``(table, accum, uids, rows, inv)`` as numpy arrays (``inv`` None
-    unless ``with_inv``)."""
+    unless ``with_inv``).  ``per_shard``: how many of the ids fall in
+    each of ``len(per_shard)`` equal row ranges of the table."""
     k = _LK
-    ids = np.sort(rng.choice(np.arange(1, vocab), size=count, replace=False))
+    if per_shard is None:
+        per_shard = [count]
+    v = vocab // len(per_shard)
+    assert sum(per_shard) == count
+    ids = np.concatenate([
+        np.sort(rng.choice(np.arange(max(1, e * v), (e + 1) * v), size=c,
+                           replace=False))
+        for e, c in enumerate(per_shard)])
     if with_zero:
         ids[0] = 0
     uids = np.zeros(k, np.int32)
@@ -342,6 +350,24 @@ def _sized_apply_cases():
         ("interleaved_pads_1d", dict(count=777, shape=(), interleave=True)),
         ("embed_mesh", dict(count=_RUNGS[2] + 1, mesh=True)),
     ]
+    # the apply made per row shard (``shard_axis``, inside a shard_map):
+    # a rung's edge in one shard and one past it in the next, a real id
+    # 0, a shard that owns nothing, everything in the last shard, the 1-D
+    # table, a merged payload, and ids seen out of order
+    r0, r1 = _RUNGS[0], _RUNGS[1]
+    for name, per, kw in [
+        ("rung_edges", [r0, r0 + 1], {}),
+        ("rung_edges_4", [r0 + 1, 3, r0, r1 + 1], {}),
+        ("id0", [500, 700], dict(with_zero=True)),
+        ("empty_shard", [r1 + 2, 0, 9, 0], {}),
+        ("one_shard", [0, 0, 0, r1], {}),
+        ("one_row", [0, 1], {}),
+        ("1d", [r0 - 1, r1], dict(shape=())),
+        ("with_inv", [800, r0 + 5], dict(with_inv=True)),
+        ("interleaved_pads", [400, 377], dict(interleave=True)),
+    ]:
+        cases.append((f"shards_{name}", dict(
+            count=sum(per), per_shard=per, shards=True, **kw)))
     return [pytest.param(kw, id=name) for name, kw in cases]
 
 
@@ -360,6 +386,7 @@ def test_sized_apply_matches_sparse_adagrad_update(case):
 
     kw = dict(case)
     mesh = kw.pop("mesh", False)
+    shards = len(kw["per_shard"]) if kw.pop("shards", False) else 0
     count = kw["count"]
     rng = np.random.default_rng(count)
     table, accum, uids, rows, inv = _live_case(rng, **kw)
@@ -382,17 +409,27 @@ def test_sized_apply_matches_sparse_adagrad_update(case):
             jnp.asarray(rows), None if inv is None else jnp.asarray(inv)]
     fn = jax.jit(lambda w, a, u, r, i: sk.merge_apply(
         w, a, u, r, i, lr=lr, eps=eps, denom=denom))
-    if mesh:
+    if mesh or shards:
         from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-        m = Mesh(np.array(jax.devices()[:4]), ("embed",))
+        m = Mesh(np.array(jax.devices()[:shards or 4]), ("embed",))
         rowwise = NamedSharding(m, P("embed", *([None] * (table.ndim - 1))))
+    if shards:
+        # each shard applies its own rows: table and accumulator by rows,
+        # ids and payload whole; the sum of squares is the whole payload's
+        fn = jax.jit(jax.shard_map(
+            lambda w, a, u, r, i: sk.merge_apply(
+                w, a, u, r, i, lr=lr, eps=eps, denom=denom,
+                shard_axis="embed"),
+            mesh=m, in_specs=(P("embed"), P("embed"), P(), P(), P()),
+            out_specs=(P("embed"), P("embed"), P())))
+    if mesh:
         args[0] = jax.device_put(args[0], rowwise)
         args[1] = jax.device_put(args[1], rowwise)
         args[2] = jax.device_put(args[2], NamedSharding(m, P()))
         args[3] = jax.device_put(args[3], NamedSharding(m, P()))
     w1, a1, s1 = fn(*args)
-    if mesh:
+    if mesh or shards:
         assert w1.sharding.is_equivalent_to(rowwise, table.ndim)
 
     np.testing.assert_allclose(np.asarray(w1), np.asarray(w0),
@@ -410,13 +447,19 @@ def test_sized_apply_matches_sparse_adagrad_update(case):
         np.testing.assert_array_equal(np.asarray(a1)[0], accum[0])
 
     # the rung the device takes is the one the host names from the count
-    _, branch = sk.live_plan(jnp.asarray(uids), table.shape[0])
-    if kw.get("interleave"):
-        assert int(branch) == len(_RUNGS)       # observed unsorted
-    else:
-        assert _RUNGS[int(branch)] == sk.ladder_slots(_LK, count)
-        assert count <= _RUNGS[int(branch)]
-        assert int(branch) == 0 or _RUNGS[int(branch) - 1] < count
+    # — the whole table's, or each shard's from its own rows
+    v = table.shape[0] // max(shards, 1)
+    for e, n in enumerate(kw.get("per_shard", [count])):
+        _, branch, start, live = sk.shard_plan(
+            jnp.asarray(uids), v, e * v if shards else None)
+        if kw.get("interleave"):
+            assert int(branch) == len(_RUNGS)       # observed unsorted
+            assert int(start) == 0
+        else:
+            assert int(live) == n
+            assert _RUNGS[int(branch)] == sk.ladder_slots(_LK, n)
+            assert n <= _RUNGS[int(branch)]
+            assert int(branch) == 0 or _RUNGS[int(branch) - 1] < n
 
 
 def test_apply_ladder_is_a_function_of_k_alone():
@@ -446,6 +489,104 @@ def test_apply_ladder_is_a_function_of_k_alone():
         uids[:count] = np.arange(1, count + 1)
         assert ladder[int(plan(jnp.asarray(uids)))] == \
             sk.ladder_slots(k, int(count)), count
+
+
+def _shard_plan_cases():
+    r0, r1 = _RUNGS[0], _RUNGS[1]
+    cases = [
+        ("two_even", [700, 900], {}),
+        ("rung_edges", [r0, r0 + 1], {}),
+        ("four", [3, r0 + 1, 0, r1], {}),
+        ("id0", [40, 50], dict(with_zero=True)),
+        ("empty_first", [0, 321], {}),
+        ("all_in_first", [r1 + 1, 0, 0, 0], {}),
+        ("full", [_LK // 2, _LK // 2], {}),
+        ("unsorted", [300, 300], dict(interleave=True)),
+    ]
+    return [pytest.param(per, kw, id=name) for name, per, kw in cases]
+
+
+@pytest.mark.parametrize("per_shard,kw", _shard_plan_cases())
+def test_shard_plan_is_a_pure_function_of_uids_and_the_row_range(per_shard,
+                                                                 kw):
+    """``shard_plan`` per row range: ``start`` is the count of live ids
+    under the range, ``count`` the distinct ids in it, ``idx[:count]`` the
+    LOCAL rows ascending, every slot behind them past the local table and
+    still ascending, the rung the smallest that holds ``count``; the
+    ranges' runs tile the live prefix; ids seen out of order keep their
+    slots (``start`` 0) and take the undeclared branch; and the whole
+    table (``lo=None``) is ``live_plan``."""
+    count = sum(per_shard)
+    vocab = 20000
+    _, _, uids, _, _ = _live_case(np.random.default_rng(count), count,
+                                  vocab=vocab, per_shard=per_shard, **kw)
+    n, v = len(per_shard), vocab // len(per_shard)
+    plan = jax.jit(lambda u, lo: sk.shard_plan(u, v, lo))
+    slot = np.arange(_LK)
+    live = (uids != 0) | (slot == 0)
+    covered = np.zeros(_LK, bool)
+    for e, want in enumerate(per_shard):
+        idx, branch, start, got = (np.asarray(x) for x in
+                                   plan(jnp.asarray(uids), e * v))
+        own = live & (uids >= e * v) & (uids < (e + 1) * v)
+        assert own.sum() == want
+        if kw.get("interleave"):
+            assert int(branch) == len(_RUNGS) and int(start) == 0
+            np.testing.assert_array_equal(idx[own], uids[own] - e * v)
+            assert (idx[~own] >= v).all()
+            covered |= own
+            continue
+        assert (int(start), int(got)) == (int((live & (uids < e * v)).sum()),
+                                          want)
+        np.testing.assert_array_equal(
+            idx[:want], uids[start:start + want] - e * v)
+        assert (idx[want:] >= v).all()                 # pads: past the table
+        assert (np.diff(idx.astype(np.int64)) > 0).all()   # ascending, unique
+        assert _RUNGS[int(branch)] == sk.ladder_slots(_LK, want)
+        covered[start:start + want] = True
+    np.testing.assert_array_equal(covered, live)
+    idx, branch, start, got = sk.shard_plan(jnp.asarray(uids), vocab)
+    idx0, branch0 = sk.live_plan(jnp.asarray(uids), vocab)
+    assert start == 0 and int(branch) == int(branch0)
+    np.testing.assert_array_equal(np.asarray(idx), np.asarray(idx0))
+    if not kw.get("interleave"):
+        assert int(got) == count
+
+
+def test_shard_plan_sees_an_own_slot_outside_its_run():
+    """Out-of-order ids whose window reads ascending — one own id in slot
+    0, ahead of the lower shard's — still take the undeclared branch: the
+    plan counts its own slots inside the run it took."""
+    v = 10000
+    uids = np.zeros(_LK, np.int32)
+    uids[:2] = (15000, 5)
+    for e, local in ((0, 5), (1, 5000)):
+        idx, branch, start, count = sk.shard_plan(jnp.asarray(uids), v, e * v)
+        assert (int(branch), int(start), int(count)) == (len(_RUNGS), 0, 1)
+        assert int(idx[1 - e]) == local and (np.asarray(idx) >= v).sum() == _LK - 1
+
+
+@pytest.mark.parametrize("per_shard,kw", _shard_plan_cases())
+def test_gather_shards_joins_each_shards_own_rows(per_shard, kw):
+    """``gather_shards`` inside a shard_map over the table's row shards:
+    every live slot holds its table row, every other slot zeros — clip's
+    last row of a shard never leaks into the sum — for ``[V, d]`` and
+    ``w[V]``."""
+    from jax.sharding import Mesh, PartitionSpec as P
+
+    count, vocab = sum(per_shard), 20000
+    rng = np.random.default_rng(count)
+    table, _, uids, _, _ = _live_case(rng, count, vocab=vocab,
+                                      per_shard=per_shard, **kw)
+    m = Mesh(np.array(jax.devices()[:len(per_shard)]), ("embed",))
+    gather = jax.jit(jax.shard_map(
+        partial(sk.gather_shards, axis_name="embed"), mesh=m,
+        in_specs=(P("embed"), P()), out_specs=P()))
+    live = (uids != 0) | (np.arange(_LK) == 0)
+    for block in (table, table[:, 0]):
+        got = np.asarray(gather(jnp.asarray(block), jnp.asarray(uids)))
+        np.testing.assert_array_equal(got[live], block[uids[live]])
+        assert not got[~live].any()
 
 
 def test_forward_gather_reads_the_live_prefix(rng):

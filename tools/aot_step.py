@@ -166,7 +166,6 @@ def compile_step(cfg: Dict, mesh_axes: Optional[Dict[str, int]] = None):
     key = jax.random.PRNGKey(0)
     tiny = dict(cfg, vocab=1024)
     trainer = model.build_trainer(tiny, model.init_params(tiny, key))
-    step = trainer._build_step()
 
     topo = topologies.get_topology_desc(platform="tpu", topology_name=TOPOLOGY)
     specs = model.param_specs(cfg)
@@ -179,6 +178,12 @@ def compile_step(cfg: Dict, mesh_axes: Optional[Dict[str, int]] = None):
             return NamedSharding(mesh, P(*axes))
 
         batch_axes = ("data",) if "data" in mesh_axes else ()
+        # the step reads which tables are row-sharded, and over what, off
+        # the trainer: hand it the described mesh (no array can be put on
+        # a described device, so the constructor could not take it)
+        trainer.mesh = mesh
+        trainer._param_sharding = jax.tree_util.tree_map(
+            place, specs, is_leaf=lambda x: isinstance(x, tuple))
     else:
         one, shards = SingleDeviceSharding(topo.devices[0]), 1
 
@@ -186,6 +191,7 @@ def compile_step(cfg: Dict, mesh_axes: Optional[Dict[str, int]] = None):
             return one
 
         batch_axes = ()
+    step = trainer._build_step()
 
     def struct(x, axes=()):
         return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=place(axes))

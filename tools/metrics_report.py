@@ -346,7 +346,10 @@ def summarize_kernels(doc) -> dict:
     on.  Beside it, per table, how the sized XLA apply engaged:
     ``trainer_apply_live_rows_total`` over ``trainer_apply_slots_total``
     is the share of the slots it worked on that held a live row (the rest
-    is the rung's round-up; 1 - slots / ids is what the ladder spared)."""
+    is the rung's round-up; 1 - slots / ids is what the ladder spared).
+    Where a table's rows are sharded over a mesh axis the two counters
+    carry a ``shard`` label — each shard takes the rung its own rows
+    need — and the table's entry lists ``shards`` beside its sums."""
     snap = doc.get("telemetry", doc) if isinstance(doc, dict) else doc
     counters = snap.get("counters", {})
     phases: dict = {}
@@ -364,8 +367,12 @@ def summarize_kernels(doc) -> dict:
         for what in ("live_rows", "slots"):
             p = f"trainer_apply_{what}_total{{"
             if name.startswith(p):
-                table = _labels(name, p).get("table", "?")
-                apply.setdefault(table, {})[what] = int(val)
+                labels = _labels(name, p)
+                entry = apply.setdefault(labels.get("table", "?"), {})
+                entry[what] = entry.get(what, 0) + int(val)
+                if "shard" in labels:
+                    entry.setdefault("shards", {}).setdefault(
+                        labels["shard"], {})[what] = int(val)
         if not name.startswith(prefix):
             continue
         labels = _labels(name, prefix)
@@ -374,10 +381,13 @@ def summarize_kernels(doc) -> dict:
         phases.setdefault(phase, {})[impl] = \
             phases.get(phase, {}).get(impl, 0) + int(val)
         total_by_impl[impl] = total_by_impl.get(impl, 0) + int(val)
-    for entry in apply.values():
-        if entry.get("slots"):
-            entry["live_share"] = round(
-                entry.get("live_rows", 0) / entry["slots"], 4)
+    for table in apply.values():
+        if "shards" in table:
+            table["shards"] = dict(sorted(table["shards"].items()))
+        for entry in (table, *table.get("shards", {}).values()):
+            if entry.get("slots"):
+                entry["live_share"] = round(
+                    entry.get("live_rows", 0) / entry["slots"], 4)
     return {
         "apply": dict(sorted(apply.items())),
         "phases": {p: dict(sorted(v.items())) for p, v in
