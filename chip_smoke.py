@@ -16,9 +16,7 @@ vocabulary 2^20, embedding dim 32, hidden 64, batch 4096):
            against ``trainer.predict_proba`` on the same rows;
   kernels  every name in ``sparse_kernels.KERNELS``: the Pallas
            implementation compiled with ``interpret=False`` at the shapes
-           the trainer uses, run, and compared with its reference twin —
-           or, for a kernel the registry deselects on TPU, the compiler's
-           message;
+           the trainer uses, run, and compared with its XLA form;
   mesh     with four or more devices, the same trainer on
            ``MeshSpec(data=2, embed=2)`` with embed-sharded tables (the
            GSPMD step) and on ``MeshSpec(data=4)`` (the hybrid exchange
@@ -71,7 +69,6 @@ class Shape:
     score_rows: tuple = (1, 7, 256)
     lr: float = 0.05
     seed: int = 0
-    adagrad_n: int = 1 << 20   # fused_adagrad case: the wide table
     flash_t: int = 1024
     flash_d: int = 64
 
@@ -295,16 +292,6 @@ def phase_train(shape: Shape, cache, log=print):
     return trainer, facts
 
 
-def kernel_path_counters() -> Dict[str, int]:
-    """``trainer_kernel_path_total{phase,impl}``: which implementation each
-    dispatch phase really resolved (once per trace)."""
-    from lightctr_tpu import obs
-
-    snap = obs.default_registry().snapshot()["counters"]
-    return {k: int(v) for k, v in sorted(snap.items())
-            if k.startswith("trainer_kernel_path_total")}
-
-
 # -- serve --------------------------------------------------------------------
 
 
@@ -368,7 +355,7 @@ class KernelCase(NamedTuple):
     kernel: str                  # a name in sparse_kernels.KERNELS
     label: str                   # which of the trainer's shapes
     pallas: Callable             # (*arrays) -> outputs, interpret=False
-    twin: Callable               # (*arrays) -> outputs, the XLA reference
+    xla: Callable                # (*arrays) -> outputs, the XLA form
     specs: tuple                 # jax.ShapeDtypeStruct per argument
     make: Callable               # (np.random.Generator) -> arrays
     rtol: float = 0.0            # 0/0 = bit-exact
@@ -376,59 +363,25 @@ class KernelCase(NamedTuple):
 
 
 def kernel_cases(shape: Shape) -> List[KernelCase]:
-    """One case per registered kernel (two for ``merge_apply`` and
-    ``flash_attention``) at the shapes the trainer uses: ``K`` ids per
-    stream, the ``[vocab, dim]`` embedding table and the 1-D wide table."""
+    """One case per registered kernel (two for ``flash_attention``) at the
+    shapes the trainer uses: ``K`` ids per stream, ``dim`` lanes a row."""
     import jax
     import jax.numpy as jnp
 
     import lightctr_tpu.nn.flash_attention  # noqa: F401 (self-registers)
-    import lightctr_tpu.optim.fused_adagrad  # noqa: F401
     from lightctr_tpu.ops import quantize
     from lightctr_tpu.ops.sparse_kernels import KERNELS
 
-    k, vocab, dim = shape.ids_per_step, shape.vocab, shape.dim
-    f32, i32 = jnp.float32, jnp.int32
+    k, dim = shape.ids_per_step, shape.dim
+    f32 = jnp.float32
     S = jax.ShapeDtypeStruct
     t8 = quantize.build_table(-1.0, 1.0, bits=8)
-
-    def ids(rng):
-        return (rng.random(k) ** 4 * vocab).astype(np.int32)
-
-    def uids(rng):
-        """Dedup convention: sorted unique, id-0 padding."""
-        u = np.unique(ids(rng))
-        out = np.zeros(k, np.int32)
-        out[:u.size] = u
-        return out, u.size
 
     def rows(rng, shp, scale=0.01):
         return (scale * rng.standard_normal(shp)).astype(np.float32)
 
-    def apply_args(rng, tshape):
-        u, n = uids(rng)
-        g = rows(rng, (k,) + tshape[1:])
-        g[n:] = 0.0  # pad slots carry zero gradient by contract
-        return (rows(rng, tshape, 1.0), np.abs(rows(rng, tshape, 1.0)), u, g)
-
-    def ef_update_args(rng):
-        u, n = uids(rng)
-        mask = (np.arange(k) < n).astype(np.float32).reshape(k, 1)
-        return (rows(rng, (k, dim)) * mask, u, rows(rng, (vocab, dim)), mask)
-
     def kd(name):
         return KERNELS[name]
-
-    def apply_case(label, tshape):
-        return KernelCase(
-            "merge_apply", label,
-            lambda t, a, u, g: kd("merge_apply").pallas(
-                t, a, u, g, None, shape.lr, 1e-7, 1.0, interpret=False),
-            lambda t, a, u, g: kd("merge_apply").reference(
-                t, a, u, g, None, shape.lr, 1e-7, 1.0),
-            (S(tshape, f32), S(tshape, f32), S((k,), i32),
-             S((k,) + tshape[1:], f32)),
-            lambda rng: apply_args(rng, tshape), rtol=1e-5, atol=1e-6)
 
     def flash_case(causal):
         qkv = S((2, shape.flash_t, 4, shape.flash_d), f32)
@@ -445,28 +398,6 @@ def kernel_cases(shape: Shape) -> List[KernelCase]:
 
     return [
         KernelCase(
-            "dedup_ids", f"K={k}",
-            lambda x: kd("dedup_ids").pallas(x, k, interpret=False),
-            lambda x: kd("dedup_ids").reference(x, k),
-            (S((k,), i32),), lambda rng: (ids(rng),)),
-        KernelCase(
-            "gather_rows", f"[{vocab},{dim}] n={k}",
-            lambda t, i: kd("gather_rows").pallas(t, i, interpret=False),
-            lambda t, i: kd("gather_rows").reference(t, i),
-            (S((vocab, dim), f32), S((k,), i32)),
-            lambda rng: (rows(rng, (vocab, dim), 1.0), ids(rng))),
-        KernelCase(
-            "merge_rows", f"M=S={k} d={dim}",
-            lambda r, inv: kd("merge_rows").pallas(r, inv, k,
-                                                   interpret=False),
-            lambda r, inv: kd("merge_rows").reference(r, inv, k),
-            (S((k, dim), f32), S((k,), i32)),
-            lambda rng: (rows(rng, (k, dim)),
-                         np.sort(rng.integers(0, k, k)).astype(np.int32)),
-            rtol=1e-5, atol=1e-6),
-        apply_case(f"embed [{vocab},{dim}] S={k}", (vocab, dim)),
-        apply_case(f"w [{vocab}] S={k}", (vocab,)),
-        KernelCase(
             "quantize_pack", f"8-bit [{k},{dim}]",
             lambda x: kd("quantize_pack").pallas(t8, x, interpret=False),
             lambda x: kd("quantize_pack").reference(t8, x),
@@ -479,37 +410,9 @@ def kernel_cases(shape: Shape) -> List[KernelCase]:
             (S((k, dim), f32), S((k, dim), f32), S((k, 1), f32)),
             lambda rng: (rows(rng, (k, dim), 0.3), rows(rng, (k, dim)),
                          (rng.random((k, 1)) > 0.25).astype(np.float32))),
-        KernelCase(
-            "quantize_pack_ef_update", f"8-bit [{k},{dim}] into "
-                                       f"[{vocab},{dim}]",
-            lambda r, u, res, m: kd("quantize_pack_ef_update").pallas(
-                t8, r, u, res, m, interpret=False),
-            lambda r, u, res, m: kd("quantize_pack_ef_update").reference(
-                t8, r, u, res, m),
-            (S((k, dim), f32), S((k,), i32), S((vocab, dim), f32),
-             S((k, 1), f32)),
-            ef_update_args),
-        KernelCase(
-            "fused_adagrad", f"n={shape.adagrad_n}",
-            # the registered impls are jit wrappers that donate; the case
-            # compiles and runs the functions under them
-            lambda w, a, g: kd("fused_adagrad").pallas.__wrapped__(
-                w, a, g, shape.lr, 1e-7, 1 << 16, interpret=False),
-            lambda w, a, g: kd("fused_adagrad").reference.__wrapped__(
-                w, a, g, shape.lr, 1e-7, 1 << 16),
-            (S((shape.adagrad_n,), f32),) * 3,
-            lambda rng: (rows(rng, (shape.adagrad_n,), 1.0),
-                         np.abs(rows(rng, (shape.adagrad_n,), 1.0)),
-                         rows(rng, (shape.adagrad_n,))),
-            rtol=1e-6, atol=1e-7),
         flash_case(False),
         flash_case(True),
     ]
-
-
-def _brief(exc: BaseException, limit: int = 300) -> str:
-    text = " ".join(str(exc).split())
-    return f"{type(exc).__name__}: {text[:limit]}"
 
 
 def _timed(fn, args) -> tuple:
@@ -523,10 +426,8 @@ def _timed(fn, args) -> tuple:
 
 def phase_kernels(shape: Shape, log=print) -> List[Dict]:
     """Per registered kernel: compile the Pallas implementation for the
-    chip, run it, compare with the twin.  A kernel ``auto`` selects on TPU
-    that fails any of these fails the smoke.  A kernel the registry
-    deselects is expected not to compile at this width: its verdict is the
-    compiler's message (and a note when it compiles after all)."""
+    chip, run it, compare with the XLA form.  A kernel that fails any of
+    these fails the smoke."""
     import jax
 
     from lightctr_tpu.ops.sparse_kernels import KERNELS
@@ -537,39 +438,24 @@ def phase_kernels(shape: Shape, log=print) -> List[Dict]:
         raise RuntimeError(f"no smoke case for kernel(s) {sorted(missing)}")
     verdicts = []
     for case in cases:
-        deselected = KERNELS[case.kernel].deselected
         rng = np.random.default_rng(shape.seed)
         args = tuple(jax.device_put(a) for a in case.make(rng))
-        fact = {"kernel": case.kernel, "case": case.label,
-                "auto_on_tpu": "xla" if deselected else "pallas"}
+        fact = {"kernel": case.kernel, "case": case.label}
         pallas = jax.jit(case.pallas)
         t0 = time.perf_counter()
-        if deselected:
-            try:
-                pallas.lower(*args).compile()
-                compiled = True
-            except Exception as e:  # noqa: BLE001 — the verdict IS the message
-                compiled = False
-                fact.update(verdict="deselected", compiler=_brief(e))
-        else:
-            pallas.lower(*args).compile()
-            compiled = True
-        if compiled:
-            fact["compile_s"] = round(time.perf_counter() - t0, 3)
-            got, fact["pallas_s"] = _timed(pallas, args)
-            want, fact["twin_s"] = _timed(jax.jit(case.twin), args)
-            match = all(
-                np.allclose(np.asarray(g), np.asarray(w),
-                            rtol=case.rtol, atol=case.atol)
-                for g, w in zip(jax.tree_util.tree_leaves(got),
-                                jax.tree_util.tree_leaves(want)))
-            fact["verdict"] = (
-                ("deselected-but-compiles-" if deselected else "")
-                + ("matches-twin" if match else "MISMATCH"))
-            for key in ("pallas_s", "twin_s"):
-                fact[key] = round(fact[key], 6)
-            if not match and not deselected:
-                raise RuntimeError(f"[kernels] {fact}")
+        pallas.lower(*args).compile()
+        fact["compile_s"] = round(time.perf_counter() - t0, 3)
+        got, pallas_s = _timed(pallas, args)
+        want, xla_s = _timed(jax.jit(case.xla), args)
+        fact.update(pallas_s=round(pallas_s, 6), xla_s=round(xla_s, 6))
+        match = all(
+            np.allclose(np.asarray(g), np.asarray(w),
+                        rtol=case.rtol, atol=case.atol)
+            for g, w in zip(jax.tree_util.tree_leaves(got),
+                            jax.tree_util.tree_leaves(want)))
+        fact["verdict"] = "matches-xla" if match else "MISMATCH"
+        if not match:
+            raise RuntimeError(f"[kernels] {fact}")
         del args
         log("[kernels] " + " ".join(f"{k}={v}" for k, v in fact.items()))
         verdicts.append(fact)
@@ -692,8 +578,6 @@ def main() -> int:
     with tempfile.TemporaryDirectory(prefix="chip_smoke_") as workdir:
         caches = phase_data(shape, workdir, log)
         trainer, summary["train"] = phase_train(shape, caches["train"], log)
-        summary["kernel_paths"] = kernel_path_counters()
-        log(f"[train] kernel paths {summary['kernel_paths']}")
         summary["serve"] = phase_serve(shape, trainer, caches["eval"], log)
         one_chip_losses = summary["train"]["losses"]
         del trainer

@@ -911,10 +911,9 @@ def _ag_gather_ids(uids: jax.Array, axis_name: str):
     [K] id stream + the union/inverse mapping every member computes
     identically.  Split out so tables sharing one id stream (identical
     batch-field tuples) gather and dedup the ids ONCE — the row half
-    (:func:`_ag_merge_rows`) reuses ``inv`` per table.  The dedup routes
-    through the kernel registry (``ops.sparse_kernels.dedup_ids``): the
-    ``jnp.unique`` contract, on a TPU too by its XLA twin of three sorts
-    (the Pallas rank kernel is deselected there)."""
+    (:func:`_ag_merge_rows`) reuses ``inv`` per table.  The dedup is
+    ``ops.sparse_kernels.dedup_ids``: the ``jnp.unique`` contract by
+    three sorts."""
     from lightctr_tpu.ops import sparse_kernels
 
     all_ids = jax.lax.all_gather(uids, axis_name, tiled=True)
@@ -952,8 +951,8 @@ def _ag_exchange_rows(
     payloads under a FIXED ``compress_range`` (requires ``uids``): the
     carried remainder is compensated into this step's encode and the fresh
     clip+quantization error is scattered back at the rows' slots — the
-    compensate/encode/decode/error/carry-scatter chain runs as ONE fused
-    ``quantize_pack_ef_update`` pass through the kernel registry."""
+    compensate/encode/decode/error/carry-scatter chain is
+    ``sparse_kernels.quantize_pack_ef_update``."""
     use_ef = residual is not None
     if compress_bits is None:
         if use_ef:
@@ -981,9 +980,8 @@ def _ag_exchange_rows(
     # every VALID slot (non-pad) compensates — including ids whose
     # gradient is zero this step, so a carried clip remainder drains on
     # the id's next appearance rather than waiting for a nonzero gradient.
-    # The compensate/encode/decode/fresh-error/CARRY-SCATTER chain is ONE
-    # fused kernel pass (quantize_pack_ef_update): the residual update no
-    # longer runs as a separate gather + scatter HLO pair.
+    # The compensate/encode/decode/fresh-error/carry-scatter chain is
+    # quantize_pack_ef_update.
     mask = _ef_valid_mask(uids, rows)
     codes, new_residual, _ = sparse_kernels.quantize_pack_ef_update(
         table, rows, uids, residual, mask
@@ -1009,8 +1007,8 @@ def _ag_merge_rows(
 ):
     """Row half of the allgather sparse exchange: gather every member's
     [K, ...] value payload (optionally quantile-coded) and segment-merge
-    the duplicates through the shared ``inv`` (the merge rides the kernel
-    registry's ``merge_rows``).
+    the duplicates through the shared ``inv``
+    (``sparse_kernels.merge_rows``).
 
     ``residual``: optional [vocab, ...] per-member error-feedback table for
     CLIPPED payloads under a FIXED ``compress_range`` (requires ``uids``).
@@ -1251,7 +1249,7 @@ def _rs_gather_rows(
     (``dest``/``order`` from :func:`rs_owner_partition`, ``inv`` from
     :func:`_rs_merge_ids`): scatter this table's [K, ...] payload into
     destination buckets, route them over the ppermute ring, merge at the
-    owner (through the kernel registry's ``merge_rows``), and all-gather
+    owner (``sparse_kernels.merge_rows``), and all-gather
     the merged shards.  Tables sharing one id stream call this once each
     while the id plumbing runs once — the id bytes ride the wire a single
     time per group.

@@ -663,7 +663,7 @@ class TieredEmbeddingStore(SSPGateMixin, WriteLogMixin):
         """Install the externally-updated device pair (the trainer fast
         path's post-step donation hand-back).  Device mode only; shapes
         must match — the caller got the pair from :meth:`device_tables`
-        and ran the registry's fused merge_apply aliasing it in place.
+        and ran ``sparse_kernels.merge_apply`` aliasing it in place.
         ``touched_slots`` marks exactly those slots dirty (all occupied
         slots otherwise); ``expect_res_epoch`` fails loud when residency
         moved between the caller's gather and this adopt (its slot

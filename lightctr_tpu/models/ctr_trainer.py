@@ -140,7 +140,6 @@ class CTRTrainer:
         compress_range: float | str = 1.0,
         compress_mode: Optional[str] = None,
         error_feedback: Optional[bool] = None,
-        fused_adagrad: bool = False,
         zero_sharded: bool = False,
         quality_bins: Optional[int] = None,
         resources: Optional[bool] = None,
@@ -150,21 +149,6 @@ class CTRTrainer:
         self.logits_fn = logits_fn
         self.l2_fn = l2_fn
         self.fused_fn = fused_fn
-        if fused_adagrad and optimizer is not None:
-            raise ValueError("fused_adagrad replaces the optimizer argument")
-        if fused_adagrad and compress_bits is not None:
-            raise ValueError(
-                "fused_adagrad is not supported with compress_bits (the "
-                "compressed ring step applies the optax update path)"
-            )
-        if fused_adagrad and param_shardings is not None:
-            raise ValueError(
-                "fused_adagrad is not supported with param_shardings: GSPMD "
-                "has no partitioning rule for the Pallas call on row-sharded "
-                "tables (it would force an all-gather of the largest arrays); "
-                "use the optax path for sharded params"
-            )
-        self.fused_adagrad = fused_adagrad
         self.tx = optimizer or optim_lib.adagrad(cfg.learning_rate)
         self.mesh = mesh
         self.compress_bits = compress_bits
@@ -174,8 +158,7 @@ class CTRTrainer:
             if mesh is None:
                 raise ValueError("zero_sharded requires a mesh (it shards the "
                                  "update over the data axis)")
-            if param_shardings is not None or compress_bits is not None \
-                    or fused_adagrad:
+            if param_shardings is not None or compress_bits is not None:
                 raise ValueError(
                     "zero_sharded composes with replicated params and the "
                     "plain optax path only"
@@ -394,38 +377,6 @@ class CTRTrainer:
     def _make_step(self):
         grad_fn = self._make_grad_fn()
         tx = self.tx
-
-        if self.fused_adagrad:
-            from lightctr_tpu.optim.fused_adagrad import fused_adagrad_update
-
-            lr, eps = self.cfg.learning_rate, 1e-7
-
-            def step(params, opt_state, batch):
-                loss, probs, grads = grad_fn(params, batch)
-                health = self._append_sketch(
-                    _health_pack(loss, optax.global_norm(grads)),
-                    probs, batch)
-                leaves_w, treedef = jax.tree_util.tree_flatten(params)
-                leaves_a = treedef.flatten_up_to(opt_state.accum)
-                leaves_g = treedef.flatten_up_to(grads)
-                # the kernel registry picks the impl: compiled Mosaic on
-                # TPU, the jitted XLA twin elsewhere, the interpreter
-                # under LIGHTCTR_KERNELS=interpret
-                pairs = [
-                    fused_adagrad_update(w, a, g, lr, eps)
-                    for w, a, g in zip(leaves_w, leaves_a, leaves_g)
-                ]
-                params = jax.tree_util.tree_unflatten(
-                    treedef, [p for p, _ in pairs]
-                )
-                opt_state = optim_lib.AdagradState(
-                    accum=jax.tree_util.tree_unflatten(
-                        treedef, [a for _, a in pairs]
-                    )
-                )
-                return params, opt_state, loss, health
-
-            return step
 
         def step(params, opt_state, batch):
             loss, probs, grads = grad_fn(params, batch)

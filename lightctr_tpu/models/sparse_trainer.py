@@ -99,13 +99,11 @@ O(touched).  XLA's CPU backend does not honor donation — there each step
 still pays an O(vocab) table copy (measured: the step beats the dense
 trainer by the eliminated gradient+optimizer passes only).
 
-Kernel note (PR 9): the per-step sparse tax — id dedup, segment merge,
-row apply, payload pack — routes through the fused-kernel registry
-(:mod:`lightctr_tpu.ops.sparse_kernels`): Pallas kernels on TPU (the
-merge and the scaled Adagrad apply fuse into ONE pass over the gradient
-rows, so merged rows are never materialized), the identical pure-XLA
-reference twins everywhere else — the trajectory is the same on every
-path (see docs/KERNELS.md).
+Kernel note: the per-step sparse tax — id dedup, row gather, segment
+merge, row apply, payload pack — is one function each in
+:mod:`lightctr_tpu.ops.sparse_kernels`, the same implementation on every
+backend; only the payload packers have a Pallas form, taken on a TPU (see
+docs/KERNELS.md).
 """
 
 from __future__ import annotations
@@ -489,11 +487,9 @@ class SparseTableCTRTrainer(CTRTrainer):
         share the resulting ``(uids, inv)`` — their position rewrites
         coincide by construction (the __init__ overlap check guarantees
         no other sharing shape exists), so dedup FLOPs are paid per
-        distinct id stream, not per table.  The dedup itself rides the
-        kernel registry (``ops.sparse_kernels.dedup_ids``): on a TPU and
-        off it the XLA twin, three sorts with payloads and a scan under
-        the ``jnp.unique`` contract (the sort-free Pallas kernel does not
-        lower at this width and is deselected; docs/KERNELS.md)."""
+        distinct id stream, not per table.  The dedup itself is
+        ``ops.sparse_kernels.dedup_ids``: three sorts with payloads and a
+        scan under the ``jnp.unique`` contract (docs/KERNELS.md)."""
         from lightctr_tpu.ops import sparse_kernels
 
         tables = {k: params[k] for k in spec}
@@ -543,12 +539,12 @@ class SparseTableCTRTrainer(CTRTrainer):
         mesh, row_shards = self.mesh, self._row_shards()
 
         def apply(k, table, accum, u, g):
-            """Touched-row apply through the kernel registry: the XLA twin
-            is sparse_adagrad_update's arithmetic over the live prefix of
+            """Touched-row apply (``sparse_kernels.merge_apply``):
+            sparse_adagrad_update's arithmetic over the live prefix of
             uids (already sorted unique; padded id-0 repeats carry zero
-            gradient and are dropped), the Pallas variant one pass per
-            row.  Row-sharded tables apply per shard: each its own rows,
-            on its own rung, every ``data`` replica of a shard alike."""
+            gradient and are dropped).  Row-sharded tables apply per
+            shard: each its own rows, on its own rung, every ``data``
+            replica of a shard alike."""
             from lightctr_tpu.ops import sparse_kernels
 
             axis = row_shards.get(k)
@@ -1631,14 +1627,13 @@ class SparseTableCTRTrainer(CTRTrainer):
         no ``np.unique`` of its own.  A row-sharded table counts per
         shard (label ``shard``): each shard takes the rung that holds the
         distinct ids in its own row range, one ``searchsorted`` of the
-        shard bounds.  Only where the one-program step's XLA apply runs:
+        shard bounds.  Only where the one-program step's apply runs:
         the exchange steps apply the merged global ids, which the host
         never counts."""
         from lightctr_tpu.ops import sparse_kernels
 
         last, self._last_touch = self._last_touch, None
-        if (not last or self._hybrid_dp or self._hier
-                or sparse_kernels.resolve_impl("merge_apply") != "xla"):
+        if not last or self._hybrid_dp or self._hier:
             return
         touch, distinct = last
         reg = self.telemetry

@@ -108,7 +108,7 @@ def _flash_reference(
     q: jax.Array, k: jax.Array, v: jax.Array,
     causal: bool, block_q: int, block_k: int,
 ) -> jax.Array:
-    """The pure-XLA twin: the ``full_attention`` oracle the kernel is
+    """The XLA form: the ``full_attention`` oracle the kernel is
     tested against (blocks are pallas tuning knobs — unused here)."""
     from lightctr_tpu.nn.ring_attention import full_attention
 
@@ -125,9 +125,8 @@ def flash_attention(
     interpret: bool = False,
 ) -> jax.Array:
     """Registry-dispatched: compiled Mosaic on TPU, the exact
-    ``full_attention`` twin off-TPU (a flash call on CPU no longer
-    crashes), the interpreter under ``LIGHTCTR_KERNELS=interpret`` or an
-    explicit ``interpret=True``.  Block validation runs on every path so
+    ``full_attention`` off-TPU (a flash call on CPU no longer crashes),
+    the interpreter under an explicit ``interpret=True``.  Block validation runs on every path so
     caller bugs surface regardless of backend."""
     from lightctr_tpu.ops import sparse_kernels
 

@@ -32,8 +32,8 @@ from lightctr_tpu.obs.registry import MetricsRegistry, default_registry
 
 def _pad_slots(slots: np.ndarray, n: int) -> np.ndarray:
     """``slots[:n]`` in an int32 block padded to the next power of two
-    (the kernel layer's shared pad policy) so the pallas gather grid
-    count stays bounded."""
+    (the kernel layer's shared pad policy) so the gather's jit shapes
+    stay on a bounded ladder."""
     from lightctr_tpu.ops.sparse_kernels import next_pow2
 
     sp = np.zeros(next_pow2(n), np.int32)
@@ -56,7 +56,7 @@ class HotEmbeddingCache:
     TPU, host on CPU, ``LIGHTCTR_DEVICE_HOT`` overrides): resident rows
     live in ONE slot-recycled ``[capacity, dim]`` device block and a hit
     batch is ONE ``ops.sparse_kernels.gather_rows`` off it — the same
-    registry kernel (and on TPU the same HBM-resident row discipline) the
+    gather (and on TPU the same HBM-resident row discipline) the
     training store's device hot tier and the trainer fast path ride, so
     train and serve share one row path (docs/TIERED_STORE.md
     "Device-resident hot tier").  The admission/eviction/invalidation
@@ -170,9 +170,9 @@ class HotEmbeddingCache:
         return rows, present
 
     def _gather_locked(self, slots: np.ndarray) -> np.ndarray:
-        """One registry-kernel gather off the device block (device mode;
-        caller holds the lock).  The slot array is padded to a power of
-        two so the pallas grid count stays bounded."""
+        """One ``gather_rows`` off the device block (device mode; caller
+        holds the lock).  The slot array is padded to a power of two so
+        the jit shapes stay on a bounded ladder."""
         import jax.numpy as jnp
 
         from lightctr_tpu.ops import sparse_kernels

@@ -783,7 +783,7 @@ def test_metrics_report_tolerates_malformed_jsonl_lines(tmp_path):
 def test_pallas_call_sites_route_through_kernel_registry():
     """Every ``pallas_call`` site in the tree must belong to a module that
     registers its kernel(s) in the ``ops.sparse_kernels`` registry — a
-    direct call with no registered XLA reference twin would crash CPU
+    direct call with no registered XLA form would crash CPU
     tier-1 the moment the dispatcher cannot gate it.  Module-level calls
     (executed at import) are banned outright."""
     import importlib
@@ -818,26 +818,24 @@ def test_pallas_call_sites_route_through_kernel_registry():
                 if m not in registered_modules}
     assert not unrouted, (
         "pallas_call sites outside the kernel registry (register the "
-        f"kernel + its XLA reference twin in ops.sparse_kernels): {unrouted}"
+        f"kernel + its XLA form in ops.sparse_kernels): {unrouted}"
     )
 
 
-def test_every_registered_kernel_declares_reference_twin():
-    """Registry contract: both impls callable, the pallas twin accepts
+def test_every_registered_kernel_declares_its_xla_form():
+    """Registry contract: both impls callable, the pallas impl accepts
     ``interpret=`` (the CPU parity path), the phase is declared, and the
-    tentpole kernels are present."""
+    kernels with two implementations are present."""
     import inspect
 
     import lightctr_tpu.nn.flash_attention    # noqa: F401 (self-registers)
-    import lightctr_tpu.optim.fused_adagrad   # noqa: F401
     from lightctr_tpu.ops import sparse_kernels as sk
 
-    assert {"dedup_ids", "merge_rows", "merge_apply", "quantize_pack",
-            "quantize_pack_ef", "fused_adagrad",
-            "flash_attention"} <= set(sk.KERNELS)
+    assert {"quantize_pack", "quantize_pack_ef",
+            "flash_attention"} == set(sk.KERNELS)
     for name, kd in sk.KERNELS.items():
         assert kd.phase in sk.KERNEL_PHASES, name
-        assert callable(kd.reference), f"{name}: no XLA reference twin"
+        assert callable(kd.reference), f"{name}: no XLA form"
         assert callable(kd.pallas), f"{name}: no pallas impl"
         assert "interpret" in inspect.signature(kd.pallas).parameters, (
             f"{name}: pallas impl must accept interpret=")
@@ -853,17 +851,17 @@ def test_metrics_report_kernels_section(tmp_path, capsys, monkeypatch):
     reg = obs.MetricsRegistry()
     monkeypatch.setattr(obs, "default_registry", lambda: reg)
     monkeypatch.setattr(sk.obs, "default_registry", lambda: reg)
-    monkeypatch.setenv(sk.ENV_FLAG, "xla")
     import jax.numpy as jnp
-    sk.dedup_ids(jnp.arange(1, 9, dtype=jnp.int32))
-    sk.merge_rows(jnp.ones((4, 2)), jnp.zeros((4,), jnp.int32), 4)
+    from lightctr_tpu.ops import quantize
+    x = jnp.linspace(-1.0, 1.0, 8)
+    for bits in (8, 16):
+        sk.quantize_pack(quantize.build_table(-1.0, 1.0, bits=bits), x)
     path = tmp_path / "snap.json"
     path.write_text(json.dumps(reg.snapshot()))
     assert metrics_report.main(["--kernels", str(path)]) == 0
     report = json.loads(capsys.readouterr().out)
-    assert report["phases"]["dedup"] == {"xla": 1}
-    assert report["phases"]["merge"] == {"xla": 1}
-    assert report["dispatches_by_impl"]["xla"] == 2
+    assert report["phases"] == {"pack": {"xla": 2}}
+    assert report["dispatches_by_impl"] == {"xla": 2}
     assert report["fused_active"] is False
 
 

@@ -11,6 +11,8 @@ from lightctr_tpu import TrainConfig
 from lightctr_tpu.core.mesh import MeshSpec, make_mesh
 from lightctr_tpu.models import widedeep
 from lightctr_tpu.models.ctr_trainer import CTRTrainer
+# bound here: ``_rotated_dedup`` is patched over ``sparse_kernels.dedup_ids``
+from lightctr_tpu.ops.sparse_kernels import dedup_ids as _dedup_ids
 
 
 def test_embed_sharded_widedeep_matches_replicated(rng):
@@ -110,9 +112,7 @@ def _rotated_dedup(ids, size=None):
     """``dedup_ids`` with the live prefix rotated by half its length: a
     valid dedup-convention pair for ids >= 1, but not ascending — what an
     exchange that hands per-owner segments gives."""
-    from lightctr_tpu.ops import sparse_kernels as sk
-
-    u, inv, count = sk.KERNELS["dedup_ids"].reference(ids, size or ids.shape[0])
+    u, inv, count = _dedup_ids(ids, size)
     slot = jnp.arange(u.shape[0])
     half = count // 2
     rot = jnp.where(slot < count, jnp.take(u, (slot + half) % count), 0)

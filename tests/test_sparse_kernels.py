@@ -1,9 +1,10 @@
-"""Fused sparse-hot-path kernels (ISSUE 9): registry dispatch and
-capability gates, kernel-vs-reference parity in interpret mode on CPU
-(dedup/merge bit-exact, apply within FMA-contraction ulp, quantize pack
-bit-identical to the existing codec), property tests over duplicate-heavy
-and empty id streams, and trajectory parity through
-``SparseTableCTRTrainer.fit``."""
+"""The sparse hot path (``ops.sparse_kernels``): each phase against an
+oracle that is not the code under test — the dedup against ``jnp.unique``
+over duplicate-heavy, degenerate and empty id streams, the merge against
+a numpy loop, the sized apply against ``sparse_adagrad_update`` within
+FMA-contraction ulp, the EF pack against the written-out codec chain —
+and, for the kernels with two implementations, the Pallas form in
+interpret mode bit-identical to the codec, and the registry's dispatch."""
 
 from functools import partial
 
@@ -16,14 +17,6 @@ from lightctr_tpu.ops import quantize
 from lightctr_tpu.ops import sparse_kernels as sk
 
 
-def _dedup_both(ids, size=None):
-    ids = jnp.asarray(ids).reshape(-1)
-    s = ids.shape[0] if size is None else size
-    ref = sk.KERNELS["dedup_ids"].reference(ids, s)
-    got = sk.KERNELS["dedup_ids"].pallas(ids, s, interpret=True)
-    return ref, got
-
-
 def _assert_dedup_equal(ref, got):
     for a, b, what in zip(ref, got, ("uids", "inv", "count")):
         assert a.dtype == b.dtype and a.shape == b.shape, what
@@ -32,8 +25,8 @@ def _assert_dedup_equal(ref, got):
 
 
 def _unique_oracle(ids, size):
-    """The literal call ``_dedup_reference`` was until PR 29, kept here as
-    the oracle the three-sort body is held to, output for output."""
+    """The literal call ``dedup_ids`` was until PR 29, kept here as the
+    oracle the three-sort body is held to, output for output."""
     ids = jnp.asarray(ids).reshape(-1)
     u, inv = jnp.unique(ids, return_inverse=True, size=size, fill_value=0)
     inv = inv.reshape(-1).astype(jnp.int32)
@@ -44,54 +37,12 @@ def _unique_oracle(ids, size):
 # -- (a) dedup: exact jnp.unique contract --------------------------------
 
 
-def test_dedup_matches_unique_random(rng):
-    ids = rng.integers(0, 500, size=777).astype(np.int32)
-    ref, got = _dedup_both(ids)
-    _assert_dedup_equal(ref, got)
-    # and against jnp.unique directly (the reference WAS that call)
-    _assert_dedup_equal(_unique_oracle(ids, 777), ref)
-
-
-def test_dedup_duplicate_heavy_and_degenerate_streams(rng):
-    """The property sweep the ISSUE asks for: duplicate-heavy (few
-    distinct values, id 0 present and absent), all-identical, single
-    element, and all-padding (all-zero) streams — interpret == reference
-    bitwise on every one."""
-    cases = [
-        rng.choice([0, 1, 7], size=300).astype(np.int32),     # heavy + id 0
-        rng.choice([3, 9], size=256).astype(np.int32),        # heavy, no 0
-        np.full(64, 5, np.int32),                             # all identical
-        np.zeros(32, np.int32),                               # all padding
-        np.array([42], np.int32),                             # single
-        np.arange(1, 97, dtype=np.int32)[::-1].copy(),        # all distinct
-    ]
-    for i, ids in enumerate(cases):
-        ref, got = _dedup_both(ids)
-        _assert_dedup_equal(ref, got)
-    for seed in range(4):
-        r = np.random.default_rng(seed)
-        ids = r.integers(0, 8, size=int(r.integers(9, 200))).astype(np.int32)
-        ref, got = _dedup_both(ids)
-        _assert_dedup_equal(ref, got)
-
-
 def test_dedup_empty_stream():
-    """K=0 never reaches a kernel: the dispatcher's early return keeps
-    the contract shapes (size-padded uids, empty inverse, zero count)."""
+    """K=0 sorts nothing: the early return keeps the contract shapes
+    (size-padded uids, empty inverse, zero count)."""
     u, inv, c = sk.dedup_ids(jnp.zeros((0,), jnp.int32), size=4)
     assert u.shape == (4,) and inv.shape == (0,) and int(c) == 0
     assert not np.asarray(u).any()
-
-
-def test_dedup_truncation_keeps_full_ranks(rng):
-    """size < distinct count: the unique array truncates but the inverse
-    keeps FULL ranks (the jnp.unique behavior the rs shard merge's
-    overflow accounting rides on) and count reports the true total."""
-    ids = rng.permutation(np.arange(1, 51)).astype(np.int32)
-    ref, got = _dedup_both(ids, size=10)
-    _assert_dedup_equal(ref, got)
-    assert int(ref[2]) == 50
-    assert int(np.asarray(ref[1]).max()) == 49  # ranks beyond the cut
 
 
 def _power_law_ids(k, vocab, seed):
@@ -105,9 +56,35 @@ def _power_law_ids(k, vocab, seed):
 
 _INT32_MAX = np.iinfo(np.int32).max
 
-#: name -> (ids, size, x64): the streams the XLA twin is held to
+
+def _property_streams():
+    """The property sweep: a random stream, duplicate-heavy ones (few
+    distinct values, id 0 present and absent), all-identical, all-padding
+    (all-zero) and all-distinct descending."""
+    r = np.random.default_rng(0)
+    return {
+        "random_777": np.random.default_rng(0).integers(
+            0, 500, size=777).astype(np.int32),
+        "heavy_id0": r.choice([0, 1, 7], size=300).astype(np.int32),
+        "heavy_no_id0": r.choice([3, 9], size=256).astype(np.int32),
+        "identical_64": np.full(64, 5, np.int32),
+        "all_zero_32": np.zeros(32, np.int32),
+        "reversed_distinct_96": np.arange(1, 97, dtype=np.int32)[::-1].copy(),
+    }
+
+
+def _seeded_stream(seed):
+    r = np.random.default_rng(seed)
+    return r.integers(0, 8, size=int(r.integers(9, 200))).astype(np.int32)
+
+
+#: name -> (ids, size, x64): the streams the dedup is held to
 #: ``jnp.unique`` on, bit for bit
 _ORACLE_CASES = {
+    **{name: (ids, None, False)
+       for name, ids in _property_streams().items()},
+    **{f"few_values_seed{seed}": (_seeded_stream(seed), None, False)
+       for seed in range(4)},
     "all_distinct": (np.random.default_rng(1).permutation(
         np.arange(1, 1025)).astype(np.int32), None, False),
     "all_equal": (np.full(300, 7, np.int32), None, False),
@@ -136,21 +113,22 @@ _ORACLE_CASES = {
 
 @pytest.mark.parametrize("jitted", [False, True], ids=["eager", "jit"])
 @pytest.mark.parametrize("case", sorted(_ORACLE_CASES))
-def test_dedup_xla_twin_is_jnp_unique_bit_for_bit(case, jitted, monkeypatch):
-    """The three-sort body against the literal ``jnp.unique`` call, through
-    the dispatcher (so K = 0 takes its early return): values, dtypes and
-    shapes of uids, the full-rank inverse and the count."""
+def test_dedup_is_jnp_unique_bit_for_bit(case, jitted):
+    """The three-sort body against the literal ``jnp.unique`` call (K = 0
+    takes the early return): values, dtypes and shapes of uids, the
+    full-rank inverse — ranks beyond the cut where ``size`` truncates —
+    and the count, which is the true total whatever ``size`` is."""
     ids, size, x64 = _ORACLE_CASES[case]
-    monkeypatch.setenv(sk.ENV_FLAG, "xla")
     size = ids.shape[0] if size is None else size
     with jax.enable_x64(x64):
         dedup = partial(sk.dedup_ids, size=size)
         got = (jax.jit(dedup) if jitted else dedup)(jnp.asarray(ids))
         assert got[0].dtype == ids.dtype     # an int64 stream keeps its width
         _assert_dedup_equal(_unique_oracle(ids, size), got)
+        assert int(got[2]) == np.unique(ids).size
 
 
-def test_dedup_xla_twin_compiles_to_three_sorts_and_nothing_k_sized_else():
+def test_dedup_compiles_to_three_sorts_and_nothing_k_sized_else():
     """What PR 29 bought, guarded without a chip: at K = 8,192 (the ladder
     live) the jitted dedup's optimized HLO holds three sorts and no
     gather and no scatter — ``jnp.unique`` compiles here to one sort, two
@@ -164,26 +142,30 @@ def test_dedup_xla_twin_compiles_to_three_sorts_and_nothing_k_sized_else():
         return {op: text.count(f" {op}(")
                 for op in ("sort", "gather", "scatter")}
 
-    new = hlo(lambda ids: sk.KERNELS["dedup_ids"].reference(ids, 8192))
+    new = hlo(lambda ids: sk.dedup_ids(ids, 8192))
     assert count(new) == {"sort": 3, "gather": 0, "scatter": 0}
     # the counter sees what it is there to see: the old body's passes
     old = hlo(lambda ids: _unique_oracle(ids, 8192))
     assert count(old)["gather"] > 0 and count(old)["scatter"] > 0
 
 
-# -- (b) merge + fused merge-apply ---------------------------------------
+# -- (b) merge + merge-apply ----------------------------------------------
 
 
 def test_merge_rows_bit_exact(rng):
+    """``merge_rows`` against a numpy loop adding each row to its segment
+    in slot order (the order ``segment_sum`` applies); out-of-range
+    segments — truncated ranks — are dropped."""
     m, s, d = 333, 40, 6
     inv = rng.integers(0, s + 5, size=m).astype(np.int32)  # incl. dropped
     rows = rng.normal(size=(m, d)).astype(np.float32)
-    ref = sk.KERNELS["merge_rows"].reference(jnp.asarray(rows),
-                                             jnp.asarray(inv), s)
-    got = sk.KERNELS["merge_rows"].pallas(jnp.asarray(rows),
-                                          jnp.asarray(inv), s,
-                                          interpret=True)
-    np.testing.assert_array_equal(np.asarray(ref), np.asarray(got))
+    want = np.zeros((s, d), np.float32)
+    for seg, row in zip(inv, rows):
+        if seg < s:
+            want[seg] += row
+    got = sk.merge_rows(jnp.asarray(rows), jnp.asarray(inv), s)
+    assert got.dtype == jnp.float32
+    np.testing.assert_array_equal(np.asarray(got), want)
 
 
 def _convention_uids(rng, s, vocab, with_zero=False):
@@ -191,96 +173,47 @@ def _convention_uids(rng, s, vocab, with_zero=False):
     u = np.unique(rng.integers(lo, vocab, size=s))
     uids = np.zeros(s, np.int64)
     uids[: u.size] = u
-    return jnp.asarray(uids), u.size
+    return uids, u.size
 
 
-def test_merge_apply_parity(rng):
-    """Fused merge+scaled-apply vs the reference chain (segment_sum ->
-    /denom -> sparse_adagrad_update): table/accum agree to the last
-    FMA-contraction ulp (XLA fuses ``accum + g*g`` into an fma on CPU;
-    the interpreter's separate mul/add differ by <= 1 ulp — see
-    docs/KERNELS.md), merged sum-of-squares to float tolerance."""
+def _small_merged_payload(rng):
+    """A merged payload (``inv``) with ``denom=4.0``, id 0 allowed."""
     m, s, vocab, d = 160, 40, 64, 5
     uids, nu = _convention_uids(rng, s, vocab, with_zero=True)
     inv = rng.integers(0, nu, size=m).astype(np.int32)
     rows = rng.normal(size=(m, d)).astype(np.float32)
     table = rng.normal(size=(vocab, d)).astype(np.float32)
     accum = np.abs(rng.normal(size=(vocab, d))).astype(np.float32)
-    args = (jnp.asarray(table), jnp.asarray(accum), uids,
-            jnp.asarray(rows), jnp.asarray(inv))
-    w0, a0, s0 = sk.KERNELS["merge_apply"].reference(
-        *args, lr=0.1, eps=1e-7, denom=4.0)
-    w1, a1, s1 = sk.KERNELS["merge_apply"].pallas(
-        *args, lr=0.1, eps=1e-7, denom=4.0, interpret=True)
-    np.testing.assert_allclose(np.asarray(w1), np.asarray(w0),
-                               rtol=0, atol=2e-7)
-    np.testing.assert_allclose(np.asarray(a1), np.asarray(a0),
-                               rtol=2e-6, atol=0)
-    np.testing.assert_allclose(float(s1), float(s0), rtol=1e-5)
-    # untouched rows MUST be bit-identical (neither impl may write them)
-    untouched = np.setdiff1d(np.arange(vocab), np.asarray(uids))
-    np.testing.assert_array_equal(np.asarray(w1)[untouched],
-                                  table[untouched])
-    np.testing.assert_array_equal(np.asarray(a1)[untouched],
-                                  accum[untouched])
+    return (table, accum, uids, rows, inv), dict(lr=0.1, denom=4.0)
 
 
-def test_merge_apply_apply_only_and_1d_table(rng):
+def _small_apply_only_1d(rng):
     """inv=None (the rs path: rows arrive merged) on a 1-D table (the FM
-    w leaf) — padded id-0 slots are exact no-ops in both impls."""
+    w leaf) — padded id-0 slots are exact no-ops."""
     s, vocab = 24, 48
     uids, nu = _convention_uids(rng, s, vocab)
     rows = rng.normal(size=(s,)).astype(np.float32)
     rows[nu:] = 0.0
     table = rng.normal(size=(vocab,)).astype(np.float32)
     accum = np.abs(rng.normal(size=(vocab,))).astype(np.float32)
-    args = (jnp.asarray(table), jnp.asarray(accum), uids, jnp.asarray(rows),
-            None)
-    w0, a0, s0 = sk.KERNELS["merge_apply"].reference(
-        *args, lr=0.05, eps=1e-7, denom=1.0)
-    w1, a1, s1 = sk.KERNELS["merge_apply"].pallas(
-        *args, lr=0.05, eps=1e-7, denom=1.0, interpret=True)
-    np.testing.assert_allclose(np.asarray(w1), np.asarray(w0),
-                               rtol=0, atol=2e-7)
-    np.testing.assert_allclose(np.asarray(a1), np.asarray(a0),
-                               rtol=2e-6, atol=0)
-    np.testing.assert_allclose(float(s1), float(s0), rtol=1e-5)
-    np.testing.assert_array_equal(np.asarray(w1)[0], table[0])  # pad row
+    return (table, accum, uids, rows, None), dict(lr=0.05, denom=1.0)
 
 
-def test_merge_apply_block_tail_pads_and_real_id0(rng):
-    """The apply kernel batches ``DMA_ROWS`` rows per grid step: a size
-    that does NOT divide the block (the round-up tail must be skipped,
-    not applied), dedup pads (skipped — row 0 is written once), and a
-    REAL id 0 at slot 0 all agree with the reference to the documented
-    FMA ulp."""
+def _small_pads_and_real_id0(rng):
+    """An odd slot count with dedup pads behind the live ids (row 0 is
+    written once) and a REAL id 0 at slot 0."""
     s, vocab, d = 11, 32, 3
-    assert s % sk.DMA_ROWS
-    uids_np = np.zeros(s, np.int64)
+    uids = np.zeros(s, np.int64)
     u = np.unique(rng.integers(1, vocab, size=s - 2))
-    uids_np[1:1 + u.size] = u  # slot 0 stays id 0 — REAL here
+    uids[1:1 + u.size] = u  # slot 0 stays id 0 — REAL here
     rows = rng.normal(size=(s, d)).astype(np.float32)
     rows[1 + u.size:] = 0.0  # pads carry zero rows
     table = rng.normal(size=(vocab, d)).astype(np.float32)
     accum = np.abs(rng.normal(size=(vocab, d))).astype(np.float32)
-    args = (jnp.asarray(table), jnp.asarray(accum), jnp.asarray(uids_np),
-            jnp.asarray(rows), None)
-    w0, a0, s0 = sk.KERNELS["merge_apply"].reference(
-        *args, lr=0.1, eps=1e-7, denom=2.0)
-    w1, a1, s1 = sk.KERNELS["merge_apply"].pallas(
-        *args, lr=0.1, eps=1e-7, denom=2.0, interpret=True)
-    np.testing.assert_allclose(np.asarray(w1), np.asarray(w0),
-                               rtol=0, atol=2e-7)
-    np.testing.assert_allclose(np.asarray(a1), np.asarray(a0),
-                               rtol=2e-6, atol=0)
-    np.testing.assert_allclose(float(s1), float(s0), rtol=1e-5)
-    assert np.asarray(w1)[0].tolist() != table[0].tolist()  # id 0 trained
-    untouched = np.setdiff1d(np.arange(vocab), uids_np)
-    np.testing.assert_array_equal(np.asarray(w1)[untouched],
-                                  table[untouched])
+    return (table, accum, uids, rows, None), dict(lr=0.1, denom=2.0)
 
 
-# -- (b') the sized XLA apply: live prefix, ladder, observed order ---------
+# -- (b') the sized apply: live prefix, ladder, observed order -------------
 
 #: the smallest K with a real ladder, and its rungs
 _LK = sk.LADDER_MIN_SLOTS
@@ -368,37 +301,53 @@ def _sized_apply_cases():
     ]:
         cases.append((f"shards_{name}", dict(
             count=sum(per), per_shard=per, shards=True, **kw)))
+    # K under LADDER_MIN_SLOTS — the one-rung ladder: the tiered store's
+    # and the exchange steps' sizes
+    cases += [
+        ("small_merged_payload", dict(small=_small_merged_payload)),
+        ("small_apply_only_1d", dict(small=_small_apply_only_1d)),
+        ("small_pads_and_real_id0", dict(small=_small_pads_and_real_id0)),
+    ]
     return [pytest.param(kw, id=name) for name, kw in cases]
 
 
 @pytest.mark.parametrize("case", _sized_apply_cases())
 def test_sized_apply_matches_sparse_adagrad_update(case):
-    """The XLA apply — live prefix, one rung of the ladder, no second
-    dedup — against ``embed.table.sparse_adagrad_update`` (the chain it
-    replaced: ``jnp.unique`` + ``segment_sum`` + K-slot scatter-adds) on
-    the same inputs, to ``test_merge_apply_parity``'s tolerances: at one
-    live slot, at every rung's edge and one past it, with a real id 0,
-    for ``w[V]`` and ``[V, d]``, with ``inv``, with interleaved pads
-    (which must take the undeclared branch), and with the table sharded
-    over ``embed`` on four devices."""
+    """The apply — live prefix, one rung of the ladder, no second dedup —
+    against ``embed.table.sparse_adagrad_update`` (the chain it replaced:
+    ``jnp.unique`` + ``segment_sum`` + K-slot scatter-adds) on the same
+    inputs, to the last FMA-contraction ulp (XLA fuses ``accum + g*g``
+    into an fma on CPU; docs/KERNELS.md): at one live slot, at every
+    rung's edge and one past it, with a real id 0, for ``w[V]`` and ``[V,
+    d]``, with ``inv``, with interleaved pads (which must take the
+    undeclared branch), with the table sharded over ``embed`` on four
+    devices, and under the ladder's floor where there is one rung."""
     from lightctr_tpu.embed.table import SparseAdagradState, \
         sparse_adagrad_update
 
     kw = dict(case)
     mesh = kw.pop("mesh", False)
     shards = len(kw["per_shard"]) if kw.pop("shards", False) else 0
-    count = kw["count"]
-    rng = np.random.default_rng(count)
-    table, accum, uids, rows, inv = _live_case(rng, **kw)
     lr, eps, denom = 0.1, 1e-7, 2.0
+    if "small" in kw:
+        (table, accum, uids, rows, inv), over = kw["small"](
+            np.random.default_rng(0))
+        lr, denom = over["lr"], over["denom"]
+        count = int(((uids != 0) | (np.arange(uids.size) == 0)).sum())
+    else:
+        count = kw["count"]
+        table, accum, uids, rows, inv = _live_case(
+            np.random.default_rng(count), **kw)
+    k = uids.shape[0]
+    rungs = sk.apply_ladder(k)
 
-    # the chain the apply replaced, fed what merge_apply's dispatch hands
-    # its implementations (pad slots zeroed for inv=None payloads)
+    # the chain the apply replaced (pad slots zeroed for inv=None
+    # payloads, as merge_apply's contract has them)
     if inv is not None:
         merged = jax.ops.segment_sum(jnp.asarray(rows), jnp.asarray(inv),
-                                     num_segments=_LK)
+                                     num_segments=k)
     else:
-        pad = (uids == 0) & (np.arange(_LK) > 0)
+        pad = (uids == 0) & (np.arange(k) > 0)
         merged = jnp.asarray(rows * (~pad).reshape((-1,) + (1,) * (rows.ndim - 1)))
     w0, st = sparse_adagrad_update(
         jnp.asarray(table), SparseAdagradState(accum=jnp.asarray(accum)),
@@ -441,10 +390,12 @@ def test_sized_apply_matches_sparse_adagrad_update(case):
     untouched = np.setdiff1d(np.arange(table.shape[0]), uids)
     np.testing.assert_array_equal(np.asarray(w1)[untouched], table[untouched])
     np.testing.assert_array_equal(np.asarray(a1)[untouched], accum[untouched])
-    if not kw.get("with_zero"):
+    if uids[0] != 0:
         # id 0 was never live: neither a pad nor noise in a pad may move it
         np.testing.assert_array_equal(np.asarray(w1)[0], table[0])
         np.testing.assert_array_equal(np.asarray(a1)[0], accum[0])
+    elif np.asarray(merged)[0].any():
+        assert np.asarray(w1)[0].tolist() != table[0].tolist()  # id 0 trained
 
     # the rung the device takes is the one the host names from the count
     # — the whole table's, or each shard's from its own rows
@@ -453,13 +404,13 @@ def test_sized_apply_matches_sparse_adagrad_update(case):
         _, branch, start, live = sk.shard_plan(
             jnp.asarray(uids), v, e * v if shards else None)
         if kw.get("interleave"):
-            assert int(branch) == len(_RUNGS)       # observed unsorted
+            assert int(branch) == len(rungs)        # observed unsorted
             assert int(start) == 0
         else:
             assert int(live) == n
-            assert _RUNGS[int(branch)] == sk.ladder_slots(_LK, n)
-            assert n <= _RUNGS[int(branch)]
-            assert int(branch) == 0 or _RUNGS[int(branch) - 1] < n
+            assert rungs[int(branch)] == sk.ladder_slots(k, n)
+            assert n <= rungs[int(branch)]
+            assert int(branch) == 0 or rungs[int(branch) - 1] < n
 
 
 def test_apply_ladder_is_a_function_of_k_alone():
@@ -681,12 +632,13 @@ def test_quantize_pack_bit_identical_to_codec(rng):
 
 
 def test_quantize_pack_wide_tables_take_the_reference(monkeypatch):
-    """Codes wider than 8 bits keep the XLA twin whatever the mode (a
-    static rule on the table, counted as ``xla``): the compare-count
-    sweep would pay 2^bits compares per element."""
+    """Codes wider than 8 bits keep the XLA form on a TPU too (a static
+    rule on the table, counted as ``xla``): the compare-count sweep would
+    pay 2^bits compares per element."""
     from lightctr_tpu import obs
 
-    monkeypatch.setenv(sk.ENV_FLAG, "interpret")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert sk.resolve_impl("quantize_pack") == "pallas"
     reg = obs.default_registry()
     key = obs.labeled("trainer_kernel_path_total", phase="pack", impl="xla")
     before = reg.snapshot()["counters"].get(key, 0)
@@ -700,11 +652,12 @@ def test_quantize_pack_wide_tables_take_the_reference(monkeypatch):
 
 
 def test_quantize_pack_ef_update_folds_the_residual_scatter(rng):
-    """The folded EF pack (PR 9 follow-up): codes AND the written-back
-    residual are bit-identical to the reference gather / compensate /
-    encode / decode / scatter chain — including a real id 0 at slot 0,
-    padded repeats that must leave their carry untouched, and untouched
-    rows that must keep theirs."""
+    """The EF pack with the carry update: codes, decoded view AND the
+    written-back residual are bit-identical to the chain written out
+    here — gather / compensate / ``quantize.compress`` / ``extract`` /
+    scatter — including a real id 0 at slot 0, padded repeats that must
+    leave their carry untouched, and untouched rows that must keep
+    theirs."""
     t = quantize.build_table(-1.0, 1.0, bits=8)
     vocab, dim, s = 96, 5, 24
     u = np.unique(rng.integers(1, vocab, 17)).astype(np.int32)
@@ -726,14 +679,18 @@ def test_quantize_pack_ef_update_folds_the_residual_scatter(rng):
             uu, rr = uids, rows
         mask = (~((uu == 0) & (np.arange(s) > 0))).astype(
             np.float32).reshape(-1, 1)
-        args = (t, jnp.asarray(rr), jnp.asarray(uu),
-                jnp.asarray(residual), jnp.asarray(mask))
-        c0, r0, d0 = sk.KERNELS["quantize_pack_ef_update"].reference(*args)
-        c1, r1, d1 = sk.KERNELS["quantize_pack_ef_update"].pallas(
-            *args, interpret=True)
+        carried = residual[uu]
+        val = rr + carried * mask
+        c0 = quantize.compress(t, jnp.asarray(val))
+        d0 = np.asarray(quantize.extract(t, c0))
+        r0 = residual.copy()
+        np.add.at(r0, uu, (val - d0 - carried) * mask)
+        c1, r1, d1 = sk.quantize_pack_ef_update(
+            t, jnp.asarray(rr), jnp.asarray(uu), jnp.asarray(residual),
+            jnp.asarray(mask))
         np.testing.assert_array_equal(np.asarray(c1), np.asarray(c0))
-        np.testing.assert_array_equal(np.asarray(r1), np.asarray(r0))
-        np.testing.assert_array_equal(np.asarray(d1), np.asarray(d0))
+        np.testing.assert_array_equal(np.asarray(r1), r0)
+        np.testing.assert_array_equal(np.asarray(d1), d0)
         untouched = np.setdiff1d(np.arange(vocab), uu)
         np.testing.assert_array_equal(np.asarray(r1)[untouched],
                                       residual[untouched])
@@ -801,65 +758,31 @@ def test_pack_nibbles_round_trip_orders(rng):
         np.array([1, 15, 0, 7, 9], np.uint8))
 
 
-# -- dispatcher: capability gates, env flag, telemetry -------------------
+# -- the registry: the kernels with two implementations --------------------
 
 
-def test_resolve_impl_env_gates(monkeypatch):
-    monkeypatch.setenv(sk.ENV_FLAG, "xla")
-    assert sk.resolve_impl("dedup_ids") == "xla"
-    monkeypatch.setenv(sk.ENV_FLAG, "interpret")
-    assert sk.resolve_impl("dedup_ids") == "interpret"
-    monkeypatch.setenv(sk.ENV_FLAG, "pallas")
-    assert sk.resolve_impl("dedup_ids") == "pallas"
-    monkeypatch.delenv(sk.ENV_FLAG, raising=False)
-    # auto: pallas only on TPU — this suite runs on the virtual CPU mesh
-    assert sk.resolve_impl("dedup_ids") == "xla"
-    with pytest.raises(KeyError):
-        sk.resolve_impl("no_such_kernel")
-
-
-def test_auto_on_tpu_skips_deselected_kernels(monkeypatch):
-    """On a TPU ``auto`` compiles every kernel EXCEPT the ones the
-    registry deselects by name (with the compiler's reason); forcing
-    ``pallas`` still reaches them."""
-    import lightctr_tpu.nn.flash_attention    # noqa: F401 (self-registers)
-    import lightctr_tpu.optim.fused_adagrad   # noqa: F401
-
-    monkeypatch.delenv(sk.ENV_FLAG, raising=False)
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    picks = {name: sk.resolve_impl(name) for name in sk.KERNELS}
-    for name, kd in sk.KERNELS.items():
-        assert picks[name] == ("xla" if kd.deselected else "pallas"), name
-        assert kd.deselected is None or len(kd.deselected) > 20
-    assert picks["dedup_ids"] == picks["merge_apply"] == "xla"
-    assert picks["quantize_pack"] == picks["flash_attention"] == "pallas"
-    monkeypatch.setenv(sk.ENV_FLAG, "pallas")
-    assert sk.resolve_impl("dedup_ids") == "pallas"
-
-
-def test_dispatch_counts_kernel_path(monkeypatch, rng):
+def test_dispatch_counts_kernel_path(rng):
     from lightctr_tpu import obs
 
-    monkeypatch.setenv(sk.ENV_FLAG, "xla")
     reg = obs.default_registry()
-    key = obs.labeled("trainer_kernel_path_total", phase="dedup", impl="xla")
+    key = obs.labeled("trainer_kernel_path_total", phase="pack", impl="xla")
     before = reg.snapshot()["counters"].get(key, 0)
-    sk.dedup_ids(jnp.asarray(rng.integers(0, 9, size=16).astype(np.int32)))
+    sk.quantize_pack(quantize.build_table(-1.0, 1.0, bits=8),
+                     jnp.asarray(rng.normal(size=16).astype(np.float32)))
     after = reg.snapshot()["counters"].get(key, 0)
     assert after == before + 1
 
 
-def test_registry_contract():
+def test_registry_contract(monkeypatch):
     """Every registered kernel declares BOTH impls, a known phase, and a
-    pallas twin that accepts interpret= (the CPU parity path)."""
+    pallas impl that accepts interpret= (the CPU parity path); the pick
+    is the backend's: the XLA form here, Pallas on a TPU."""
     import inspect
 
     import lightctr_tpu.nn.flash_attention    # noqa: F401 (self-registers)
-    import lightctr_tpu.optim.fused_adagrad   # noqa: F401
 
-    assert {"dedup_ids", "merge_rows", "merge_apply", "quantize_pack",
-            "quantize_pack_ef", "fused_adagrad",
-            "flash_attention"} <= set(sk.KERNELS)
+    assert set(sk.KERNELS) == {"quantize_pack", "quantize_pack_ef",
+                               "flash_attention"}
     for name, kd in sk.KERNELS.items():
         assert kd.phase in sk.KERNEL_PHASES, name
         assert callable(kd.reference) and callable(kd.pallas), name
@@ -867,90 +790,8 @@ def test_registry_contract():
         assert "interpret" in sig.parameters, (
             f"{name}: pallas impl must accept interpret= for the CPU "
             "parity path")
-
-
-# -- trajectory: interpret-mode trainer == reference trainer -------------
-
-
-def _fm_batch(rng, n=96, f=512, nnz=5):
-    return {
-        "fids": rng.integers(1, f, size=(n, nnz)).astype(np.int32),
-        "fields": np.zeros((n, nnz), np.int32),
-        "vals": np.ones((n, nnz), np.float32),
-        "mask": np.ones((n, nnz), np.float32),
-        "labels": (rng.random(n) > 0.5).astype(np.float32),
-    }
-
-
-def test_trainer_fit_trajectory_interpret_vs_reference(rng, monkeypatch):
-    """The acceptance gate: SparseTableCTRTrainer.fit driven through the
-    interpret-mode fused kernels tracks the reference-path trainer —
-    same losses, same touched rows — to FMA-contraction tolerance over a
-    multi-epoch fit."""
-    from lightctr_tpu import TrainConfig
-    from lightctr_tpu.models import fm
-    from lightctr_tpu.models.sparse_trainer import SparseTableCTRTrainer
-
-    f = 512
-    batch = _fm_batch(rng, f=f)
-    params = fm.init(jax.random.PRNGKey(0), f, 8)
-    cfg = TrainConfig(learning_rate=0.1)
-
-    def run():
-        tr = SparseTableCTRTrainer(
-            params, fm.logits, cfg,
-            sparse_tables={"w": ["fids"], "v": ["fids"]},
-        )
-        tr.health = None
-        hist = tr.fit(batch, epochs=6)
-        return hist["loss"], tr.params
-
-    monkeypatch.setenv(sk.ENV_FLAG, "xla")
-    l_ref, p_ref = run()
-    monkeypatch.setenv(sk.ENV_FLAG, "interpret")
-    l_int, p_int = run()
-    np.testing.assert_allclose(l_int, l_ref, rtol=2e-6, atol=1e-7)
-    for key in ("w", "v"):
-        np.testing.assert_allclose(
-            np.asarray(p_int[key]), np.asarray(p_ref[key]),
-            rtol=2e-5, atol=2e-6,
-        )
-
-
-def test_hybrid_trainer_step_interpret_matches_reference(rng, monkeypatch):
-    """The hybrid data-parallel step (allgather sparse exchange + fused
-    merge-apply inside shard_map) under interpret-mode kernels matches
-    the reference program's step on an 8-way mesh."""
-    from lightctr_tpu import TrainConfig
-    from lightctr_tpu.core.mesh import MeshSpec, make_mesh
-    from lightctr_tpu.models import fm
-    from lightctr_tpu.models.sparse_trainer import SparseTableCTRTrainer
-
-    f = 1 << 14
-    batch = _fm_batch(rng, n=256, f=f, nnz=4)
-    params = fm.init(jax.random.PRNGKey(1), f, 8)
-    cfg = TrainConfig(learning_rate=0.1)
-    mesh = make_mesh(MeshSpec(data=8))
-
-    def run():
-        tr = SparseTableCTRTrainer(
-            params, fm.logits, cfg,
-            sparse_tables={"w": ["fids"], "v": ["fids"]}, mesh=mesh,
-        )
-        tr.health = None
-        for _ in range(2):
-            loss = tr.train_step(batch)
-        return float(loss), tr.params, dict(tr.exchange_policy)
-
-    monkeypatch.setenv(sk.ENV_FLAG, "xla")
-    l_ref, p_ref, pol_ref = run()
-    monkeypatch.setenv(sk.ENV_FLAG, "interpret")
-    l_int, p_int, pol_int = run()
-    assert pol_ref == pol_int
-    assert pol_ref["v"] == "sparse", pol_ref   # the allgather regime
-    np.testing.assert_allclose(l_int, l_ref, rtol=2e-6, atol=1e-7)
-    for key in ("w", "v"):
-        np.testing.assert_allclose(
-            np.asarray(p_int[key]), np.asarray(p_ref[key]),
-            rtol=2e-5, atol=2e-6,
-        )
+        assert sk.resolve_impl(name) == "xla"    # the virtual CPU mesh
+    with pytest.raises(KeyError):
+        sk.resolve_impl("dedup_ids")             # one implementation: no pick
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert {sk.resolve_impl(name) for name in sk.KERNELS} == {"pallas"}

@@ -15,20 +15,6 @@ import numpy as np
 import pytest
 
 
-def test_fused_adagrad_compiled_exact():
-    from lightctr_tpu.optim.fused_adagrad import fused_adagrad_update
-
-    n = 1 << 18
-    w = jax.random.normal(jax.random.PRNGKey(0), (n,), jnp.float32)
-    a = jnp.abs(jax.random.normal(jax.random.PRNGKey(1), (n,), jnp.float32))
-    g = jax.random.normal(jax.random.PRNGKey(2), (n,), jnp.float32)
-    want_w = np.asarray(w - 0.1 * g * jax.lax.rsqrt(a + g * g + 1e-7))
-    want_a = np.asarray(a + g * g)
-    got_w, got_a = fused_adagrad_update(w, a, g, 0.1)  # donates w, a
-    np.testing.assert_array_equal(np.asarray(got_w), want_w)
-    np.testing.assert_array_equal(np.asarray(got_a), want_a)
-
-
 @pytest.mark.parametrize("causal", [False, True])
 def test_flash_attention_compiled_matches_full(causal):
     from lightctr_tpu.nn.flash_attention import flash_attention
@@ -44,12 +30,6 @@ def test_flash_attention_compiled_matches_full(causal):
 
 
 # -- sparse hot-path registry kernels: compiled Mosaic gates -----------------
-#
-# Only kernels ``auto`` selects on a TPU have a gate here.  The ones the
-# registry deselects (dedup_ids, merge_rows, merge_apply, gather_rows,
-# quantize_pack_ef_update) do not compile at the trainer's width; their
-# per-kernel verdict, with the compiler's message, is chip_smoke.py's
-# kernel phase.
 
 
 def test_quantize_pack_compiled_bit_identical():

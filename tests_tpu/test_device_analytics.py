@@ -1,8 +1,8 @@
 """Compiled-mode (real TPU) gates for the device observability plane
 (ISSUE 19): the ProgramCatalog's HLO cost/memory analytics must be
-readable for the registered fused kernels through the actual Mosaic
-lowering path — not just the CPU/interpret twin the main suite proves —
-and donation verification must confirm the donated fused-update really
+readable for the registered kernels through the actual Mosaic
+lowering path — not just the interpreter the main suite proves —
+and donation verification must confirm a donated update really
 aliases on chip (the property whose silent loss doubles HBM traffic).
 
     python -m pytest tests_tpu -q        # from the repo root, TPU visible
@@ -94,18 +94,19 @@ def test_catalog_analyzes_registered_mosaic_kernels():
         cat.close()
 
 
-def test_donated_fused_adagrad_aliases_on_chip():
-    """verify_donation on the REAL donated fused update: the aliased
-    path must record checks with zero misses on hardware — this is the
-    acceptance twin of the CPU test's broken control, run where the
-    aliasing actually pays (in-place HBM update vs a full table copy)."""
-    from lightctr_tpu.optim.fused_adagrad import fused_adagrad_update
+def test_donated_adagrad_update_aliases_on_chip():
+    """verify_donation on a REAL donated Adagrad update: the aliased
+    path must record checks with zero misses on hardware — the
+    counterpart of the CPU test's broken control, run where the aliasing
+    actually pays (in-place HBM update vs a full table copy)."""
+    def update(w, a, g):
+        a = a + g * g
+        return w - 0.1 * g * jax.lax.rsqrt(a + 1e-7), a
 
     watch = device.DonationWatch(register=False)
-    fn = jax.jit(lambda w, a, g: fused_adagrad_update(w, a, g, 0.1),
-                 donate_argnums=(0, 1))
+    fn = jax.jit(update, donate_argnums=(0, 1))
     checked = device.verify_donation(
-        "fused_adagrad", fn, donate_argnums=(0, 1),
+        "adagrad_update", fn, donate_argnums=(0, 1),
         watch=watch, sample_every=1)
     n = 1 << 16
     with obs.override(True):
@@ -116,8 +117,8 @@ def test_donated_fused_adagrad_aliases_on_chip():
             jax.random.normal(jax.random.PRNGKey(2), (n,), jnp.float32))
     jax.block_until_ready((w2, a2))
     snap = watch.snapshot()
-    assert snap["programs"]["fused_adagrad"]["checks"] == 1
-    assert snap["programs"]["fused_adagrad"]["misses"] == 0
+    assert snap["programs"]["adagrad_update"]["checks"] == 1
+    assert snap["programs"]["adagrad_update"]["misses"] == 0
     watch.close()
 
 

@@ -29,10 +29,12 @@ turns either into something readable:
       #    occupancy, hit/fault/demotion counters, and fault-path
       #    latency for TIERED stores
   python -m tools.metrics_report --kernels SNAPSHOT_JSON
-      # -> which sparse-hot-path kernel implementation actually ran
+      # -> which implementation of the registered kernels (payload
+      #    pack, flash attention) actually ran
       #    (trainer_kernel_path_total{phase,impl} from a registry
       #    snapshot or stats() dump): per-phase dispatch counts for
-      #    pallas / interpret / xla — measured, not assumed
+      #    pallas / interpret / xla — measured, not assumed; and per
+      #    table how the sized apply engaged (trainer_apply_*_total)
   python -m tools.metrics_report --online SNAPSHOT_JSON
       # -> online learning plane (docs/ONLINE.md): freshness age +
       #    per-entry apply-age percentiles, deltas applied vs
@@ -338,12 +340,13 @@ def summarize_store(doc) -> dict:
 
 def summarize_kernels(doc) -> dict:
     """Registry snapshot (or a stats() dump carrying one under
-    ``telemetry``) -> per-phase kernel dispatch report: how many traces
-    resolved each implementation of ``trainer_kernel_path_total``.  The
-    counter increments once per dispatch at trace time (the pick is
-    static inside jit), so this answers "which implementation actually
-    ran" — the honesty check docs/KERNELS.md's bench methodology leans
-    on.  Beside it, per table, how the sized XLA apply engaged:
+    ``telemetry``) -> per-phase dispatch report of the registered
+    kernels (``sparse_kernels.KERNELS``: the phases with two
+    implementations): how many traces resolved each implementation of
+    ``trainer_kernel_path_total``.  The counter increments once per
+    dispatch at trace time (the pick is static inside jit), so this
+    answers "which implementation actually ran".  Beside it, per table,
+    how the sized apply engaged:
     ``trainer_apply_live_rows_total`` over ``trainer_apply_slots_total``
     is the share of the slots it worked on that held a live row (the rest
     is the rung's round-up; 1 - slots / ids is what the ladder spared).
@@ -917,9 +920,9 @@ def main(argv=None):
                          "from a PS stats() dump — one shard's dict or a "
                          "ShardedPSClient.stats() list")
     ap.add_argument("--kernels", metavar="SNAPSHOT_JSON",
-                    help="summarize sparse-kernel dispatch counts "
-                         "(trainer_kernel_path_total{phase,impl}) from a "
-                         "registry snapshot or stats() dump")
+                    help="summarize the registered kernels' dispatch "
+                         "counts (trainer_kernel_path_total{phase,impl}) "
+                         "from a registry snapshot or stats() dump")
     ap.add_argument("--online", metavar="SNAPSHOT_JSON",
                     help="summarize the online learning plane (freshness "
                          "age + deltas applied vs full refreshes, swap "

@@ -814,11 +814,11 @@ def run(steps: int = 4, out: str = "SPARSE_RING_BENCH.json",
         "the grid must cover the rs-winning regime"
     )
 
-    # live kernel-dispatch cell (ISSUE 9): which sparse-hot-path kernel
-    # implementation the trainer cells above ACTUALLY ran, read from the
-    # same trainer_kernel_path_total{phase,impl} counters a production
+    # live kernel-dispatch cell (ISSUE 9): which implementation of the
+    # registered kernels the trainer cells above ACTUALLY ran, read from
+    # the same trainer_kernel_path_total{phase,impl} counters a production
     # scrape sees (the dispatch counts to the process default registry at
-    # trace time) — off-TPU this records the XLA reference path honestly.
+    # trace time) — off-TPU this records their XLA form honestly.
     from lightctr_tpu import obs as obs_mod
     from lightctr_tpu.ops import sparse_kernels
     from tools.metrics_report import summarize_kernels
@@ -830,9 +830,9 @@ def run(steps: int = 4, out: str = "SPARSE_RING_BENCH.json",
     }
     kernel_cell["note"] = (
         "dispatch counts from the live trainer cells above (once per "
-        "traced program per kernel); 'resolved' is the capability-gated "
-        "pick on THIS platform — pallas only on a real TPU, so a CPU run "
-        "records the reference path instead of faking a fused win"
+        "traced program per kernel); 'resolved' is the pick of each of "
+        "the registered kernels on THIS platform — pallas only on a real "
+        "TPU, so a CPU run records the XLA form instead of faking a fused win"
     )
 
     criteo_like = sweep[-1]
