@@ -49,8 +49,10 @@ _LOG = logging.getLogger(__name__)
 
 
 def _health_pack(loss, grad_norm):
-    """One f32[2] device vector ``[loss, grad_norm]`` — the health feed's
-    single-fetch payload (see ``CTRTrainer._feed_health``)."""
+    """One f32[2] device vector ``[loss, grad_norm]`` — the head of the
+    health feed's single-fetch payload (see ``CTRTrainer._feed_health``);
+    a step appends what else it reports (the sparse trainer's counts or
+    overflow slot), then the quality sketch."""
     return jnp.stack([
         jnp.asarray(loss, jnp.float32), jnp.asarray(grad_norm, jnp.float32)
     ])
@@ -670,9 +672,11 @@ class CTRTrainer:
     _HEALTH_MAX_LAG = 8
 
     def _feed_health(self, batch, health) -> None:
-        """Per-step ``[loss, grad_norm]`` vectors (and any subclass
-        signals) into the health monitor.  ``wants`` gates the work: a
-        monitor without loss/grad detectors costs nothing here.  The
+        """Per-step health vectors — ``[loss, grad_norm]``, then whatever
+        else the step's program reports (:meth:`_vector_signals`) — and
+        any signal a subclass makes on the host (:meth:`_health_signals`)
+        into the health monitor.  ``wants`` gates the work: a monitor
+        with no detector for any of them costs nothing here.  The
         vectors are queued as DEVICE values and drained oldest-first once
         materialized (``jax.Array.is_ready``) with ONE host fetch each,
         so the feed never syncs the in-flight step — a NaN step flips
@@ -687,7 +691,8 @@ class CTRTrainer:
         # the quality tracker drains the SAME queued vector (its sketch
         # tail), so an armed trainer feeds it even with health monitoring
         # off — the queue discipline below is identical either way
-        want = (on and hm.wants("loss", "grad_norm")) \
+        want = (on and hm.wants("loss", "grad_norm",
+                                *self._vector_signals())) \
             or self.quality is not None
         if health is None or not want:
             return
@@ -748,9 +753,17 @@ class CTRTrainer:
         return self.stepwatch
 
     def _health_signals(self, batch) -> Dict:
-        """Extra health signals subclasses contribute per step (the sparse
-        trainer reports per-table touched-uid counts here)."""
+        """Extra health signals a subclass makes on the host per step (the
+        sparse trainer's hybrid and hier steps count per-table touched
+        uids here)."""
         return {}
+
+    def _vector_signals(self) -> tuple:
+        """Names of the signals a subclass's step carries in the health
+        vector behind ``[loss, grad_norm]``: a monitor that wants one has
+        the vector queued (the sparse trainer's one-program step carries
+        ``table_touch``)."""
+        return ()
 
     def _step_event_fields(self) -> Dict:
         """Extra fields subclasses contribute to each ``step`` event (the

@@ -195,7 +195,100 @@ EXCHANGE_SERIES = (
     # lane rows the apply writes, lane-packed tables only: over the live
     # rows, how many of them share a lane row (1 is none, 1/r is all)
     "trainer_apply_lane_rows_total",     # {table[, shard]}
+    # steps whose table_touch (and the three counters above) came off the
+    # step's own health vector ("device": the one-program step) against
+    # steps whose ids the host counted ("host": the hybrid and hier steps)
+    "trainer_health_signals_total",      # {source}
 )
+
+
+def _pack_counts(counts):
+    """int32 ``[n]`` -> f32 ``[2n]``: the low 16 bits of each, then the
+    high ones.  A half is exact in an f32 whatever the count (159,744
+    would fit one whole, a stream of 2^24 ids would not) and stays exact
+    through the sum with zeros by which a mesh may replicate the vector;
+    a bitcast would ride as a denormal, which a TPU flushes."""
+    c = counts.astype(jnp.int32)
+    return jnp.concatenate([c & 0xFFFF, c >> 16]).astype(jnp.float32)
+
+
+def _unpack_counts(vals: np.ndarray) -> list:
+    """The host's inverse of :func:`_pack_counts`: Python ints."""
+    halves = vals.astype(np.int64)
+    n = halves.shape[0] // 2
+    return (halves[:n] + (halves[n:] << 16)).tolist()
+
+
+class _StepCounts:
+    """The integers the one-program step's health vector carries behind
+    ``[loss, grad_norm]`` (ahead of the quality sketch): :meth:`pack`
+    makes them in the jitted step, :meth:`read` is what the host makes of
+    them once the vector is drained.  In order: per id stream the dedup's
+    distinct ``count`` and the stream's length; per table — per row shard
+    where ``shards`` (``{table: shards}``) names the table — the live
+    slots of the apply's plan, the ``branch`` its switches took and, for
+    a lane-packed table, the lane rows it wrote
+    (``sparse_kernels.apply_counts``).  All static but the values: built
+    with the step, from what the step is built from."""
+
+    def __init__(self, spec, vocab: Dict[str, int], shards: Dict[str, int],
+                 lane_pack: Dict[str, int]):
+        self._groups = SparseTableCTRTrainer._field_groups(spec)
+        self._vocab = vocab
+        # (table, rows a lane row holds, the labels of its counters a shard)
+        self._apply = [
+            (k, lane_pack.get(k, 1),
+             [{"table": k, "shard": i} for i in range(shards[k])]
+             if k in shards else [{"table": k}])
+            for k in spec]
+        #: f32 slots of the vector the counts take: two an integer
+        self.width = 2 * (2 * len(self._groups) + sum(
+            (2 + (r > 1)) * len(per) for _, r, per in self._apply))
+
+    def pack(self, distinct, uids, batch):
+        """The ``width`` slots, inside the step: ``distinct`` is
+        ``_dedup_and_gather``'s ``{field_tuple: count}``, ``uids`` its
+        ``{table: uids}``.  Each shard's plan is made from the replicated
+        ``uids`` as the shard itself makes it, on every chip alike, so no
+        collective has to bring it out of the apply's ``shard_map``."""
+        from lightctr_tpu.ops import sparse_kernels
+
+        counts = [jnp.stack([distinct[fields],
+                             sum(batch[f].size for f in fields)])
+                  for fields in self._groups]
+        for k, r, per in self._apply:
+            rows = self._vocab[k] // len(per)
+            counts += [sparse_kernels.apply_counts(
+                uids[k], rows, i * rows if len(per) > 1 else None, r)
+                for i in range(len(per))]
+        return _pack_counts(jnp.concatenate(counts))
+
+    def read(self, vals: np.ndarray, reg) -> Dict:
+        """``vals``: the vector's ``width`` count slots.  Increments the
+        apply's counters on ``reg`` — live rows, the slots of the rung
+        the device's switch took (the ladder's, or all K on the
+        undeclared branch), lane rows — and returns the skew detector's
+        ``table_touch``."""
+        from lightctr_tpu.ops import sparse_kernels
+
+        ints = iter(_unpack_counts(vals))
+        touch = {}
+        for tables in self._groups.values():
+            unique, ids = next(ints), next(ints)
+            for k in tables:
+                touch[k] = {"unique": unique, "ids": ids,
+                            "vocab": self._vocab[k]}
+        for k, r, per in self._apply:
+            ladder = sparse_kernels.apply_ladder(touch[k]["ids"])
+            for labels in per:
+                reg.inc(obs.labeled("trainer_apply_live_rows_total",
+                                    **labels), next(ints))
+                reg.inc(obs.labeled("trainer_apply_slots_total", **labels),
+                        (ladder + ladder[-1:])[next(ints)])
+                if r > 1:
+                    reg.inc(obs.labeled("trainer_apply_lane_rows_total",
+                                        **labels), next(ints))
+        return touch
 
 
 class SparseTableCTRTrainer(CTRTrainer):
@@ -316,9 +409,10 @@ class SparseTableCTRTrainer(CTRTrainer):
         # JSONs cannot disagree
         self.exchange_bytes_per_step: Dict[str, int] = {}
         self._exchange_logged = False
-        # the step's per-table distinct counts and sorted distinct ids,
-        # handed from _health_signals to _count_apply_slots
-        self._last_touch = None
+        # what the one-program step's health vector carries behind [loss,
+        # grad_norm] (_make_step builds it with the step); None where the
+        # program that runs carries no counts and the host counts the ids
+        self._step_counts: Optional[_StepCounts] = None
         # reduce-scatter capacity safety net: rs capacities are EXPECTED
         # sizes with slack (dist.collectives.rs_default_caps), so every
         # batch is checked HOST-side (rs_fits) before dispatch and one
@@ -594,7 +688,9 @@ class SparseTableCTRTrainer(CTRTrainer):
         in ``lane_pack`` (``{table: r}``) is stored ``[V // r, 128]`` and
         gathered by whole lane rows — ``rows[k]`` is ``[K, d]`` all the
         same.  Shared by the single-program step and the per-replica
-        hybrid step (where ``batch`` is the replica's local shard).
+        hybrid step (where ``batch`` is the replica's local shard).  The
+        last output is each id stream's distinct count, ``{field_tuple:
+        count}``, as the dedup returns it.
 
         Tables listing the IDENTICAL field tuple run the dedup once and
         share the resulting ``(uids, inv)`` — their position rewrites
@@ -608,7 +704,7 @@ class SparseTableCTRTrainer(CTRTrainer):
         tables = {k: params[k] for k in spec}
         dense = {k: v for k, v in params.items() if k not in spec}
         batch2 = dict(batch)
-        uids = {}
+        uids, counts = {}, {}
         groups = SparseTableCTRTrainer._field_groups(spec)
 
         def gather(k):
@@ -632,7 +728,7 @@ class SparseTableCTRTrainer(CTRTrainer):
                 ids = jnp.concatenate(
                     [batch[f].reshape(-1) for f in fields]
                 ).astype(jnp.int32)
-                u, inv, _ = sparse_kernels.dedup_ids(ids)
+                u, inv, counts[fields] = sparse_kernels.dedup_ids(ids)
                 for k in keys:
                     uids[k] = u
                 ofs = 0
@@ -644,7 +740,7 @@ class SparseTableCTRTrainer(CTRTrainer):
             # the apply's ladder; the rows behind the rung are zeros, and
             # ``inv`` never points past the live prefix
             rows = {k: gather(k) for k in spec}
-        return tables, dense, batch2, uids, rows
+        return tables, dense, batch2, uids, rows, counts
 
     def _make_step(self):
         armed = self._quality_bins is not None
@@ -655,6 +751,13 @@ class SparseTableCTRTrainer(CTRTrainer):
         dedup_and_gather = self._dedup_and_gather
         mesh, row_shards = self.mesh, self._row_shards()
         lane_pack = self._lane_pack
+        layout = _StepCounts(
+            spec, {k: self._table_shapes[k][0] for k in spec},
+            {k: mesh.shape[axis] for k, axis in row_shards.items()},
+            lane_pack)
+        if not self._hier:
+            # (the hier trainer builds this program and never runs it)
+            self._step_counts = layout
 
         def apply(k, table, accum, u, g):
             """Touched-row apply (``sparse_kernels.merge_apply``):
@@ -680,7 +783,7 @@ class SparseTableCTRTrainer(CTRTrainer):
             return one(table, accum, u, g)
 
         def step(params, opt_state, batch):
-            tables, dense, batch2, uids, rows = dedup_and_gather(
+            tables, dense, batch2, uids, rows, distinct = dedup_and_gather(
                 spec, params, batch, mesh, row_shards, lane_pack
             )
 
@@ -714,7 +817,9 @@ class SparseTableCTRTrainer(CTRTrainer):
 
             params = {**dense, **tables}
             health = self._append_sketch(
-                _health_pack(loss, gnorm), probs, batch2)
+                jnp.concatenate([_health_pack(loss, gnorm),
+                                 layout.pack(distinct, uids, batch)]),
+                probs, batch2)
             return (params, {"dense": new_dense_state, "accum": new_accum},
                     loss, health)
 
@@ -799,7 +904,7 @@ class SparseTableCTRTrainer(CTRTrainer):
         def local_step(params, opt_state, batch):
             # batch arrives as this replica's shard: the dedup below is
             # per-replica, over O(local touched) ids
-            tables, dense, batch2, uids, rows = dedup_and_gather(
+            tables, dense, batch2, uids, rows, _ = dedup_and_gather(
                 spec, params, batch
             )
 
@@ -1102,7 +1207,7 @@ class SparseTableCTRTrainer(CTRTrainer):
                 self.hier_local_bytes_per_step
 
         def local_step(params, batch):
-            tables, dense, batch2, uids, rows = dedup_and_gather(
+            tables, dense, batch2, uids, rows, _ = dedup_and_gather(
                 spec, params, batch
             )
 
@@ -1652,20 +1757,37 @@ class SparseTableCTRTrainer(CTRTrainer):
             return self._fallback_policy, self._fallback_bytes
         return self.exchange_policy, self.exchange_bytes_per_step
 
+    def _vector_signals(self) -> tuple:
+        return ("table_touch",) if self._step_counts is not None else ()
+
     def _observe_scalars(self, hm, health) -> None:
-        """The hybrid/hier step's health vector carries a third slot: the
-        in-jit rs overflow count.  Nonzero means the host capacity check
-        and the compiled program disagreed — gradient entries were
-        dropped; surface it loudly instead of silently.  Anything past
-        the head scalars is the quality sketch (when armed), so the
-        overflow slot is addressed by step family, not by length."""
+        """What rides the vector behind ``[loss, grad_norm]`` is the step
+        program's own: the one-program step's counts (``_StepCounts``:
+        the skew detector's ``table_touch`` and the apply's counters,
+        from the same single fetch, a queue's lag behind the step), or
+        the hybrid/hier step's third slot, the in-jit rs overflow count.
+        Nonzero means the host capacity check and the compiled program
+        disagreed — gradient entries were dropped; surface it loudly
+        instead of silently.  Anything past the head is the quality
+        sketch (when armed), so the slots are addressed by what the
+        program carries, not by length."""
         vals = self._fetch_health(health)
+        signals = {"loss": float(vals[0]), "grad_norm": float(vals[1])}
+        counts = self._step_counts
+        if counts is not None:
+            head = 2 + counts.width
+            if hm is not None and hm.wants("table_touch"):
+                signals["table_touch"] = counts.read(vals[2:head],
+                                                     self.telemetry)
+                self.telemetry.inc(obs.labeled(
+                    "trainer_health_signals_total", source="device"))
+        else:
+            head = 3
+            if vals.shape[0] > 2 and vals[2] > 0:
+                self.telemetry.inc("trainer_rs_overflow_total", int(vals[2]))
+                obs.emit_event("rs_overflow", count=int(vals[2]))
         if hm is not None:
-            hm.observe(loss=float(vals[0]), grad_norm=float(vals[1]))
-        head = 3 if (self._hybrid_dp or self._hier) else 2
-        if head == 3 and vals.shape[0] > 2 and vals[2] > 0:
-            self.telemetry.inc("trainer_rs_overflow_total", int(vals[2]))
-            obs.emit_event("rs_overflow", count=int(vals[2]))
+            hm.observe(**signals)
         self._feed_quality(vals, head)
 
     def _exchange_byte_totals(self):
@@ -1715,75 +1837,36 @@ class SparseTableCTRTrainer(CTRTrainer):
         return wire, sum(wire.values()), sum(lb.values())
 
     def _health_signals(self, batch) -> Dict:
-        """Per-table touched-uid counts for the skew detector — the same
-        id streams ``_dedup_and_gather`` dedups in-jit, fetched back from
-        the device and counted host-side with ``np.unique``: at a
-        Criteo-shape batch that is 159,744 int32 ids a table and 5.8 ms a
-        step (``train_telemetry_ms_per_step``, PERF.md).  Skipped entirely
-        unless a table_skew detector is installed."""
+        """Per-table touched-uid counts for the skew detector where the
+        step's program does not carry them: the hybrid and hier programs
+        dedup each replica's LOCAL rows in-jit, so the global distinct
+        count is nowhere on the device, and the host counts it — the id
+        columns fetched back and one ``np.unique`` a table, on the step's
+        thread (5.8 ms a step at a Criteo-shape batch; PERF.md section 6,
+        PR 35).  The one-program step returns its own dedup's counts in
+        the health vector (:meth:`_observe_scalars`) and nothing is
+        counted here.  Skipped entirely unless a table_skew detector is
+        installed."""
         hm = self.health
-        if hm is None or not hm.wants("table_touch"):
+        if (self._step_counts is not None or hm is None
+                or not hm.wants("table_touch")):
             return {}
-        touch, distinct = {}, {}
+        touch = {}
         for k, fields in self._spec.items():
             ids = np.concatenate(
                 [np.asarray(batch[f]).reshape(-1) for f in fields]
             )
-            distinct[k] = np.unique(ids)
             touch[k] = {
-                "unique": int(distinct[k].size),
+                "unique": int(np.unique(ids).size),
                 "ids": int(ids.size),
                 "vocab": self._table_shapes[k][0],
             }
-        self._last_touch = touch, distinct
+        self.telemetry.inc(obs.labeled(
+            "trainer_health_signals_total", source="host"))
         return {"table_touch": touch}
-
-    def _count_apply_slots(self) -> None:
-        """How often the sized apply engages: live rows against the slots
-        of the rung taken (``sparse_kernels.ladder_slots``, the function
-        the device's switch indexes), from the sorted distinct ids
-        :meth:`_health_signals` made on the host this step — no fetch and
-        no ``np.unique`` of its own.  A row-sharded table counts per
-        shard (label ``shard``): each shard takes the rung that holds the
-        distinct ids in its own row range, one ``searchsorted`` of the
-        shard bounds.  A lane-packed table also counts the lane rows
-        those ids fall in (``trainer_apply_lane_rows_total``: what the
-        apply's scatters write).  Only where the one-program step's apply runs:
-        the exchange steps apply the merged global ids, which the host
-        never counts."""
-        from lightctr_tpu.ops import sparse_kernels
-
-        last, self._last_touch = self._last_touch, None
-        if not last or self._hybrid_dp or self._hier:
-            return
-        touch, distinct = last
-        reg = self.telemetry
-        row_shards = self._row_shards()
-        for k, t in touch.items():
-            per = [({"table": k}, distinct[k])]
-            if k in row_shards:
-                n = self.mesh.shape[row_shards[k]]
-                cuts = np.searchsorted(
-                    distinct[k], np.arange(1, n) * (t["vocab"] // n))
-                per = [({"table": k, "shard": i}, own)
-                       for i, own in enumerate(np.split(distinct[k], cuts))]
-            r = self._lane_pack.get(k)
-            for labels, own in per:
-                reg.inc(obs.labeled("trainer_apply_live_rows_total",
-                                    **labels), own.size)
-                reg.inc(obs.labeled("trainer_apply_slots_total", **labels),
-                        sparse_kernels.ladder_slots(t["ids"], own.size))
-                if r:
-                    # a shard's rows are a multiple of r: no lane row
-                    # spans two shards
-                    reg.inc(obs.labeled("trainer_apply_lane_rows_total",
-                                        **labels),
-                            int(np.count_nonzero(np.diff(own // r))
-                                + (own.size > 0)))
 
     def _record_step(self, dt: float, batch, health=None) -> None:
         super()._record_step(dt, batch, health=health)
-        self._count_apply_slots()
         policy, xbytes = self._live_exchange_dicts()
         if not ((self._hybrid_dp or self._hier) and policy):
             return

@@ -381,6 +381,27 @@ def live_plan(uids: jax.Array, vocab: int):
     return shard_plan(uids, vocab)[:2]
 
 
+def apply_counts(uids: jax.Array, rows: int, lo=None, pack: int = 1):
+    """What :func:`merge_apply` works on over the table rows ``[lo, lo +
+    rows)`` of ``uids`` (``lo=None``: a whole table of ``rows``; ``rows``
+    logical where the table is lane-packed), as int32 ``[2 + (pack >
+    1)]``: the live slots of the plan it makes there, the ``branch`` its
+    switches take, and for a lane-packed table the lane rows its scatters
+    write — ``idx // pack`` over the own slots, which stand in one
+    ascending run on a rung, so a lane row is new where it differs from
+    the slot before (one K-sized pass; on the undeclared branch that
+    counts runs, an upper bound).  :func:`shard_plan` of the same ids and
+    the same rows: the integers are the apply's own."""
+    idx, branch, _, count = shard_plan(uids, rows, lo)
+    out = [count.astype(jnp.int32), branch]
+    if pack > 1:
+        phys = idx // pack
+        new = jnp.concatenate([jnp.ones((1,), jnp.bool_),
+                               phys[1:] != phys[:-1]])
+        out.append(jnp.sum((idx < rows) & new, dtype=jnp.int32))
+    return jnp.stack(out)
+
+
 def _ladder_branches(k: int, rung: Callable) -> list:
     """``rung(slots, ordered)`` for every rung of ``apply_ladder(k)``,
     then the undeclared full-K branch ``live_plan`` names for ids it saw

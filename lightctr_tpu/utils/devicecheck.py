@@ -25,16 +25,28 @@ def pin_cpu_platform(n_devices: Optional[int] = None) -> None:
     device count is read once, at backend initialisation), the
     ``JAX_PLATFORMS`` environment variable (inherited by children), and
     ``jax.config`` (this process may have imported jax already, after
-    which the environment variable alone is too late)."""
+    which the environment variable alone is too late).
+
+    It also takes XLA:CPU's concurrency-optimized scheduler off, unless
+    ``XLA_FLAGS`` already says either way.  The row-sharded sparse step
+    holds one ``psum`` in every branch of two ``switch``es over the same
+    axis, all on one channel id; under that scheduler the CPU runtime
+    starts both conditionals at once, two all-reduces meet in one
+    rendezvous and the process aborts or segfaults (PR 35: the parent's
+    step over unpacked ``[V, 16]``-and-wider tables 0 of 4 runs, 4 of 4
+    with the scheduler off).  A TPU runs its ops in one stream."""
+    flags = os.environ.get("XLA_FLAGS", "")
     if n_devices is not None:
-        flags = os.environ.get("XLA_FLAGS", "")
         pat = r"--xla_force_host_platform_device_count=\d+"
         want = f"--xla_force_host_platform_device_count={n_devices}"
         if re.search(pat, flags):
             flags = re.sub(pat, want, flags)
         else:
             flags = (flags + " " + want).strip()
-        os.environ["XLA_FLAGS"] = flags
+    if "xla_cpu_enable_concurrency_optimized_scheduler" not in flags:
+        flags = (flags + " --xla_cpu_enable_concurrency_optimized_scheduler"
+                 "=false").strip()
+    os.environ["XLA_FLAGS"] = flags
     os.environ["JAX_PLATFORMS"] = "cpu"
     import jax
 

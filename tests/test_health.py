@@ -477,6 +477,7 @@ def test_sparse_trainer_feeds_table_touch_and_flags_dead_table():
                     rep_fids=np.zeros_like(rep))
         for _ in range(2):  # trip_after=2
             tr.train_step(dead)
+        tr.flush_health()  # the counts ride the step's health vector
         v = hm.verdict()
         assert v["detectors"]["table_skew"]["status"] == health.UNHEALTHY
         detail = v["detectors"]["table_skew"]["detail"]

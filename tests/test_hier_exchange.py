@@ -427,6 +427,8 @@ def test_hier_trainer_matches_single_process_oracle(rng):
         np.testing.assert_allclose(p0[k], np.asarray(oracle.params[k]),
                                    rtol=1e-4, atol=1e-5)
     assert tr0.exchange_policy == {"w": "hier", "v": "hier"}
+    # the hier programs' health vector carries no counts: the host counts
+    assert tr0._step_counts is None and oracle._step_counts is not None
     assert tr0.hier_local_policy["w"] in ("sparse", "sparse_rs")
     assert all(b > 0 for b in tr0.exchange_bytes_per_step.values())
     snap = regs[0].snapshot()

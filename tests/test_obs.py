@@ -879,10 +879,17 @@ def test_metrics_report_kernels_apply_section(sharded, tmp_path, capsys):
         labels = dict(table="embed", **({"shard": shard} if sharded else {}))
         reg.inc(obs.labeled("trainer_apply_live_rows_total", **labels), live)
         reg.inc(obs.labeled("trainer_apply_slots_total", **labels), slots)
+    # where the counts came from: the step's health vector, or the host
+    reg.inc(obs.labeled("trainer_health_signals_total", source="device"), 511)
+    if sharded:
+        reg.inc(obs.labeled("trainer_health_signals_total", source="host"), 2)
     path = tmp_path / "snap.json"
     path.write_text(json.dumps(reg.snapshot()))
     assert metrics_report.main(["--kernels", str(path)]) == 0
-    entry = json.loads(capsys.readouterr().out)["apply"]["embed"]
+    report = json.loads(capsys.readouterr().out)
+    assert report["health_signals"] == (
+        {"device": 511, "host": 2} if sharded else {"device": 511})
+    entry = report["apply"]["embed"]
     if not sharded:
         assert entry == {"live_rows": 45_393, "slots": 49_920,
                          "live_share": 0.9093}

@@ -69,9 +69,9 @@ def _row_sharded(mesh):
             "fc1": {"w": rep, "b": rep}, "fc2": {"w": rep, "b": rep}}
 
 
-def _stream_cases():
+def _stream_cases(vocab=_VOCAB):
     """{name: (ids_of(step, n_shards) -> the batch's distinct ids, hook)}:
-    every case builds the distinct set per shard of ``_VOCAB // n`` rows."""
+    every case builds the distinct set per shard of ``vocab // n`` rows."""
     from lightctr_tpu.ops import sparse_kernels as sk
 
     ladder = sk.apply_ladder(_B * _F)
@@ -79,7 +79,7 @@ def _stream_cases():
     def spread(counts_of):
         def ids(step, n):
             rng = np.random.default_rng(100 + step)
-            v = _VOCAB // n
+            v = vocab // n
             out = []
             for e, c in enumerate(counts_of(n, step)):
                 lo = max(1, e * v)                   # id 0 only on purpose
@@ -140,7 +140,8 @@ def _wd_batch(distinct, step):
 def test_sharded_sparse_step_equals_one_device(axes, stream, monkeypatch):
     """Params, accumulators and loss of the row-sharded O(touched) step
     against the one-device step over three batches, and each shard on the
-    rung its own rows need (the host's per-shard counters name it)."""
+    rung its own rows need (the per-shard counters name it, from the
+    branch the step reports in its health vector)."""
     from lightctr_tpu import obs
     from lightctr_tpu.models.sparse_trainer import SparseTableCTRTrainer
     from lightctr_tpu.obs import health
@@ -173,7 +174,12 @@ def test_sharded_sparse_step_equals_one_device(axes, stream, monkeypatch):
                 ls, lp = sharded.train_step(batch), plain.train_step(batch)
                 np.testing.assert_allclose(float(ls), float(lp), rtol=2e-6)
                 per = np.bincount(distinct // (_VOCAB // n), minlength=n)
-                want_slots += [sk.ladder_slots(_B * _F, int(c)) for c in per]
+                # (ids the plan sees out of order take the undeclared
+                # branch, all K slots, and the counter says so since it
+                # reads the branch the device's switch took)
+                want_slots += [_B * _F if stream == "unsorted"
+                               else sk.ladder_slots(_B * _F, int(c))
+                               for c in per]
             sharded.flush_health()
     finally:
         sharded.health.close()

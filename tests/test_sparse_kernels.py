@@ -550,7 +550,7 @@ def test_forward_gather_reads_the_live_prefix(rng):
     fids = rng.integers(0, 2500, size=(b, f)).astype(np.int32)
     params = {"v": jnp.asarray(rng.normal(size=(vocab, 3)), jnp.float32),
               "w": jnp.asarray(rng.normal(size=(vocab,)), jnp.float32)}
-    _, _, batch2, uids, rows = jax.jit(
+    _, _, batch2, uids, rows, _ = jax.jit(
         lambda p, bt: SparseTableCTRTrainer._dedup_and_gather(
             {"v": ("fids",), "w": ("fids",)}, p, bt)
     )(params, {"fids": jnp.asarray(fids)})
@@ -567,8 +567,8 @@ def test_forward_gather_reads_the_live_prefix(rng):
 
 def test_trainer_counts_live_rows_and_rung_slots():
     """``trainer_apply_live_rows_total`` / ``trainer_apply_slots_total``:
-    incremented from the host's own distinct counts and the ladder
-    function, per table, and printed as the live share by
+    incremented from the live count and the branch the step reports in
+    its health vector, per table, and printed as the live share by
     ``metrics_report --kernels``."""
     from lightctr_tpu import TrainConfig, obs
     from lightctr_tpu.models import fm
@@ -788,7 +788,7 @@ def test_packed_apply_matches_sparse_adagrad_update(case, shards):
 
 def test_trainer_counts_lane_rows():
     """``trainer_apply_lane_rows_total``: the lane rows the packed apply
-    writes, from the sorted distinct ids the host already holds; only a
+    writes, counted in the step and read off its health vector; only a
     lane-packed table counts them, and ``metrics_report --kernels`` prints
     their share of the live rows."""
     from lightctr_tpu import TrainConfig, obs

@@ -416,12 +416,15 @@ def test_shared_id_stream_rewrite(rng):
         "c": jnp.asarray(rng.normal(size=(vocab, 2)), jnp.float32),
     }
     spec = {"a": ("fids",), "b": ("fids",), "c": ("other",)}
-    tables, dense, batch2, uids, rows = \
+    tables, dense, batch2, uids, rows, counts = \
         SparseTableCTRTrainer._dedup_and_gather(spec, params, batch)
     assert uids["a"] is uids["b"]  # literally one shared stream
     assert uids["c"] is not uids["a"]
     ids = batch["fids"].reshape(-1).astype(np.int32)
     u, inv = np.unique(ids, return_inverse=True)
+    # one distinct count a stream, the dedup's own
+    assert {f: int(c) for f, c in counts.items()} == {
+        ("fids",): u.size, ("other",): np.unique(batch["other"]).size}
     np.testing.assert_array_equal(np.asarray(uids["a"])[:u.size], u)
     np.testing.assert_array_equal(
         np.asarray(batch2["fids"]).reshape(-1), inv

@@ -34,7 +34,9 @@ turns either into something readable:
       #    (trainer_kernel_path_total{phase,impl} from a registry
       #    snapshot or stats() dump): per-phase dispatch counts for
       #    pallas / interpret / xla — measured, not assumed; and per
-      #    table how the sized apply engaged (trainer_apply_*_total)
+      #    table how the sized apply engaged (trainer_apply_*_total),
+      #    and whether the step or the host counted the ids
+      #    (trainer_health_signals_total{source})
   python -m tools.metrics_report --online SNAPSHOT_JSON
       # -> online learning plane (docs/ONLINE.md): freshness age +
       #    per-entry apply-age percentiles, deltas applied vs
@@ -356,12 +358,17 @@ def summarize_kernels(doc) -> dict:
     table the trainer keeps lane-packed also counts the lane rows its
     apply writes (``trainer_apply_lane_rows_total``): over the live rows
     that is ``lane_row_share``, 1 where no two live rows share a lane row
-    and 1/r where every one is shared in full."""
+    and 1/r where every one is shared in full.  ``health_signals`` says
+    where those counts and the skew detector's ``table_touch`` came from
+    (``trainer_health_signals_total{source}``): steps read off the step's
+    own health vector (``device``) against steps the host counted with
+    ``np.unique`` (``host``: the hybrid and hier exchange steps)."""
     snap = doc.get("telemetry", doc) if isinstance(doc, dict) else doc
     counters = snap.get("counters", {})
     phases: dict = {}
     total_by_impl: dict = {}
     apply: dict = {}
+    signals: dict = {}
 
     def _labels(name: str, prefix: str) -> dict:
         return dict(
@@ -371,6 +378,9 @@ def summarize_kernels(doc) -> dict:
 
     prefix = "trainer_kernel_path_total{"
     for name, val in counters.items():
+        p = "trainer_health_signals_total{"
+        if name.startswith(p):
+            signals[_labels(name, p).get("source", "?")] = int(val)
         for what in ("live_rows", "slots", "lane_rows"):
             p = f"trainer_apply_{what}_total{{"
             if name.startswith(p):
@@ -400,6 +410,7 @@ def summarize_kernels(doc) -> dict:
                     entry["lane_rows"] / entry["live_rows"], 4)
     return {
         "apply": dict(sorted(apply.items())),
+        "health_signals": dict(sorted(signals.items())),
         "phases": {p: dict(sorted(v.items())) for p, v in
                    sorted(phases.items())},
         "dispatches_by_impl": dict(sorted(total_by_impl.items())),
