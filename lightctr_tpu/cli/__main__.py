@@ -113,6 +113,12 @@ def build_parser() -> argparse.ArgumentParser:
     sp.add_argument("--max-len", type=int, default=128)
     sp.add_argument("--full-batch", action="store_true")
 
+    # sequence language model: lines of "id id id ..." (one document a
+    # line), packed into sequences; the hybrid linear-attention MoE model
+    # of models/kimi_linear.py at its tiny preset
+    sp = common(sub.add_parser("seqlm"), lr=0.05, batch=1)
+    sp.add_argument("--seq-len", type=positive_int, default=64)
+
     # word2vec on raw text (TEST_EMB pipeline: train -> quantize -> cluster)
     sp = common(sub.add_parser("embed"), lr=0.3, batch=256)
     sp.add_argument("--dim", type=int, default=100)
@@ -383,6 +389,52 @@ def main(argv=None) -> int:
             # same policy as the libFFM loader)
             evb["seq_ids"] = (evb["seq_ids"] % vocab).astype(np.int32)
             report["eval"] = tr.evaluate(evb)
+        if args.ckpt_dir:
+            from lightctr_tpu import ckpt
+
+            report["checkpoint"] = ckpt.save(args.ckpt_dir, args.epochs, {
+                "params": tr.params, "opt_state": tr.opt_state,
+            })
+
+    elif args.model == "seqlm":
+        import dataclasses
+
+        from lightctr_tpu import obs
+        from lightctr_tpu.data import ingest
+        from lightctr_tpu.models import kimi_linear
+        from lightctr_tpu.models.sparse_trainer import SparseTableCTRTrainer
+
+        with open(args.data) as f:
+            docs = [[int(tok) for tok in line.split()]
+                    for line in f if line.strip()]
+        if any(t < 0 for d in docs for t in d):
+            raise ValueError(f"{args.data}: negative token id")
+        rows = ingest.pack_documents(docs, args.seq_len)
+        spec = dataclasses.replace(
+            kimi_linear.Spec(), vocab=int(rows["fids"].max()) + 1)
+        params, logits = kimi_linear.build(jax.random.PRNGKey(args.seed), spec)
+        tr = SparseTableCTRTrainer(
+            params, logits, cfg.replace(loss="softmax_xent"),
+            sparse_tables={"embed": ["tokens"]})
+        tr.telemetry = obs.MetricsRegistry()
+        n, b = len(rows["labels"]), cfg.minibatch_size
+        losses = []
+        for _ in range(args.epochs):
+            for i in range(0, max(n - b, 0) + 1, b):      # whole batches
+                losses.append(float(tr.train_step(ingest.sequence_batch(
+                    {k: v[i:i + b] for k, v in rows.items()}))))
+        tr.flush_health()
+        counters = tr.telemetry.snapshot()["counters"]
+
+        def total(name):
+            return sum(v for k, v in counters.items() if k.startswith(name))
+
+        report.update(
+            documents=len(docs), sequences=n, vocab=spec.vocab,
+            first_loss=losses[0], final_loss=losses[-1],
+            tokens=int(total("trainer_seq_tokens_total")),
+            held_share=total("trainer_moe_held_assignments_total")
+            / max(1.0, total("trainer_moe_assignments_total")))
         if args.ckpt_dir:
             from lightctr_tpu import ckpt
 

@@ -48,6 +48,12 @@ class TrainConfig:
     compute_dtype: str = "float32"
     # PRNG seed.
     seed: int = 0
+    # What the CTR trainers minimise: "logistic" (binary log-loss of one
+    # score a row against ``batch["labels"]``) or "softmax_xent" (the
+    # model's logits are [B, T, V]; mean over the positions
+    # ``batch["target_mask"]`` marks of the softmax cross-entropy against
+    # ``batch["targets"]``: next-token / next-item prediction).
+    loss: str = "logistic"
 
     @property
     def sparse_rate(self) -> float:

@@ -50,7 +50,7 @@ import queue as queue_mod
 import struct
 import threading
 import time
-from typing import Dict, Iterable, Iterator, Optional, Tuple
+from typing import Dict, Iterable, Iterator, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -933,6 +933,52 @@ def as_arrays(source, max_nnz: Optional[int] = None, **compile_kw
 
 
 # -- prefetch pipeline --------------------------------------------------------
+
+
+def pack_documents(docs: Sequence[Sequence[int]], seq_len: int
+                   ) -> Dict[str, np.ndarray]:
+    """Documents (lists of token ids) laid end to end in arrival order and
+    cut at every ``seq_len``-th token, as rows in the ingest's own columns:
+    ``fids`` the token ids, ``fields`` the document's number within its row
+    (a document the cut divides starts a new one in the next row), ``mask``
+    0 on the last row's unfilled tail.  :func:`sequence_batch` turns such
+    rows into a sequence model's batch."""
+    if seq_len < 2:
+        raise ValueError("seq_len must be at least 2")
+    flat = np.fromiter((t for d in docs for t in d), np.int64)
+    if flat.size == 0:
+        raise ValueError("no tokens to pack")
+    doc_of = np.repeat(np.arange(len(docs)), [len(d) for d in docs])
+    rows = -(-flat.size // seq_len)
+    fids = np.zeros((rows, seq_len), np.int32)
+    fields = np.zeros((rows, seq_len), np.int32)
+    mask = np.zeros((rows, seq_len), np.float32)
+    fids.reshape(-1)[:flat.size] = flat
+    mask.reshape(-1)[:flat.size] = 1.0
+    fields.reshape(-1)[:flat.size] = doc_of
+    fields -= fields[:, :1]
+    return {"fids": fids, "fields": fields, "mask": mask,
+            "vals": mask.copy(), "labels": np.zeros(rows, np.float32)}
+
+
+def sequence_batch(batch: Dict[str, np.ndarray]) -> Dict[str, np.ndarray]:
+    """A batch of packed rows (``fids`` token ids, ``fields`` the document's
+    number, ``mask``: :func:`pack_documents`, or shards compiled from
+    ``label doc:token:1 ...`` lines) as a sequence model's batch:
+    ``tokens`` and ``segment_ids`` [B, T] int32, ``targets`` the next token
+    of the same document and ``target_mask`` 1 where there is one (not at
+    a document's last token, the row's last, or before padding).  Padding
+    is a document of its own (``segment_ids`` -1)."""
+    with trace_mod.span("ingest/pack"):
+        real = np.asarray(batch["mask"]) > 0
+        tokens = np.where(real, batch["fids"], 0).astype(np.int32)
+        seg = np.where(real, batch["fields"], -1).astype(np.int32)
+        has = np.zeros(tokens.shape, bool)
+        has[:, :-1] = (seg[:, 1:] == seg[:, :-1]) & real[:, 1:]
+        targets = np.zeros_like(tokens)
+        targets[:, :-1] = tokens[:, 1:]
+        return {"tokens": tokens, "segment_ids": seg,
+                "targets": targets * has, "target_mask": has.astype(np.float32)}
 
 
 def prefetch_batches(

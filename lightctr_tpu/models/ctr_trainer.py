@@ -58,6 +58,24 @@ def _health_pack(loss, grad_norm):
     ])
 
 
+#: what ``TrainConfig.loss`` may say
+LOSSES = ("logistic", "softmax_xent")
+#: the integers the softmax loss counts itself, ahead of the model's own
+#: (``logits_fn.step_counts``): positions, positions with a target, and
+#: packed documents, of one step's batch
+SEQ_COUNTS = ("trainer_seq_tokens_total", "trainer_seq_targets_total",
+              "trainer_seq_documents_total")
+
+
+def softmax_count_names(logits_fn) -> tuple:
+    """``((name, labels), ...)`` of the int32 vector a softmax loss
+    function returns beside the loss: :data:`SEQ_COUNTS`, then what the
+    model's ``logits_fn.step_counts`` names (``models/kimi_linear.py``:
+    the expert layers' assignments)."""
+    return (tuple((name, {}) for name in SEQ_COUNTS)
+            + tuple(getattr(logits_fn, "step_counts", ())))
+
+
 class CompressedRingState(NamedTuple):
     """Optimizer state of the wire-compressed data-parallel path: the inner
     optax state (replicated) plus the per-replica EF-SGD residual carry
@@ -148,6 +166,9 @@ class CTRTrainer:
         device: Optional[bool] = None,
     ):
         self.cfg = cfg
+        if cfg.loss not in LOSSES:
+            raise ValueError(f"TrainConfig.loss must be one of {LOSSES}, "
+                             f"got {cfg.loss!r}")
         self.logits_fn = logits_fn
         self.l2_fn = l2_fn
         self.fused_fn = fused_fn
@@ -237,6 +258,13 @@ class CTRTrainer:
         # syncs the in-flight step.  Static at trace time: unarmed
         # trainers keep the exact PR-4 health payload.
         self._quality_bins = quality_mod.resolve_bins(quality_bins)
+        if cfg.loss != "logistic" and (
+                self._quality_bins is not None or zero_sharded
+                or compress_bits is not None):
+            raise ValueError(
+                f"loss={cfg.loss!r} runs on the plain one-program step: the "
+                "quality sketch scores binary labels, and the compressed "
+                "ring and the sharded update build the logistic loss")
         self.quality: Optional[quality_mod.QualityTracker] = None
         if self._quality_bins is not None:
             self.quality = quality_mod.QualityTracker(
@@ -321,6 +349,11 @@ class CTRTrainer:
         return params
 
     def _make_loss_fn(self, with_probs: bool = False):
+        """``(params, batch) -> loss`` (``(loss, probs)`` with
+        ``with_probs``) of the logistic loss; of ``loss="softmax_xent"``
+        always ``(loss, counts)``: :meth:`_make_softmax_loss_fn`."""
+        if self.cfg.loss == "softmax_xent":
+            return self._make_softmax_loss_fn()
         lambda_l2 = self.cfg.lambda_l2
         l2_fn = self.l2_fn
         logits_fn = self.logits_fn
@@ -344,14 +377,56 @@ class CTRTrainer:
 
         return loss_fn
 
+    def _make_softmax_loss_fn(self):
+        """``(params, batch) -> (loss, counts)``: the mean, over the
+        positions ``batch["target_mask"]`` marks, of the softmax
+        cross-entropy of ``batch["targets"]`` under the model's ``[B, T,
+        V]`` logits (plus ``lambda_l2 * l2_fn`` over their number, as the
+        logistic loss adds it).  ``counts`` is the int32 vector
+        :func:`softmax_count_names` names: the batch's positions, targets
+        and documents (``batch["segment_ids"]`` where the batch is packed)
+        and, where ``logits_fn`` returns ``(logits, counts)``, the
+        model's."""
+        lambda_l2, l2_fn, logits_fn = self.cfg.lambda_l2, self.l2_fn, self.logits_fn
+
+        def loss_fn(params, batch):
+            out = logits_fn(params, batch)
+            z, model_counts = out if isinstance(out, tuple) else (out, None)
+            with annotate("seq/head_loss"):
+                mask = batch["target_mask"].astype(z.dtype)
+                picked = jnp.take_along_axis(
+                    z, batch["targets"][..., None], axis=-1)[..., 0]
+                loss = jnp.sum((jax.nn.logsumexp(z, axis=-1) - picked) * mask)
+                n = jnp.maximum(jnp.sum(mask), 1.0)
+            if lambda_l2 > 0.0 and l2_fn is not None:
+                loss = loss + lambda_l2 * l2_fn(params, batch)
+            docs = mask.shape[0]
+            if "segment_ids" in batch:
+                seg = batch["segment_ids"]
+                docs = docs + jnp.sum(seg[:, 1:] != seg[:, :-1])
+            counts = jnp.stack([jnp.int32(mask.size),
+                                jnp.sum(mask).astype(jnp.int32),
+                                jnp.asarray(docs, jnp.int32)])
+            if model_counts is not None:
+                counts = jnp.concatenate([counts, model_counts])
+            return loss / n, counts
+
+        return loss_fn
+
     def _make_grad_fn(self):
         """``(params, batch) -> (loss, probs, grads)``; ``probs`` is the
         aux predicted probabilities when the quality sketch is armed,
         else None — one builder so every step variant gets the same
-        arming rule."""
+        arming rule.  (The softmax loss's counts are the sparse trainer's
+        to carry; here they are dropped.)"""
         armed = self._quality_bins is not None
         loss_fn = self._make_loss_fn(with_probs=armed)
-        if armed:
+        if self.cfg.loss == "softmax_xent":
+            def grad_fn(params, batch):
+                (loss, _), grads = jax.value_and_grad(
+                    loss_fn, has_aux=True)(params, batch)
+                return loss, None, grads
+        elif armed:
             def grad_fn(params, batch):
                 (loss, probs), grads = jax.value_and_grad(
                     loss_fn, has_aux=True)(params, batch)

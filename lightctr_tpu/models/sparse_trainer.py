@@ -126,7 +126,8 @@ from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from lightctr_tpu import obs
-from lightctr_tpu.models.ctr_trainer import CTRTrainer, _health_pack
+from lightctr_tpu.models.ctr_trainer import (CTRTrainer, _health_pack,
+                                              softmax_count_names)
 from lightctr_tpu.obs import device as obs_device
 from lightctr_tpu.obs import health as health_mod
 from lightctr_tpu.obs import quality as quality_mod
@@ -228,13 +229,18 @@ class _StepCounts:
     where ``shards`` (``{table: shards}``) names the table — the live
     slots of the apply's plan, the ``branch`` its switches took and, for
     a lane-packed table, the lane rows it wrote
-    (``sparse_kernels.apply_counts``).  All static but the values: built
+    (``sparse_kernels.apply_counts``); last, where the loss is the
+    softmax cross-entropy, the integers its loss function counts
+    (``model``: ``ctr_trainer.softmax_count_names``, each a counter the
+    host adds the step's value to).  All static but the values: built
     with the step, from what the step is built from."""
 
     def __init__(self, spec, vocab: Dict[str, int], shards: Dict[str, int],
-                 lane_pack: Dict[str, int]):
+                 lane_pack: Dict[str, int], model: tuple = ()):
         self._groups = SparseTableCTRTrainer._field_groups(spec)
         self._vocab = vocab
+        self.model = tuple(obs.labeled(name, **labels)
+                           for name, labels in model)
         # (table, rows a lane row holds, the labels of its counters a shard)
         self._apply = [
             (k, lane_pack.get(k, 1),
@@ -243,9 +249,10 @@ class _StepCounts:
             for k in spec]
         #: f32 slots of the vector the counts take: two an integer
         self.width = 2 * (2 * len(self._groups) + sum(
-            (2 + (r > 1)) * len(per) for _, r, per in self._apply))
+            (2 + (r > 1)) * len(per) for _, r, per in self._apply)
+            + len(self.model))
 
-    def pack(self, distinct, uids, batch):
+    def pack(self, distinct, uids, batch, model_counts=None):
         """The ``width`` slots, inside the step: ``distinct`` is
         ``_dedup_and_gather``'s ``{field_tuple: count}``, ``uids`` its
         ``{table: uids}``.  Each shard's plan is made from the replicated
@@ -261,17 +268,26 @@ class _StepCounts:
             counts += [sparse_kernels.apply_counts(
                 uids[k], rows, i * rows if len(per) > 1 else None, r)
                 for i in range(len(per))]
+        if self.model:
+            counts.append(model_counts)
         return _pack_counts(jnp.concatenate(counts))
 
-    def read(self, vals: np.ndarray, reg) -> Dict:
-        """``vals``: the vector's ``width`` count slots.  Increments the
-        apply's counters on ``reg`` — live rows, the slots of the rung
-        the device's switch took (the ladder's, or all K on the
-        undeclared branch), lane rows — and returns the skew detector's
+    def read(self, vals: np.ndarray, reg, tables: bool = True) -> Dict:
+        """``vals``: the vector's ``width`` count slots.  Adds the
+        model's counts to their counters on ``reg`` and, with ``tables``,
+        increments the apply's — live rows, the slots of the rung the
+        device's switch took (the ladder's, or all K on the undeclared
+        branch), lane rows — and returns the skew detector's
         ``table_touch``."""
         from lightctr_tpu.ops import sparse_kernels
 
-        ints = iter(_unpack_counts(vals))
+        ints = _unpack_counts(vals)
+        if self.model:
+            for name, value in zip(self.model, ints[-len(self.model):]):
+                reg.inc(name, value)
+        if not tables:
+            return {}
+        ints = iter(ints)
         touch = {}
         for tables in self._groups.values():
             unique, ids = next(ints), next(ints)
@@ -394,6 +410,11 @@ class SparseTableCTRTrainer(CTRTrainer):
                     "'q4_ef'); compress_bits must stay None"
                 )
             self._hybrid_dp = False
+        if cfg.loss != "logistic" and (self._hybrid_dp or self._hier):
+            raise ValueError(
+                f"loss={cfg.loss!r} runs on the one-program step (one device, "
+                "or a mesh with param_shardings): the hybrid and the "
+                "hierarchical exchange build the logistic loss")
         # the hybrid and the hierarchical steps keep logical tables
         if not (self._hybrid_dp or self._hier):
             self._plan_lane_pack(param_shardings)
@@ -751,10 +772,14 @@ class SparseTableCTRTrainer(CTRTrainer):
         dedup_and_gather = self._dedup_and_gather
         mesh, row_shards = self.mesh, self._row_shards()
         lane_pack = self._lane_pack
+        # the softmax loss returns its counts beside the loss, as the
+        # quality sketch's probabilities ride (never both: the ctor)
+        seq = self.cfg.loss == "softmax_xent"
         layout = _StepCounts(
             spec, {k: self._table_shapes[k][0] for k in spec},
             {k: mesh.shape[axis] for k, axis in row_shards.items()},
-            lane_pack)
+            lane_pack,
+            softmax_count_names(self.logits_fn) if seq else ())
         if not self._hier:
             # (the hier trainer builds this program and never runs it)
             self._step_counts = layout
@@ -790,15 +815,16 @@ class SparseTableCTRTrainer(CTRTrainer):
             def loss_on(rows, dense):
                 return loss_fn({**dense, **rows}, batch2)
 
-            if armed:
-                (loss, probs), (g_rows, g_dense) = jax.value_and_grad(
+            if armed or seq:
+                (loss, aux), (g_rows, g_dense) = jax.value_and_grad(
                     loss_on, argnums=(0, 1), has_aux=True
                 )(rows, dense)
+                probs, model_counts = (aux, None) if armed else (None, aux)
             else:
                 loss, (g_rows, g_dense) = jax.value_and_grad(
                     loss_on, argnums=(0, 1)
                 )(rows, dense)
-                probs = None
+                probs = model_counts = None
             # grad global norm over touched rows + dense leaves: the
             # health scalar (one reduction; fetched only when monitored)
             gnorm = optax.global_norm((g_rows, g_dense))
@@ -818,7 +844,8 @@ class SparseTableCTRTrainer(CTRTrainer):
             params = {**dense, **tables}
             health = self._append_sketch(
                 jnp.concatenate([_health_pack(loss, gnorm),
-                                 layout.pack(distinct, uids, batch)]),
+                                 layout.pack(distinct, uids, batch,
+                                             model_counts)]),
                 probs, batch2)
             return (params, {"dense": new_dense_state, "accum": new_accum},
                     loss, health)
@@ -1781,6 +1808,8 @@ class SparseTableCTRTrainer(CTRTrainer):
                                                      self.telemetry)
                 self.telemetry.inc(obs.labeled(
                     "trainer_health_signals_total", source="device"))
+            elif counts.model:
+                counts.read(vals[2:head], self.telemetry, tables=False)
         else:
             head = 3
             if vals.shape[0] > 2 and vals[2] > 0:

@@ -166,8 +166,13 @@ def compile_step(cfg: Dict, mesh_axes: Optional[Dict[str, int]] = None):
     jax.config.update("jax_default_matmul_precision", cfg["matmul_precision"])
     model = importlib.import_module("benchmarks.models." + cfg["model"])
     key = jax.random.PRNGKey(0)
-    tiny = dict(cfg, vocab=1024)
-    trainer = model.build_trainer(tiny, model.init_params(tiny, key))
+    if hasattr(model, "aot_trainer"):
+        # a model whose dense leaves are GBs too builds its trainer small
+        # and hands over the step of the real sizes
+        trainer = model.aot_trainer(cfg)
+    else:
+        tiny = dict(cfg, vocab=1024)
+        trainer = model.build_trainer(tiny, model.init_params(tiny, key))
 
     topo = topologies.get_topology_desc(platform="tpu", topology_name=TOPOLOGY)
     specs = model.param_specs(cfg)
@@ -207,12 +212,14 @@ def compile_step(cfg: Dict, mesh_axes: Optional[Dict[str, int]] = None):
         shapes[k] = jax.ShapeDtypeStruct((v // r, r * d), shapes[k].dtype)
     params = jax.tree_util.tree_map(
         struct, shapes, specs, is_leaf=lambda x: isinstance(x, tuple))
-    state = trainer._opt_state
-    tables = list(state["accum"])
-    opt = {"dense": jax.tree_util.tree_map(struct, state["dense"]),
+    tables = list(trainer._opt_state["accum"])
+    dense = jax.eval_shape(
+        trainer.tx.init, {k: v for k, v in shapes.items() if k not in tables})
+    opt = {"dense": jax.tree_util.tree_map(struct, dense),
            "accum": {k: params[k] for k in tables}}
+    host_batch = getattr(model, "aot_batch", _host_batch)(cfg)
     batch = {k: struct(v, batch_axes)
-             for k, v in model.feed_layout(cfg, _host_batch(cfg)).items()}
+             for k, v in model.feed_layout(cfg, host_batch).items()}
     compiled = jax.jit(step, donate_argnums=(0, 1)).lower(
         params, opt, batch).compile()
     per_device = [(shapes[k].shape[0] // shards,) + shapes[k].shape[1:]
@@ -261,7 +268,7 @@ def main(argv=None) -> int:
     except Exception as e:  # the compiler's refusal is the finding
         if "RESOURCE_EXHAUSTED" not in str(e) and "hbm" not in str(e).lower():
             raise
-        print(f"COMPILE FAILED: {str(e)[:2000]}", file=sys.stderr)
+        print(f"COMPILE FAILED: {str(e)[:12000]}", file=sys.stderr)
         return 1
     text = compiled.as_text()
     if args.hlo:

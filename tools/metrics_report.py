@@ -792,6 +792,49 @@ def summarize_ingest(doc) -> dict:
     return report
 
 
+def summarize_seq(doc, held_experts: int = 0) -> dict:
+    """Registry snapshot (or a stats() dump carrying one under
+    ``telemetry``) -> the sequence tower's report: positions, targets and
+    packed documents the softmax loss counted (``trainer_seq_*_total``,
+    off the step's health vector) and, per routed expert layer, the
+    assignments the router made, the share of them that went to experts
+    this chip holds (``held_share``), and the load of the busiest held
+    expert against the mean (``max_over_mean``:
+    ``trainer_moe_expert_tokens_max`` is a step's maximum summed over
+    steps, the mean is the held assignments over ``held_experts``, which
+    the caller states — the counters do not carry it)."""
+    snap = doc.get("telemetry", doc) if isinstance(doc, dict) else doc
+    counters = snap.get("counters", {})
+    report: dict = {}
+    seq = {what: int(counters[f"trainer_seq_{what}_total"])
+           for what in ("tokens", "targets", "documents")
+           if f"trainer_seq_{what}_total" in counters}
+    if seq:
+        if seq.get("documents"):
+            seq["tokens_per_document"] = round(
+                seq.get("tokens", 0) / seq["documents"], 1)
+        report["sequences"] = seq
+    layers: dict = {}
+    for what, name in (("assignments", "trainer_moe_assignments_total"),
+                       ("held", "trainer_moe_held_assignments_total"),
+                       ("max_sum", "trainer_moe_expert_tokens_max")):
+        for key, val in counters.items():
+            if key.startswith(name + "{"):
+                layer = key[len(name) + 1:-1].replace('"', "").split("=", 1)[1]
+                layers.setdefault(layer, {})[what] = int(val)
+    for entry in layers.values():
+        if entry.get("assignments"):
+            entry["held_share"] = round(
+                entry.get("held", 0) / entry["assignments"], 4)
+        most = entry.pop("max_sum", None)
+        if most is not None and held_experts and entry.get("held"):
+            entry["max_over_mean"] = round(
+                most / (entry["held"] / held_experts), 3)
+    if layers:
+        report["moe_layers"] = dict(sorted(layers.items()))
+    return report
+
+
 def summarize_device(doc) -> dict:
     """Registry snapshot (or a stats() dump carrying one under
     ``telemetry``) -> device/compiled-program report
@@ -979,8 +1022,27 @@ def main(argv=None):
                          "overlap-ratio honesty gauge, consumer-wait "
                          "percentiles, prefetch queue fill) from a "
                          "registry snapshot or stats() dump")
+    ap.add_argument("--seq", metavar="SNAPSHOT_JSON",
+                    help="summarize the sequence tower (positions, targets "
+                         "and documents the softmax loss counted; per routed "
+                         "layer the held share of the router's assignments "
+                         "and, with --held-experts, the busiest held "
+                         "expert's load over the mean) from a registry "
+                         "snapshot or stats() dump")
+    ap.add_argument("--held-experts", type=int, default=0,
+                    help="experts this chip holds a layer (for --seq's "
+                         "max_over_mean)")
     args = ap.parse_args(argv)
 
+    if args.seq:
+        with open(args.seq) as f:
+            doc = json.load(f)
+        report = summarize_seq(doc, args.held_experts)
+        print(json.dumps(report, indent=1))
+        if args.out:
+            with open(args.out, "w") as f:
+                json.dump(report, f, indent=1)
+        return 0
     if args.prom:
         with open(args.prom) as f:
             snap = json.load(f)
