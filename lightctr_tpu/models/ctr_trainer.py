@@ -456,10 +456,12 @@ class CTRTrainer:
 
         def step(params, opt_state, batch):
             loss, probs, grads = grad_fn(params, batch)
-            health = self._append_sketch(
-                _health_pack(loss, optax.global_norm(grads)), probs, batch)
-            updates, opt_state = tx.update(grads, opt_state, params)
-            params = optim_lib.apply_updates(params, updates)
+            with annotate("step/update"):
+                health = self._append_sketch(
+                    _health_pack(loss, optax.global_norm(grads)), probs,
+                    batch)
+                updates, opt_state = tx.update(grads, opt_state, params)
+                params = optim_lib.apply_updates(params, updates)
             return params, opt_state, loss, health
 
         return step
@@ -690,10 +692,17 @@ class CTRTrainer:
         t0 = time.perf_counter()
         sw = self.stepwatch
         with span("trainer/step", step=self._steps_seen + 1):
-            with span("trainer/input"):
+            with span("trainer/input") as sp:
                 if sw is not None:
                     sw.mark("input")
                 dev_batch = batch if device_ready else self._put(batch)
+                if sp is not None:
+                    # what crossed the boundary, counted only for a span
+                    # that is recorded
+                    sp.set(arrays=len(batch),
+                           bytes=sum(v.nbytes for v in batch.values()),
+                           devices=1 if self.mesh is None
+                           else self.mesh.devices.size)
             with span("trainer/exec"):
                 # dispatch of the jitted step, and any wait inside it (the
                 # runtime blocks the host once its queue of dispatched

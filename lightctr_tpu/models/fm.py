@@ -26,6 +26,8 @@ from typing import Dict
 import jax
 import jax.numpy as jnp
 
+from lightctr_tpu.ops.sparse_kernels import expand_rows
+
 
 def init(key: jax.Array, feature_cnt: int, factor_cnt: int) -> Dict[str, jax.Array]:
     """W zero-init, V ~ N(0, 1/k) (fm_algo_abst.h:53-67)."""
@@ -47,9 +49,9 @@ def logits_with_l2(params: Dict[str, jax.Array], batch: Dict[str, jax.Array]):
     step on a bandwidth-bound backend)."""
     vals = batch["vals"] * batch["mask"]          # [B, P]; padding already 0
     mask = batch["mask"]
-    w = jnp.take(params["w"], batch["fids"], axis=0)            # [B, P]
+    w = expand_rows(params["w"], batch["fids"])                 # [B, P]
     linear = jnp.sum(w * vals, axis=-1)                          # [B]
-    v = jnp.take(params["v"], batch["fids"], axis=0)             # [B, P, k]
+    v = expand_rows(params["v"], batch["fids"])                  # [B, P, k]
     vx = v * vals[..., None]                                     # [B, P, k]
     sumvx = jnp.sum(vx, axis=1)                                  # [B, k]
     second = 0.5 * (
@@ -132,8 +134,8 @@ def l2_penalty(params: Dict[str, jax.Array], batch: Dict[str, jax.Array]) -> jax
     ``L2Reg_ratio * W[fid]`` per occurrence (train_fm_algo.cpp:108-115) rather
     than decaying the whole table."""
     vals_mask = batch["mask"]
-    w = jnp.take(params["w"], batch["fids"], axis=0)
-    v = jnp.take(params["v"], batch["fids"], axis=0)
+    w = expand_rows(params["w"], batch["fids"])
+    v = expand_rows(params["v"], batch["fids"])
     return 0.5 * (
         jnp.sum(w * w * vals_mask) + jnp.sum(v * v * vals_mask[..., None])
     )

@@ -35,6 +35,7 @@ import jax
 import jax.numpy as jnp
 
 from lightctr_tpu.nn import kda, mla, moe
+from lightctr_tpu.ops.sparse_kernels import expand_rows
 from lightctr_tpu.utils.profiling import annotate
 
 
@@ -196,7 +197,7 @@ def make_logits(s: Spec):
 
     def logits(params: Dict, batch: Dict[str, jax.Array]):
         seg = batch["segment_ids"]
-        x = jnp.take(params["embed"], batch["tokens"], axis=0)
+        x = expand_rows(params["embed"], batch["tokens"])
         stats = []
         for i, kind in enumerate(s.mixers, 1):
             x, st = jax.checkpoint(_layer, static_argnums=(0, 1, 2))(

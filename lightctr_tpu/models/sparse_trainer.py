@@ -784,22 +784,24 @@ class SparseTableCTRTrainer(CTRTrainer):
 
         with annotate("sparse_tables/dedup_gather", tables=len(spec),
                       id_streams=len(groups)):
-            for fields, keys in groups.items():
-                ids = jnp.concatenate(
-                    [batch[f].reshape(-1) for f in fields]
-                ).astype(jnp.int32)
-                u, inv, counts[fields] = sparse_kernels.dedup_ids(ids)
-                for k in keys:
-                    uids[k] = u
-                ofs = 0
-                for f in fields:
-                    m = batch[f].size
-                    batch2[f] = inv[ofs:ofs + m].reshape(batch[f].shape)
-                    ofs += m
+            with annotate("dedup_ids"):
+                for fields, keys in groups.items():
+                    ids = jnp.concatenate(
+                        [batch[f].reshape(-1) for f in fields]
+                    ).astype(jnp.int32)
+                    u, inv, counts[fields] = sparse_kernels.dedup_ids(ids)
+                    for k in keys:
+                        uids[k] = u
+                    ofs = 0
+                    for f in fields:
+                        m = batch[f].size
+                        batch2[f] = inv[ofs:ofs + m].reshape(batch[f].shape)
+                        ofs += m
             # the forward gather reads the live prefix of the slots, on
             # the apply's ladder; the rows behind the rung are zeros, and
             # ``inv`` never points past the live prefix
-            rows = {k: gather(k) for k in spec}
+            with annotate("gather_rows"):
+                rows = {k: gather(k) for k in spec}
         return tables, dense, batch2, uids, rows, counts
 
     def _make_step(self):
@@ -865,14 +867,16 @@ class SparseTableCTRTrainer(CTRTrainer):
                     loss_on, argnums=(0, 1)
                 )(rows, dense)
                 probs = model_counts = None
-            # grad global norm over touched rows + dense leaves: the
-            # health scalar (one reduction; fetched only when monitored)
-            gnorm = optax.global_norm((g_rows, g_dense))
+            with annotate("step/update"):
+                # grad global norm over touched rows + dense leaves: the
+                # health scalar (one reduction; fetched only when monitored)
+                gnorm = optax.global_norm((g_rows, g_dense))
 
-            updates, new_dense_state = tx.update(g_dense, opt_state["dense"], dense)
-            dense = jax.tree_util.tree_map(
-                lambda p, u: p + u.astype(p.dtype), dense, updates
-            )
+                updates, new_dense_state = tx.update(
+                    g_dense, opt_state["dense"], dense)
+                dense = jax.tree_util.tree_map(
+                    lambda p, u: p + u.astype(p.dtype), dense, updates
+                )
 
             new_accum = {}
             with annotate("sparse_tables/apply"):
@@ -884,11 +888,13 @@ class SparseTableCTRTrainer(CTRTrainer):
                         new_accum[k] = accum
 
             params = {**dense, **tables}
-            health = self._append_sketch(
-                jnp.concatenate([_health_pack(loss, gnorm),
-                                 layout.pack(distinct, uids, batch,
-                                             model_counts)]),
-                probs, batch2)
+            # (the scope a second time: the step's ops keep their order)
+            with annotate("step/update"):
+                health = self._append_sketch(
+                    jnp.concatenate([_health_pack(loss, gnorm),
+                                     layout.pack(distinct, uids, batch,
+                                                 model_counts)]),
+                    probs, batch2)
             return (params, {"dense": new_dense_state, "accum": new_accum},
                     loss, health)
 

@@ -30,6 +30,7 @@ import numpy as np
 
 from lightctr_tpu.nn import dense
 from lightctr_tpu.ops.activations import sigmoid
+from lightctr_tpu.ops.sparse_kernels import expand_rows
 
 
 def field_representatives(
@@ -80,10 +81,10 @@ def init(
 
 def logits(params: Dict[str, jax.Array], batch: Dict[str, jax.Array]) -> jax.Array:
     vals = batch["vals"] * batch["mask"]
-    w = jnp.take(params["w"], batch["fids"], axis=0)
+    w = expand_rows(params["w"], batch["fids"])
     wide = jnp.sum(w * vals, axis=-1)
 
-    emb = jnp.take(params["embed"], batch["rep_fids"], axis=0)   # [B, Fl, D]
+    emb = expand_rows(params["embed"], batch["rep_fids"])        # [B, Fl, D]
     emb = emb * batch["rep_mask"][..., None]                      # absent fields -> 0
     deep_in = emb.reshape(emb.shape[0], -1)                       # [B, Fl*D]
     h = dense.apply(params["fc1"], deep_in, activation=jnp.tanh)

@@ -24,6 +24,8 @@ runs, and every other backend with it:
     scatter once a table (docs/KERNELS.md "The fused store").
   - :func:`gather_rows` — ``block[idx]``, the read half of the
     device-resident row path (tiered store, serving cache).
+  - :func:`expand_rows` — ``rows[ids]`` a position of the batch, the
+    models' read of a table leaf, under the ``model/expand`` scope.
   - :func:`quantize_pack` / :func:`quantize_pack_ef` /
     :func:`quantize_pack_ef_update` — quantile-codec payload packing (the
     wire codes of ``ops.quantize``) with the error-feedback residual.
@@ -59,6 +61,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from lightctr_tpu import obs
+from lightctr_tpu.utils.profiling import annotate
 
 #: the phases a registered kernel may declare (the ``phase`` label of
 #: ``trainer_kernel_path_total``); metrics_report --kernels groups by these
@@ -677,6 +680,17 @@ def gather_rows(block: jax.Array, idx: jax.Array):
     # mode="clip" explicitly: jnp.take's DEFAULT out-of-range mode is
     # "fill" (NaN rows)
     return jnp.take(block, idx, axis=0, mode="clip")
+
+
+def expand_rows(rows: jax.Array, ids: jax.Array):
+    """``rows[ids]`` a position of the batch — how a model reads a table
+    leaf (under the sparse trainer the dedup's gathered rows, ``ids``
+    their positions).  The one place the ``model/expand`` scope is
+    opened: the take, its transpose's scatter-add and, on a mesh, the
+    all-reduce that transpose feeds are the step's ``expand`` phase
+    (docs/OBSERVABILITY.md, "The phases of the step")."""
+    with annotate("model/expand"):
+        return jnp.take(rows, ids, axis=0)
 
 
 # =========================================================================

@@ -8,7 +8,9 @@ The cache's directory is part of its key, so it must not move between
 runs: where ``JAX_COMPILATION_CACHE_DIR`` is set JAX already uses it and
 this module sets no directory at all; otherwise the cache lives at
 ``<checkout>/.jax_cache`` (git-ignored), derived from this file's own
-location.
+location.  The key of an entry covers the program's metadata too — name
+stacks and source locations: an executable carries the name stacks it
+was compiled with.
 """
 
 from __future__ import annotations
@@ -42,4 +44,12 @@ def configure_compile_cache() -> str:
     if path is not None:
         jax.config.update("jax_compilation_cache_dir", path)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    # the key covers the operations' name stacks: they are what a device
+    # trace is read by (the step's phase scopes, ``seq/*``), and JAX's
+    # default key leaves them out, so a step whose scopes alone changed
+    # would run the cached executable and carry the old names.  The flag
+    # keeps ALL debug info in the key, source files and line numbers too:
+    # an edit that shifts a line of a traced file, or a moved checkout,
+    # compiles again
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     return jax.config.jax_compilation_cache_dir
