@@ -413,6 +413,21 @@ class CTRTrainer:
 
         return loss_fn
 
+    def _replica_share(self, batch, axis: str):
+        """Inside a ``shard_map`` over the mesh axis ``axis``, ``batch``
+        the member's own rows: what the member's loss (a mean over its own
+        rows, as :meth:`_make_loss_fn` returns it) is multiplied by so that
+        the ``psum`` over ``axis`` is the loss of the whole batch.  The
+        logistic loss divides by its rows, which the members hold in equal
+        shares (a power of two of them: the rescale is exact); the softmax
+        loss by the positions ``target_mask`` marks, or 1 where there are
+        none, which they do not."""
+        if self.cfg.loss == "softmax_xent":
+            own = jnp.sum(batch["target_mask"].astype(jnp.float32))
+            return (jnp.maximum(own, 1.0)
+                    / jnp.maximum(jax.lax.psum(own, axis), 1.0))
+        return 1.0 / jax.lax.axis_size(axis)
+
     def _make_grad_fn(self):
         """``(params, batch) -> (loss, probs, grads)``; ``probs`` is the
         aux predicted probabilities when the quality sketch is armed,

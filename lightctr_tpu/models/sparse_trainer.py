@@ -35,8 +35,11 @@ batches, and PS-style ``param_shardings`` (tables row-sharded over the
 rung of the ladder its own rows need, inside a ``shard_map`` over that
 axis, and one ``psum`` joins the gathered rows — the reference's
 worker→PS-shard pull/push topology, pull.h:50-99 /
-distributed_algo_abst.h:176-280; the rest of the step stays one GSPMD
-program).
+distributed_algo_abst.h:176-280; where the mesh's ``data`` axis holds more
+than one replica the loss and its gradient run a replica inside a
+``shard_map`` and one flat ``psum`` at the live prefix's rung joins the
+replicas' row gradients, ``sparse_kernels.join_live``; the rest of the
+step stays one GSPMD program).
 
 Multi-device replicated data parallelism (``mesh`` given, no
 ``param_shardings``) runs an EXPLICIT hybrid exchange instead of letting
@@ -126,6 +129,7 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 from jax import shard_map
+from jax.flatten_util import ravel_pytree
 from jax.sharding import PartitionSpec as P
 
 from lightctr_tpu import obs
@@ -242,9 +246,13 @@ class _StepCounts:
     with the step, from what the step is built from."""
 
     def __init__(self, spec, vocab: Dict[str, int], shards: Dict[str, int],
-                 lane_pack: Dict[str, int], model: tuple = ()):
+                 lane_pack: Dict[str, int], model: tuple = (),
+                 joins: Optional[Dict[str, tuple]] = None):
         self._groups = SparseTableCTRTrainer._field_groups(spec)
         self._vocab = vocab
+        # {table: (bytes a row, the policies of the joins the mesh step
+        # makes of the table's [K, ...] rows)} (``sparse_kernels.join_live``)
+        self._joins = joins or {}
         self.model = tuple(obs.labeled(name, **labels)
                            for name, labels in model)
         # (table, its lane_pack r — over 1: a fused store —, the labels of
@@ -286,7 +294,11 @@ class _StepCounts:
         device's switch took (the ladder's, or all K on the undeclared
         branch), those slots once a scatter the apply makes there (one
         into a fused store, else table and accumulator), lane rows — and
-        returns the skew detector's ``table_touch``."""
+        the bytes a member hands each join of the mesh step's
+        (``trainer_exchange_bytes_total``: the slots of the rung the whole
+        live prefix takes, from the stream's distinct count, times the
+        bytes of a row), and returns the skew detector's
+        ``table_touch``."""
         from lightctr_tpu.ops import sparse_kernels
 
         ints = _unpack_counts(vals)
@@ -315,6 +327,13 @@ class _StepCounts:
                 if r > 1:
                     reg.inc(obs.labeled("trainer_apply_lane_rows_total",
                                         **labels), next(ints))
+        for k, (row_bytes, policies) in self._joins.items():
+            slots = sparse_kernels.ladder_slots(touch[k]["ids"],
+                                                touch[k]["unique"])
+            for policy in policies:
+                reg.inc(obs.labeled("trainer_exchange_bytes_total",
+                                    table=k, policy=policy),
+                        slots * row_bytes)
         return touch
 
 
@@ -816,11 +835,24 @@ class SparseTableCTRTrainer(CTRTrainer):
         # the softmax loss returns its counts beside the loss, as the
         # quality sketch's probabilities ride (never both: the ctor)
         seq = self.cfg.loss == "softmax_xent"
+        has_aux = armed or seq
+        vocab = {k: self._table_shapes[k][0] for k in spec}
+        # the ``data`` replicas of a mesh hold their own rows of the batch
+        # each: over more than one the step joins their row gradients
+        # itself (``per_replica`` below)
+        data_axis = "data"
+        replicas = 1 if mesh is None else mesh.shape.get(data_axis, 1)
+        # the joins a table's [K, ...] rows cross the mesh by: the sharded
+        # forward gather's, and the row gradients' over ``data``
+        joins = {k: (int(np.prod(self._table_shapes[k][1:]))
+                     * self._params[k].dtype.itemsize,
+                     ("rows_join",) * (k in row_shards)
+                     + ("grad_join",) * (replicas > 1)) for k in spec}
         layout = _StepCounts(
-            spec, {k: self._table_shapes[k][0] for k in spec},
+            spec, vocab,
             {k: mesh.shape[axis] for k, axis in row_shards.items()},
             lane_pack,
-            softmax_count_names(self.logits_fn) if seq else ())
+            softmax_count_names(self.logits_fn) if seq else (), joins)
         if not self._hier:
             # (the hier trainer builds this program and never runs it)
             self._step_counts = layout
@@ -849,24 +881,80 @@ class SparseTableCTRTrainer(CTRTrainer):
                                 out_specs=(P(axis), P(axis)))
             return one(table, accum, u, g)
 
+        def loss_and_grads(rows, dense, batch2, share=None):
+            """``loss, aux, (g_rows, g_dense)`` of the loss over
+            ``batch2`` (``aux``: the armed step's probabilities, the
+            softmax loss's counts, else None), the loss times
+            ``share(batch2)`` where a replica holds a share of the
+            batch."""
+            def loss_on(rows, dense):
+                out = loss_fn({**dense, **rows}, batch2)
+                loss, aux = out if has_aux else (out, None)
+                if share is not None:
+                    loss = loss * share(batch2)
+                return loss, aux
+
+            (loss, aux), grads = jax.value_and_grad(
+                loss_on, argnums=(0, 1), has_aux=True)(rows, dense)
+            return loss, aux, grads
+
+        def per_replica(rows, dense, batch2, uids):
+            """:func:`loss_and_grads` inside a ``shard_map`` over the
+            mesh: ``batch2`` is one ``data`` replica's rows of the batch,
+            everything else replicated (the ``embed`` members of a replica
+            repeat its work).  Each replica differentiates its share of
+            the global loss with respect to its own copy of the gathered
+            rows, so the row gradients leave the model as PARTIAL ``[K,
+            ...]`` sums, and the step joins them itself, flat and at the
+            rung of the live prefix (``sparse_kernels.join_live``; ``inv``
+            never points behind the prefix, so the rows behind it are
+            zeros on every replica) — where GSPMD all-reduces all K rows
+            of an ``[K, 32]`` operand that XLA:TPU pads to 128 lanes
+            (docs/KERNELS.md, "On a mesh").  The join is the expansion's:
+            it sums what the transposes of the model's ``rows[inv]``
+            takes made.  The arithmetic is the one program's: the same
+            partial sums on the same replicas, added over the same axis.
+
+            ``check_vma`` is off: a model's ``lax.scan`` may start its
+            carry from a constant, which the check refuses once the body
+            makes it vary; unchecked, a gradient with respect to a
+            replicated input is the replica's own partial sum, which is
+            what the join takes."""
+            from lightctr_tpu.ops import sparse_kernels
+
+            loss, aux, (g_rows, g_dense) = loss_and_grads(
+                rows, dense, batch2,
+                share=partial(self._replica_share, axis=data_axis))
+            with annotate("model/expand"):
+                g_rows = {k: sparse_kernels.join_live(
+                    g_rows[k], uids[k], data_axis, vocab[k]) for k in spec}
+            with annotate("step/update"):
+                # the loss and the dense gradients cross as one flat vector
+                # (a 2-D operand under 128 lanes would be padded, as above)
+                flat, unravel = ravel_pytree((loss, g_dense))
+                loss, g_dense = unravel(jax.lax.psum(flat, data_axis))
+                if seq:
+                    aux = jax.lax.psum(aux, data_axis)
+            return loss, aux, (g_rows, g_dense)
+
+        if replicas > 1:
+            loss_and_grads_of = shard_map(
+                per_replica, mesh=mesh,
+                in_specs=(P(), P(), P(data_axis), P()),
+                # the probabilities stay with their rows of the batch
+                out_specs=(P(), P(data_axis) if armed else P(), P()),
+                check_vma=False)
+        else:
+            def loss_and_grads_of(rows, dense, batch2, uids):
+                return loss_and_grads(rows, dense, batch2)
+
         def step(params, opt_state, batch):
             tables, dense, batch2, uids, rows, distinct = dedup_and_gather(
                 spec, params, batch, mesh, row_shards, lane_pack
             )
-
-            def loss_on(rows, dense):
-                return loss_fn({**dense, **rows}, batch2)
-
-            if armed or seq:
-                (loss, aux), (g_rows, g_dense) = jax.value_and_grad(
-                    loss_on, argnums=(0, 1), has_aux=True
-                )(rows, dense)
-                probs, model_counts = (aux, None) if armed else (None, aux)
-            else:
-                loss, (g_rows, g_dense) = jax.value_and_grad(
-                    loss_on, argnums=(0, 1)
-                )(rows, dense)
-                probs = model_counts = None
+            loss, aux, (g_rows, g_dense) = loss_and_grads_of(
+                rows, dense, batch2, uids)
+            probs, model_counts = (aux, None) if armed else (None, aux)
             with annotate("step/update"):
                 # grad global norm over touched rows + dense leaves: the
                 # health scalar (one reduction; fetched only when monitored)
@@ -911,7 +999,6 @@ class SparseTableCTRTrainer(CTRTrainer):
         the exchanged ID stream: the id plumbing (gather / owner partition /
         shard merge) runs once per (stream, algo) group and only the first
         table of a group pays the wire id bytes."""
-        from jax.flatten_util import ravel_pytree
         from jax.sharding import PartitionSpec as P
 
         from jax import shard_map
@@ -1252,7 +1339,6 @@ class SparseTableCTRTrainer(CTRTrainer):
         leaves and the loss psum into one flat vector.  Every output is
         replica-identical (terminal collectives), so the shard_map emits
         replicated values the host reads once."""
-        from jax.flatten_util import ravel_pytree
         from jax.sharding import PartitionSpec as P
 
         from jax import shard_map
@@ -1379,7 +1465,6 @@ class SparseTableCTRTrainer(CTRTrainer):
         squares feeding the health gradient norm from the same passes.
         Identical inputs on every host => identical parameters
         everywhere."""
-        from jax.flatten_util import ravel_pytree
 
         tx = self.tx
         spec = self._spec

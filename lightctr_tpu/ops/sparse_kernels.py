@@ -485,6 +485,49 @@ def gather_live(block: jax.Array, idx: jax.Array, branch: jax.Array,
     return jax.lax.switch(branch, _ladder_branches(k, rung), block, idx)
 
 
+def join_live(x: jax.Array, uids: jax.Array, axis_name: str, vocab: int,
+              start=None):
+    """``x`` [K, ...] summed over the mapped mesh axis ``axis_name``,
+    where every member's rows behind the live prefix of ``uids`` are
+    zeros: call it inside a ``shard_map`` with ``uids`` — a dedup stream
+    over a table of ``vocab`` rows — replicated.  A switch over the rung
+    the WHOLE live prefix takes (:func:`live_plan`: its index comes from
+    the replicated ``uids`` and is the same on every member — docs/
+    KERNELS.md, "Why no collective sits in a per-shard branch"); in each
+    branch one ``psum`` of the rung's rows, zeros behind them.
+
+    The sum is made at the rung ``s``, not at K, and flat on both sides
+    of the ``psum``, up to the zeros behind the rung: XLA:TPU all-reduces
+    an ``[s, 32]`` operand row-major with its 32 lanes padded to 128, four
+    times the bytes, and moves a reshape that only wraps the ``psum`` out
+    of the way (docs/KERNELS.md, "On a mesh").
+
+    ``start`` (traced, a member's own): ``x`` holds a run of the prefix
+    brought to slot 0 (:func:`shard_plan`), and is slid back ``start``
+    slots to where the run stands in ``uids`` before the sum — inside the
+    rung, where every run ends."""
+    k, tail = uids.shape[0], x.shape[1:]
+    width = math.prod(tail)
+
+    def rung(s, ordered):
+        del ordered
+        # on the rung K no slice or pad stands between the reshapes and the
+        # psum: a barrier does, or the operand is the lane-padded [K, 32]
+        # again
+        fence = jax.lax.optimization_barrier if s == k else (lambda v: v)
+
+        def f(x, start):
+            own = x[:s].reshape(-1)
+            if start is not None:
+                own = _slide(own, start * width, back=True)
+            joined = fence(jax.lax.psum(fence(own), axis_name))
+            return jnp.pad(joined, (0, (k - s) * width)).reshape((k,) + tail)
+        return f
+
+    _, whole = live_plan(uids, vocab)
+    return jax.lax.switch(whole, _ladder_branches(k, rung), x, start)
+
+
 def gather_shards(block: jax.Array, uids: jax.Array, axis_name: str,
                   pack: int = 1):
     """``table[uids]`` in ``uids`` order ([K, ...], zero rows behind the
@@ -495,36 +538,18 @@ def gather_shards(block: jax.Array, uids: jax.Array, axis_name: str,
     holds no collective) with zeros wherever a slot is not its own —
     the shards' rows are summed next, and clip's last row must not leak
     into the sum.  The join is a second switch, over the rung the WHOLE
-    live prefix takes — its index comes from the replicated ``uids`` and
-    is the same on every device: there the run is slid back to where it
-    stands in ``uids`` and one ``psum`` adds the shards.  ``pack`` as in
-    :func:`gather_live`: a shard's lane rows hold its own logical rows,
-    and of a fused store the join carries the table's rows alone — the
-    accumulator's stay with the shard, whose apply reads them itself."""
-    k, rows = uids.shape[0], block.shape[0] * fused_ids(pack)
+    live prefix takes (:func:`join_live`): there the run is slid back to
+    where it stands in ``uids`` and one ``psum`` adds the shards.
+    ``pack`` as in :func:`gather_live`: a shard's lane rows hold its own
+    logical rows, and of a fused store the join carries the table's rows
+    alone — the accumulator's stay with the shard, whose apply reads
+    them itself."""
+    rows = block.shape[0] * fused_ids(pack)
     lo = jax.lax.axis_index(axis_name) * rows
     idx, branch, start, _ = shard_plan(uids, rows, lo)
     part = gather_live(block, idx, branch, zero_pads=True, pack=pack)
-    tail = part.shape[1:]
-    width = math.prod(tail)
-
-    def rung(s, ordered):
-        del ordered
-
-        def f(part, start):
-            # every run ends inside the whole prefix's rung, so the slide
-            # and the sum are made at s, not at K — and flat, up to the
-            # zeros behind the rung: XLA:TPU all-reduces an [s, 32]
-            # operand row-major with its 32 lanes padded to 128, four
-            # times the bytes, and moves a reshape that only wraps the
-            # psum out of the way (docs/KERNELS.md, "On a mesh")
-            own = _slide(part[:s].reshape(-1), start * width, back=True)
-            joined = jax.lax.psum(own, axis_name)
-            return jnp.pad(joined, (0, (k - s) * width)).reshape((k,) + tail)
-        return f
-
-    _, whole = live_plan(uids, rows * jax.lax.axis_size(axis_name))
-    return jax.lax.switch(whole, _ladder_branches(k, rung), part, start)
+    return join_live(part, uids, axis_name,
+                     rows * jax.lax.axis_size(axis_name), start)
 
 
 def _scatter_live(table, accum, idx, delta, acc, branch, pack: int = 1):
@@ -686,9 +711,10 @@ def expand_rows(rows: jax.Array, ids: jax.Array):
     """``rows[ids]`` a position of the batch — how a model reads a table
     leaf (under the sparse trainer the dedup's gathered rows, ``ids``
     their positions).  The one place the ``model/expand`` scope is
-    opened: the take, its transpose's scatter-add and, on a mesh, the
-    all-reduce that transpose feeds are the step's ``expand`` phase
-    (docs/OBSERVABILITY.md, "The phases of the step")."""
+    opened but for the mesh step's join of what the transposes made
+    (:func:`join_live`, which the step puts under the same scope): the
+    take, its transpose's scatter-add and that join are the step's
+    ``expand`` phase (docs/OBSERVABILITY.md, "The phases of the step")."""
     with annotate("model/expand"):
         return jnp.take(rows, ids, axis=0)
 

@@ -1,6 +1,8 @@
 """CTRTrainer with PS-style param shardings (embedding tables row-sharded
 over the embed axis) matches replicated training."""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -199,17 +201,20 @@ def test_sharded_sparse_step_equals_one_device(axes, stream, monkeypatch):
         assert len(set(got)) > 1              # shards on different rungs
 
 
-@pytest.mark.parametrize("axes", [None, dict(data=2), dict(data=2, embed=2)],
-                         ids=["no_mesh", "embed_axis_of_1", "embed2"])
+@pytest.mark.parametrize(
+    "axes", [None, dict(data=1), dict(data=2), dict(data=2, embed=2)],
+    ids=["no_mesh", "axes_of_1", "data2", "embed2"])
 def test_step_without_row_shards_compiles_as_before(axes):
-    """What ISSUE 30 may not cost the one-chip cells, guarded without a
-    chip: with no mesh, or with rows "sharded" over an axis of one device,
-    the step's optimized HLO is the parent's in what matters — 6 sorts, 38
-    gathers, 38 scatters, 6 conditionals (counted at PR 29's tree on this
-    configuration) — and holds nothing a shard_map leaves behind: no
-    name scope of one, no collective, no dynamic slice, no partition id.
-    The counter sees what it is there to see: over two row shards they
-    appear."""
+    """What ISSUEs 30 and 39 may not cost the one-chip cells, guarded
+    without a chip: with no mesh, or with rows "sharded" and the batch
+    split over axes of one device, the step's optimized HLO is the
+    parent's in what matters — 6 sorts, 38 gathers, 38 scatters, 6
+    conditionals (counted at PR 29's tree on this configuration) — and
+    holds nothing a shard_map leaves behind: no name scope of one, no
+    collective, no dynamic slice, no partition id.  The counter sees what
+    it is there to see: over two ``data`` replicas the step joins the row
+    gradients inside a shard_map (and still plans no row shard), over two
+    row shards the shards' plans appear."""
     from lightctr_tpu.models.sparse_trainer import SparseTableCTRTrainer
 
     params = widedeep.init(jax.random.PRNGKey(0), _VOCAB, _F, _DIM)
@@ -244,6 +249,10 @@ def test_step_without_row_shards_compiles_as_before(axes):
         assert count["dynamic-slice"] and count["partition-id"]
         return
     assert tr._row_shards() == {}
+    if axes and axes["data"] > 1:
+        assert "shard_map/model/expand" in text and count["all-reduce"]
+        assert "gather_rows/shard_map" not in text
+        return
     assert "shard_map" not in text
     if not axes:                      # (GSPMD slices a data-sharded batch)
         # a forward gather and the accumulator's, one switch of 9 branches
@@ -253,6 +262,204 @@ def test_step_without_row_shards_compiles_as_before(axes):
         assert count == {"sort": 6, "gather": 38, "scatter": 29,
                          "conditional": 6, "all-reduce": 0,
                          "dynamic-slice": 0, "partition-id": 0}
+
+
+# -- the row gradients joined over ``data`` by the step itself ---------------
+#
+# Over more than one ``data`` replica the loss and its gradient run a replica
+# inside a shard_map and ``sparse_kernels.join_live`` sums the partial row
+# gradients, flat and at the live prefix's rung.  The one-device step is the
+# oracle, for every loss the step builds and for models that share no code.
+
+_X4 = dict(data=2, embed=2)
+
+
+def _by_rows(mesh, tables, params):
+    rep = NamedSharding(mesh, P())
+    return {k: NamedSharding(mesh, P("embed")) if k in tables else rep
+            for k in params}
+
+
+def _fm_pair(**kw):
+    from lightctr_tpu.models import fm
+    from lightctr_tpu.models.sparse_trainer import SparseTableCTRTrainer
+
+    params = fm.init(jax.random.PRNGKey(3), _VOCAB, _DIM)
+    tables = {"w": ["fids"], "v": ["fids"]}
+    mesh = make_mesh(MeshSpec(**_X4))
+
+    def build(**mesh_kw):
+        return SparseTableCTRTrainer(
+            params, fm.logits, TrainConfig(learning_rate=0.1, lambda_l2=0.001),
+            fused_fn=fm.logits_with_l2, sparse_tables=tables, **kw, **mesh_kw)
+
+    batches = [_wd_batch(_stream_cases()["id0_live"](s, 2), s) for s in range(3)]
+    return (build(mesh=mesh, param_shardings=_by_rows(mesh, tables, params)),
+            build(), batches)
+
+
+def _widedeep_pair(**kw):
+    from lightctr_tpu.models.sparse_trainer import SparseTableCTRTrainer
+
+    params = widedeep.init(jax.random.PRNGKey(2), _VOCAB, _F, _DIM)
+    tables = {"w": ["fids"], "embed": ["rep_fids"]}
+    mesh = make_mesh(MeshSpec(**_X4))
+
+    def build(**mesh_kw):
+        return SparseTableCTRTrainer(
+            params, widedeep.logits, TrainConfig(learning_rate=0.1),
+            sparse_tables=tables, **kw, **mesh_kw)
+
+    batches = [_wd_batch(_stream_cases()["rung_edges"](s, 2), s)
+               for s in range(3)]
+    return (build(mesh=mesh, param_shardings=_row_sharded(mesh)), build(),
+            batches)
+
+
+def _kimi_pair():
+    """The sequence tower under the softmax loss: packed rows whose marked
+    positions the two ``data`` replicas hold unequally (91 and 60), so a
+    mean of the replicas' means would not be the batch's."""
+    from lightctr_tpu.data import ingest
+    from lightctr_tpu.models import kimi_linear
+    from lightctr_tpu.models.sparse_trainer import SparseTableCTRTrainer
+
+    params, logits = kimi_linear.build(jax.random.PRNGKey(0))
+    tables = {"embed": ["tokens"]}
+    mesh = make_mesh(MeshSpec(**_X4))
+
+    def build(**mesh_kw):
+        return SparseTableCTRTrainer(
+            params, logits,
+            TrainConfig(learning_rate=0.05, lambda_l2=0.0, loss="softmax_xent"),
+            sparse_tables=tables, **mesh_kw)
+
+    docs = [list(range(1, 20)), list(range(5, 40)), list(range(30, 50)),
+            list(range(3, 60)), list(range(7, 31)), list(range(2, 9))]
+    batch = ingest.sequence_batch(ingest.pack_documents(docs, 32))
+    marked = batch["target_mask"].reshape(2, -1).sum(axis=1)
+    assert marked[0] != marked[1]
+    return (build(mesh=mesh, param_shardings=_by_rows(mesh, tables, params)),
+            build(), [batch] * 3)
+
+
+# (the pair, the most a weight may differ: this file's 5e-7 — FM's hot ids 0
+# and 1 sum a few hundred addends a step in another order and Adagrad's
+# rsqrt carries that into 2 of ``w``'s weights and 13 of ``v``'s, by 7.9e-6
+# at most, under GSPMD's all-reduce at PR 38 to the same digits; the tower's
+# norm weights of 1.0 take Adagrad steps of 0.05 and end ten ulps apart)
+_JOINED = {
+    "widedeep-plain": (_widedeep_pair, 5e-7),
+    "fm-plain": (_fm_pair, 1e-5),
+    "widedeep-armed": (lambda: _widedeep_pair(quality_bins=16), 5e-7),
+    "fm-armed": (lambda: _fm_pair(quality_bins=16), 1e-5),
+    "kimi_linear-softmax_xent": (_kimi_pair, 5e-6),
+}
+
+
+def _run(tr, batch):
+    """One step of the trainer's own jitted step, with its health vector."""
+    tr._params, tr._opt_state, loss, health = tr._step(
+        tr._params, tr._opt_state, tr._put(batch))
+    return float(loss), np.asarray(health)
+
+
+def _padded_collectives(text):
+    """The all-reduces, reduce-scatters and all-gathers of an optimized
+    HLO whose operand or result is an array of two dimensions or more
+    with fewer than 128 in the minor one: what XLA:TPU pads to 128
+    lanes.  (An array under one 8 x 128 tile's 1,024 elements — GSPMD's
+    sum of the armed step's ``[bins, 4]`` sketch — is one tile either
+    way.)"""
+    found = []
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = (.*?) "
+                     r"(all-reduce|reduce-scatter|all-gather)(?:-start)?\(",
+                     line)
+        if not m:
+            continue
+        for dims in re.findall(r"\[([0-9,]+)\]", m.group(1)):
+            dims = [int(d) for d in dims.split(",")]
+            if len(dims) > 1 and dims[-1] < 128 and np.prod(dims) >= 1024:
+                found.append(line.strip()[:160])
+    return found
+
+
+@pytest.mark.parametrize("case", sorted(_JOINED))
+def test_mesh_step_joins_the_row_gradients_itself(case):
+    """Three steps over ``data=2 x embed=2`` against the one-device step,
+    for the plain step, the step armed with the quality sketch and the
+    softmax loss: loss, tables, accumulators, dense leaves, the sketch and
+    the loss's own counts; the bytes a member hands each join, counted on
+    the host from the stream's distinct count; and the compiled step holds
+    no collective over a ``[.., d < 128]`` array — the row gradients cross
+    flat, and so do the dense ones."""
+    from lightctr_tpu import obs
+    from lightctr_tpu.models.sparse_trainer import _unpack_counts
+    from lightctr_tpu.obs import health
+    from lightctr_tpu.ops import sparse_kernels as sk
+    from tools import metrics_report
+
+    pair, atol = _JOINED[case]
+    sharded, plain, batches = pair()
+    spec = sharded._spec
+    text = jax.jit(sharded._build_step(), donate_argnums=(0, 1)).lower(
+        sharded._params, sharded._opt_state, sharded._put(batches[0])
+    ).compile().as_text()
+    assert "shard_map/model/expand" in text
+    assert not _padded_collectives(text)
+    assert _padded_collectives(
+        "%ar = (f32[9984,4]{1,0}, f32[7]{0}) all-reduce-start(%x, %y)")
+
+    sharded.telemetry = obs.MetricsRegistry()
+    sharded.health = health.HealthMonitor(registry=obs.MetricsRegistry())
+    health.ensure_trainer_detectors(sharded.health, tables=True)
+    want_bytes = dict.fromkeys(spec, 0)
+    try:
+        with obs.override(True):
+            for batch in batches:
+                (ls, hs), (lp, hp) = _run(sharded, batch), _run(plain, batch)
+                np.testing.assert_allclose(ls, lp, rtol=2e-6)
+                np.testing.assert_allclose(hs[:2], hp[:2], rtol=2e-5)
+                if sharded._quality_bins:
+                    tail = 4 * sharded._quality_bins
+                    np.testing.assert_allclose(hs[-tail:], hp[-tail:],
+                                               rtol=1e-5, atol=1e-6)
+                model = sharded._step_counts.model
+                if model:
+                    got, want = (
+                        _unpack_counts(h[2:2 + t._step_counts.width])[-len(model):]
+                        for t, h in ((sharded, hs), (plain, hp)))
+                    for name, g, w in zip(model, got, want):
+                        # (a replica's fullest expert, summed over the
+                        # replicas, bounds the batch's from above)
+                        assert g >= w if "tokens_max" in name else g == w, name
+                # what the host makes of the vector once it is drained
+                sharded._observe_scalars(sharded.health, hs)
+                for k, fields in spec.items():
+                    ids = np.concatenate([batch[f].reshape(-1) for f in fields])
+                    want_bytes[k] += 4 * sk.ladder_slots(
+                        ids.size, np.unique(ids).size) * int(
+                            np.prod(sharded._table_shapes[k][1:]))
+    finally:
+        sharded.health.close()
+    # ``trainer_exchange_bytes_total{table, policy}``, as the report shows it
+    report = metrics_report.summarize_exchange(sharded.telemetry.snapshot())
+    for k in spec:
+        assert report["tables"][k]["bytes"] == {
+            "rows_join": want_bytes[k], "grad_join": want_bytes[k]}
+        np.testing.assert_allclose(np.asarray(sharded.params[k]),
+                                   np.asarray(plain.params[k]),
+                                   rtol=0, atol=atol)
+        np.testing.assert_allclose(
+            np.asarray(sharded.opt_state["accum"][k]),
+            np.asarray(plain.opt_state["accum"][k]), rtol=2e-6, atol=1e-9)
+    dense = [jax.tree_util.tree_leaves({k: v for k, v in t.params.items()
+                                        if k not in spec})
+             for t in (sharded, plain)]
+    for a, b in zip(*dense):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=0, atol=atol)
 
 
 # -- the fused store (docs/KERNELS.md) --------------------------------------

@@ -6,7 +6,7 @@ FMA-contraction ulp, the EF pack against the written-out codec chain —
 and, for the kernels with two implementations, the Pallas form in
 interpret mode bit-identical to the codec, and the registry's dispatch."""
 
-from functools import partial
+from functools import lru_cache, partial
 
 import numpy as np
 import jax
@@ -538,6 +538,49 @@ def test_gather_shards_joins_each_shards_own_rows(per_shard, kw):
         got = np.asarray(gather(jnp.asarray(block), jnp.asarray(uids)))
         np.testing.assert_array_equal(got[live], block[uids[live]])
         assert not got[~live].any()
+
+
+@lru_cache(maxsize=None)
+def _joins(n, tail):
+    """``(join_live, a plain psum of the whole array)`` over ``n`` members
+    of a ``data`` axis, each handed its own ``[_LK, *tail]`` addend."""
+    from jax.sharding import Mesh, PartitionSpec as P
+
+    m = Mesh(np.array(jax.devices()[:n]), ("data",))
+
+    def over(fn):
+        return jax.jit(jax.shard_map(
+            lambda x, u: fn(x[0], u), mesh=m, in_specs=(P("data"), P()),
+            out_specs=P(), check_vma=False))
+
+    return (over(lambda x, u: sk.join_live(x, u, "data", 20000)),
+            over(lambda x, u: jax.lax.psum(x, "data")))
+
+
+@pytest.mark.parametrize("n", [2, 4])
+@pytest.mark.parametrize("tail", [(), (32,), (64,)],
+                         ids=["scalar", "d32", "d64"])
+@pytest.mark.parametrize("branch", range(len(_RUNGS) + 1))
+def test_join_live_is_a_psum_of_the_whole_array(branch, tail, n):
+    """``join_live`` over 2 and 4 members against one ``psum`` of all K
+    rows, bit for bit: on every rung of the ladder with a live prefix
+    that ends exactly on it, and on the undeclared full-K branch (ids out
+    of order), where rows may stand anywhere."""
+    rng = np.random.default_rng(branch)
+    ordered = branch < len(_RUNGS)
+    count = _RUNGS[branch] if ordered else 600
+    _, _, uids, _, _ = _live_case(rng, count, interleave=not ordered)
+    _, got_branch = sk.live_plan(jnp.asarray(uids), 20000)
+    assert int(got_branch) == branch
+    x = rng.normal(size=(n, _LK) + tail).astype(np.float32)
+    if ordered:
+        x[:, count:] = 0
+    join, plain = _joins(n, tail)
+    got = np.asarray(join(jnp.asarray(x), jnp.asarray(uids)))
+    assert got.shape == (_LK,) + tail
+    np.testing.assert_array_equal(
+        got, np.asarray(plain(jnp.asarray(x), jnp.asarray(uids))))
+    assert got[:count].all()                       # the sum, not zeros
 
 
 def test_forward_gather_reads_the_live_prefix(rng):

@@ -7,6 +7,7 @@ host's ``trainer/input`` span says what crossed its boundary — when it is
 recorded, and at no cost when it is not."""
 
 import collections
+import functools
 import re
 
 import jax
@@ -25,6 +26,7 @@ from test_sharded_trainer import _B, _F, _PD, _PV, _row_sharded, _wd_batch
 
 _INSTRUCTION = re.compile(r"^\s*(?:ROOT )?%?[\w.\-]+ = .*? ([a-z][a-z0-9\-]*)\(")
 _OP_NAME = re.compile(r'op_name="([^"]*)"')
+_SHARD_MAP_LITERAL = re.compile(r"jit\(step\)/shard_map(/broadcast\.\d+)?")
 
 
 def _widedeep(mesh):
@@ -66,8 +68,11 @@ def _kimi(mesh):
 def _instructions(tr, batch):
     """``[(opcode, name stack)]`` of the compiled step's named instructions
     (XLA makes a called computation's names whole as it inlines it)."""
-    text = jax.jit(tr._build_step(), donate_argnums=(0, 1)).lower(
-        tr._params, tr._opt_state, tr._put(batch)).compile().as_text()
+    return _named(jax.jit(tr._build_step(), donate_argnums=(0, 1)).lower(
+        tr._params, tr._opt_state, tr._put(batch)).compile().as_text())
+
+
+def _named(text):
     out = []
     for line in text.splitlines():
         op, name = _INSTRUCTION.match(line), _OP_NAME.search(line)
@@ -79,21 +84,32 @@ def _instructions(tr, batch):
 X4 = dict(data=2, embed=2)
 
 
+@functools.lru_cache(maxsize=None)
+def _built(build, mesh):
+    """``(trainer, batch, _instructions of its step)``, compiled once."""
+    tr, batch = build(make_mesh(MeshSpec(**X4)) if mesh else None)
+    return tr, batch, _instructions(tr, batch)
+
+
 @pytest.mark.parametrize("build, axes", [
     (_widedeep, None), (_widedeep, X4), (_fm, None), (_fm, X4), (_kimi, None),
 ], ids=["widedeep-one_device", "widedeep-data2xembed2", "fm-one_device",
         "fm-data2xembed2", "kimi_linear-one_device"])
 def test_every_named_instruction_of_the_step_lies_under_one_phase(build, axes):
-    tr, batch = build(make_mesh(MeshSpec(**axes)) if axes else None)
+    _, _, instructions = _built(build, bool(axes))
     found = collections.defaultdict(set)
     unscoped = []
     names = set()
-    for op, name in _instructions(tr, batch):
+    for op, name in instructions:
         names.add(name)
         phase = phases.phase_of(name)
         # the model's pass opens no scope of its own: what falls to it is
         # what the step differentiates, and nothing the step left unnamed
-        if phase is None or (phase == "model" and "jvp(" not in name):
+        # (but the literals of the mesh step's per-replica shard_map, which
+        # the partitioner names after it: constants and their broadcasts)
+        if phase is None or (phase == "model" and "jvp(" not in name
+                             and not (op in ("constant", "broadcast")
+                                      and _SHARD_MAP_LITERAL.fullmatch(name))):
             unscoped.append((op, name))
         found[op].add(phase)
     assert not unscoped, unscoped[:20]
@@ -111,6 +127,85 @@ def test_every_named_instruction_of_the_step_lies_under_one_phase(build, axes):
     assert found["gather"] - {"model"} == {"gather", "expand", "apply"}
     assert found["scatter"] - {"model"} == {"expand", "apply"}
     assert found["dot"] <= {"model"}
+
+
+def _step_as_pr38_built_it(tr):
+    """The one-program step as the tree of PR 38 wrote it out, from the
+    trainer's own pieces: the loss differentiated in the step's body, no
+    function between them (``SparseTableCTRTrainer._make_step`` before the
+    mesh step joined its row gradients itself)."""
+    import jax.numpy as jnp
+    import optax
+
+    from lightctr_tpu.models.ctr_trainer import _health_pack, softmax_count_names
+    from lightctr_tpu.models.sparse_trainer import _StepCounts
+    from lightctr_tpu.ops import sparse_kernels
+    from lightctr_tpu.utils.profiling import annotate
+
+    armed = tr._quality_bins is not None
+    seq = tr.cfg.loss == "softmax_xent"
+    loss_fn = tr._make_loss_fn(with_probs=armed)
+    spec, lane_pack = tr._spec, tr._lane_pack
+    layout = _StepCounts(
+        spec, {k: tr._table_shapes[k][0] for k in spec}, {}, lane_pack,
+        softmax_count_names(tr.logits_fn) if seq else ())
+
+    def step(params, opt_state, batch):
+        tables, dense, batch2, uids, rows, distinct = tr._dedup_and_gather(
+            spec, params, batch, None, {}, lane_pack)
+
+        def loss_on(rows, dense):
+            return loss_fn({**dense, **rows}, batch2)
+
+        if armed or seq:
+            (loss, aux), (g_rows, g_dense) = jax.value_and_grad(
+                loss_on, argnums=(0, 1), has_aux=True)(rows, dense)
+            probs, model_counts = (aux, None) if armed else (None, aux)
+        else:
+            loss, (g_rows, g_dense) = jax.value_and_grad(
+                loss_on, argnums=(0, 1))(rows, dense)
+            probs = model_counts = None
+        with annotate("step/update"):
+            gnorm = optax.global_norm((g_rows, g_dense))
+            updates, new_dense_state = tr.tx.update(
+                g_dense, opt_state["dense"], dense)
+            dense = jax.tree_util.tree_map(
+                lambda p, u: p + u.astype(p.dtype), dense, updates)
+        new_accum = {}
+        with annotate("sparse_tables/apply"):
+            for k in spec:
+                tables[k], accum, _ = sparse_kernels.merge_apply(
+                    tables[k], opt_state["accum"].get(k), uids[k], g_rows[k],
+                    None, lr=tr.cfg.learning_rate, eps=tr._eps,
+                    pack=lane_pack.get(k, 1))
+                if accum is not None:
+                    new_accum[k] = accum
+        with annotate("step/update"):
+            health = tr._append_sketch(
+                jnp.concatenate([_health_pack(loss, gnorm),
+                                 layout.pack(distinct, uids, batch,
+                                             model_counts)]),
+                probs, batch2)
+        return ({**dense, **tables},
+                {"dense": new_dense_state, "accum": new_accum}, loss, health)
+
+    return step
+
+
+@pytest.mark.parametrize("build", [_widedeep, _fm, _kimi],
+                         ids=["widedeep", "fm", "kimi_linear"])
+def test_the_one_device_step_keeps_the_name_stacks_it_had(build):
+    """With no mesh nothing wraps the loss: the compiled step's named
+    instructions — opcode and name stack, each as often — are those of the
+    step PR 38 built (``seq/kda/scan`` is read off a label cut at 120
+    characters: PERF.md section 7, question 8)."""
+    tr, batch, instructions = _built(build, False)
+    assert tr.mesh is None
+    text = jax.jit(_step_as_pr38_built_it(tr), donate_argnums=(0, 1)).lower(
+        tr._params, tr._opt_state, tr._put(batch)).compile().as_text()
+    assert collections.Counter(instructions) == collections.Counter(
+        _named(text))
+    assert not [name for _, name in instructions if "shard_map" in name]
 
 
 # -- the host's boundary: ``trainer/input`` ----------------------------------

@@ -432,7 +432,9 @@ def summarize_exchange(doc) -> dict:
     ``telemetry``) -> gradient-exchange report: per-table algorithm
     decisions (``trainer_exchange_algo_total{table,algo}`` — dense ring,
     sparse allgather, sparse reduce-scatter, or the HIERARCHICAL
-    two-level exchange), per-table bytes, the per-algorithm byte totals,
+    two-level exchange), per-table bytes (a policy each; of the
+    one-program mesh step its two joins, ``rows_join`` and ``grad_join``:
+    bytes a member hands each ``psum``), the per-algorithm byte totals,
     and for the hierarchical path its per-HOP split: the ICI local-merge
     bytes vs the DCN wire bytes (the number that stays flat in local
     replica count — docs/SPARSE_EXCHANGE.md)."""
