@@ -32,21 +32,40 @@ from lightctr_tpu.utils.profiling import annotate
 BLOCK = 512
 
 
-def _block(q, k, v, seg_q, seg_k, first):
+def _block(q, k, v, seg_q, seg_k, first, key_first=None, window: int = 0):
     """Queries ``q`` [Bq, H, dk] at positions ``first + i`` against keys
-    ``k`` [Tk, H, dk], values ``v`` [Tk, H, dv] at positions ``0..Tk``."""
-    scores = jnp.einsum("qhd,khd->hqk", q, k) * q.shape[-1] ** -0.5
+    ``k`` [Tk, Hk, dk], values ``v`` [Tk, Hk, dv] at positions ``0..Tk``
+    (``key_first + j`` where given).  ``Hk`` divides ``H``: query head
+    ``h`` reads key head ``h // (H / Hk)`` (grouped-query attention);
+    with ``window`` a key ``window`` or more positions back is masked too."""
+    grouped = k.shape[1] != q.shape[1]
+    if grouped:
+        qg = q.reshape(q.shape[0], k.shape[1], -1, q.shape[-1])
+        scores = jnp.einsum("qjgd,kjd->jgqk", qg, k).reshape(
+            q.shape[1], q.shape[0], k.shape[0]) * q.shape[-1] ** -0.5
+    else:
+        scores = jnp.einsum("qhd,khd->hqk", q, k) * q.shape[-1] ** -0.5
     pos_q = first + jnp.arange(q.shape[0])
-    keep = ((seg_q[:, None] == seg_k[None, :])
-            & (jnp.arange(k.shape[0])[None, :] <= pos_q[:, None]))
+    same = seg_q[:, None] == seg_k[None, :]
+    pos_k = jnp.arange(k.shape[0])
+    if key_first is not None:
+        pos_k = key_first + pos_k
+    keep = same & (pos_k[None, :] <= pos_q[:, None])
+    if window:
+        keep = keep & (pos_q[:, None] - pos_k[None, :] < window)
     probs = jax.nn.softmax(jnp.where(keep[None], scores, -jnp.inf), axis=-1)
+    if grouped:
+        pg = probs.reshape(k.shape[1], -1, q.shape[0], k.shape[0])
+        return jnp.einsum("jgqk,kjd->qjgd", pg, v).reshape(
+            q.shape[0], q.shape[1], v.shape[-1])
     return jnp.einsum("hqk,khd->qhd", probs, v)
 
 
 def segment_attention(q, k, v, seg, block: int = BLOCK):
     """Causal softmax attention in which a token sees its own document
-    alone.  ``q``, ``k`` [T, H, dk], ``v`` [T, H, dv], ``seg`` [T]; every
-    query sees at least itself, so no row of the softmax is empty."""
+    alone.  ``q`` [T, H, dk], ``k`` [T, Hk, dk], ``v`` [T, Hk, dv] with
+    ``Hk`` dividing ``H`` (:func:`_block`), ``seg`` [T]; every query sees
+    at least itself, so no row of the softmax is empty."""
     n_tok = q.shape[0]
     out = []
     for first in range(0, n_tok, block):

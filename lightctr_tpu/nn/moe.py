@@ -1,19 +1,24 @@
 """A routed expert layer that is told which experts it holds.
 
 The router scores every token against all ``E`` experts of the layer and
-picks ``top_k`` of them (sigmoid scores, a selection bias that is added
-for the pick and not for the weight, weights renormalised over the picked
-and scaled: DeepSeek-V3's auxiliary-loss-free router, as Kimi Linear
-configures it); this chip holds experts ``first .. first + n_held`` and
-computes their part of the result:
+picks ``top_k`` of them; this chip holds experts ``first .. first +
+n_held`` and computes their part of the result.  Two routers:
 
-    s = sigmoid(x Wr);  E(t) = top_k(s + bias);  w_e = scaling s_e / sum_{e' in E(t)} s_e'
-    y_t = Shared(x_t) + sum_{e in E(t), first <= e < first + n_held} w_e Expert_e(x_t)
+    "sigmoid":  s = sigmoid(x Wr);  E(t) = top_k(s + bias);  w_e = scaling s_e / sum_{e' in E(t)} s_e'
+    "softmax":  s = softmax(x Wr);  E(t) = top_k(s);         w_e = s_e / sum_{e' in E(t)} s_e'
+    y_t = [Shared(x_t)] + sum_{e in E(t), first <= e < first + n_held} w_e Expert_e(x_t)
+
+(the first is DeepSeek-V3's auxiliary-loss-free router, with a selection
+bias added for the pick and not for the weight, as Kimi Linear configures
+it; the second the softmax router with ``norm_topk_prob``, as Mellum 2
+does, with no shared expert).  The weights are renormalised over all the
+picked experts, never over the held ones alone.
 
 What the absent experts would add is left out: under expert parallelism
-their chips add it (tests/test_moe.py sums the shares back to the whole
-layer).  Nothing is dropped: every assignment to a held expert is
-computed whatever the imbalance, with static shapes.
+their chips add it (tests/test_kimi_linear.py and tests/test_mellum.py sum
+the shares back to the whole layer, one test a router).  Nothing is
+dropped: every assignment to a held expert is computed whatever the
+imbalance, with static shapes.
 
 The grouped product (docs/KERNELS.md, "The grouped expert product"): the
 ``N top_k`` assignments are sorted by held expert (the others behind
@@ -58,10 +63,16 @@ def swiglu(p: Dict, x: jax.Array, block: int = 0) -> jax.Array:
     return y.reshape(x.shape)
 
 
-def route(x: jax.Array, w_router: jax.Array, bias: jax.Array, top_k: int,
-          scaling: float) -> Tuple[jax.Array, jax.Array]:
+def route(x: jax.Array, w_router: jax.Array, bias, top_k: int,
+          scaling: float, router: str = "sigmoid") -> Tuple[jax.Array, jax.Array]:
     """``(experts [N, top_k] int32, weights [N, top_k])`` over all the
-    router's experts."""
+    router's experts (``router``: the module docstring's two; the softmax
+    one takes no ``bias``)."""
+    if router == "softmax":
+        s = jax.nn.softmax(x @ w_router, axis=-1)
+        _, idx = jax.lax.top_k(s, top_k)
+        picked = jnp.take_along_axis(s, idx, axis=-1)
+        return idx, scaling * picked / jnp.sum(picked, axis=-1, keepdims=True)
     s = jax.nn.sigmoid(x @ w_router)
     _, idx = jax.lax.top_k(s + bias, top_k)
     picked = jnp.take_along_axis(s, idx, axis=-1)
@@ -155,25 +166,28 @@ def _grouped_bwd(res, dy):
 grouped_experts.defvjp(_grouped_fwd, _grouped_bwd)
 
 
-def ffn(p: Dict, x: jax.Array, *, top_k: int, scaling: float, first: int = 0,
-        tile: int = TILE) -> Tuple[jax.Array, jax.Array]:
+def ffn(p: Dict, x: jax.Array, *, top_k: int, scaling: float = 1.0,
+        first: int = 0, tile: int = TILE, router: str = "sigmoid"
+        ) -> Tuple[jax.Array, jax.Array]:
     """The expert layer of the module docstring over ``x`` [..., D], and
     int32 ``[3]``: the assignments the router made, those to held experts,
     and the busiest held expert's tokens.  ``p``: ``router`` [D, E],
-    ``router_bias`` [E], ``shared`` (a SwiGLU's ``w_gate``, ``w_up``,
-    ``w_down``) and ``experts`` (the held experts' three, stacked)."""
+    ``router_bias`` [E] (the sigmoid router's), ``shared`` (a SwiGLU's
+    ``w_gate``, ``w_up``, ``w_down``; optional) and ``experts`` (the held
+    experts' three, stacked)."""
     flat = x.reshape(-1, x.shape[-1])
     held = p["experts"]
     with annotate("seq/moe"):
         with annotate("seq/moe/route"):
-            idx, weights = route(flat, p["router"], p["router_bias"], top_k,
-                                 scaling)
+            idx, weights = route(flat, p["router"], p.get("router_bias"),
+                                 top_k, scaling, router)
             tok, wt, expert, n_tiles, counts = tile_plan(
                 idx, weights, first, held["w_gate"].shape[0], tile)
-        y = swiglu(p["shared"], flat)
+        shared = swiglu(p["shared"], flat) if "shared" in p else None
         with annotate("seq/moe/experts"):
-            y = y + grouped_experts(flat, wt, tok, expert, n_tiles,
-                                    held["w_gate"], held["w_up"],
-                                    held["w_down"])
+            y = grouped_experts(flat, wt, tok, expert, n_tiles,
+                                held["w_gate"], held["w_up"], held["w_down"])
+            if shared is not None:
+                y = shared + y
     stats = jnp.stack([jnp.int32(idx.size), jnp.sum(counts), jnp.max(counts)])
     return y.reshape(x.shape), stats

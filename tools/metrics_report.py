@@ -802,6 +802,9 @@ def summarize_ingest(doc) -> dict:
     return report
 
 
+PAIRS = "trainer_seq_attn_pairs_total"
+
+
 def summarize_seq(doc, held_experts: int = 0) -> dict:
     """Registry snapshot (or a stats() dump carrying one under
     ``telemetry``) -> the sequence tower's report: positions, targets and
@@ -812,7 +815,9 @@ def summarize_seq(doc, held_experts: int = 0) -> dict:
     expert against the mean (``max_over_mean``:
     ``trainer_moe_expert_tokens_max`` is a step's maximum summed over
     steps, the mean is the held assignments over ``held_experts``, which
-    the caller states — the counters do not carry it)."""
+    the caller states — the counters do not carry it); and the (query,
+    key) pairs one attention layer of each kind attends
+    (``trainer_seq_attn_pairs_total{kind}``, summed over steps)."""
     snap = doc.get("telemetry", doc) if isinstance(doc, dict) else doc
     counters = snap.get("counters", {})
     report: dict = {}
@@ -842,6 +847,12 @@ def summarize_seq(doc, held_experts: int = 0) -> dict:
                 most / (entry["held"] / held_experts), 3)
     if layers:
         report["moe_layers"] = dict(sorted(layers.items()))
+    pairs = {key[len(PAIRS) + 1:-1].split("=", 1)[1].strip('"'): int(val)
+             for key, val in counters.items() if key.startswith(PAIRS + "{")}
+    if pairs:
+        if pairs.get("window") and pairs.get("full"):
+            pairs["window_over_full"] = pairs["window"] / pairs["full"]
+        report["attention_pairs"] = pairs
     return report
 
 
