@@ -34,7 +34,7 @@ from typing import Dict, Tuple
 import jax
 import jax.numpy as jnp
 
-from lightctr_tpu.nn import kda, mla, moe
+from lightctr_tpu.nn import gqa, kda, mla, moe
 from lightctr_tpu.ops.sparse_kernels import expand_rows
 from lightctr_tpu.utils.profiling import annotate
 
@@ -190,8 +190,10 @@ def make_logits(s: Spec):
     """``logits(params, batch) -> ([B, T, vocab], counts)``.  ``counts``
     are the integers ``logits.step_counts`` names, in its order: per routed
     layer the router's assignments, those to held experts and the busiest
-    held expert's tokens.  The sparse trainer carries them on the step's
-    health vector (``models/sparse_trainer._StepCounts``)."""
+    held expert's tokens; then, where a layer is MLA, the (query, key) pairs
+    one such layer attends and those its core multiplies to do so.  The
+    sparse trainer carries them on the step's health vector
+    (``models/sparse_trainer._StepCounts``)."""
     if any(kind not in ("kda", "mla") for kind in s.mixers):
         raise ValueError(f"mixers are 'kda' or 'mla', got {s.mixers}")
 
@@ -204,6 +206,9 @@ def make_logits(s: Spec):
                 s, i, kind, params[f"layer{i}"], x, seg)
             if i > s.first_dense:
                 stats.append(st)
+        if "mla" in s.mixers:
+            stats += [gqa.attended_pairs(seg)[None],
+                      mla.visited_pairs(seg, s.attn_block)[None]]
         with annotate("seq/head_loss"):
             z = kda.rms_norm(x, params["final_norm"], s.eps) @ params["head"]
         return z, jnp.concatenate(stats) if stats else jnp.zeros((0,), jnp.int32)
@@ -212,7 +217,10 @@ def make_logits(s: Spec):
         (name, {"layer": str(i)}) for i in s.moe_layers
         for name in ("trainer_moe_assignments_total",
                      "trainer_moe_held_assignments_total",
-                     "trainer_moe_expert_tokens_max"))
+                     "trainer_moe_expert_tokens_max")) + tuple(
+        (name, {"kind": "mla"}) for name in ("trainer_seq_attn_pairs_total",
+                                             "trainer_seq_attn_visited_pairs_total")
+        if "mla" in s.mixers)
     return logits
 
 

@@ -16,8 +16,9 @@ is a ``sparse_tables`` leaf of ``SparseTableCTRTrainer`` (``{"embed":
 ["tokens"]}``), the batch is ``data.ingest.sequence_batch``'s (a packed
 document's number in ``segment_ids``: positions restart and attention
 stops at its edges), the loss ``TrainConfig(loss="softmax_xent")``,
-``Spec.held`` the chip's share of each layer's experts, and one remat
-boundary a layer.
+``Spec.held`` the chip's share of each layer's experts, one remat
+boundary a layer, and a layer's weight gradients complete before the
+layer below begins its backward pass (:func:`grads_at_once`).
 """
 
 from __future__ import annotations
@@ -28,7 +29,7 @@ from typing import Dict, Tuple
 import jax
 import jax.numpy as jnp
 
-from lightctr_tpu.nn import gqa, moe
+from lightctr_tpu.nn import gqa, mla, moe
 from lightctr_tpu.nn.kda import rms_norm
 from lightctr_tpu.ops.sparse_kernels import expand_rows
 from lightctr_tpu.utils.profiling import annotate
@@ -112,13 +113,38 @@ def _layer(s: Spec, kind: str, p: Dict, x, seg, cos, sin):
     return x + y, stats
 
 
+@jax.custom_vjp
+def grads_at_once(tree):
+    """``tree`` as it is; its backward pass hands on all the cotangents of
+    ``tree`` together, through one optimization barrier.  Around a layer's
+    weights and input it makes the layer's weight gradients complete before
+    the layer below begins its backward pass: XLA otherwise put each weight
+    gradient's product into the optimizer's update at the end of the step
+    and held its operands, the layer's activations and their cotangents,
+    until then (11.03 GB of compiled temporaries at the published sizes
+    where the tie leaves 4.77)."""
+    return tree
+
+
+def _grads_at_once_fwd(tree):
+    return tree, None
+
+
+def _grads_at_once_bwd(_, cotangents):
+    return (jax.lax.optimization_barrier(cotangents),)
+
+
+grads_at_once.defvjp(_grads_at_once_fwd, _grads_at_once_bwd)
+
+
 def make_logits(s: Spec):
     """``logits(params, batch) -> ([B, T, vocab], counts)``.  ``counts``
     are the integers ``logits.step_counts`` names, in its order: per
     layer the router's assignments, those to held experts and the busiest
     held expert's tokens; then, per attention kind in the stack, the
     (query, key) pairs one layer of that kind attends (counted from the
-    segment ids)."""
+    segment ids), and the pairs a full layer's core multiplies to do so
+    (``mla.visited_pairs``)."""
     if any(kind not in (WINDOW, FULL) for kind in s.layers):
         raise ValueError(f"layers are {WINDOW!r} or {FULL!r}, got {s.layers}")
     kinds = tuple(k for k in (WINDOW, FULL) if k in s.layers)
@@ -130,13 +156,17 @@ def make_logits(s: Spec):
         x = expand_rows(params["embed"], batch["tokens"])
         stats = []
         for i, kind in enumerate(s.layers, 1):
+            p_i, x = grads_at_once((params[f"layer{i}"], x))
             x, st = jax.checkpoint(_layer, static_argnums=(0, 1))(
-                s, kind, params[f"layer{i}"], x, seg, *tables[kind])
+                s, kind, p_i, x, seg, *tables[kind])
             stats.append(st)
         stats += [gqa.attended_pairs(seg, s.window if k == WINDOW else 0)[None]
                   for k in kinds]
+        if FULL in kinds:
+            stats.append(mla.visited_pairs(seg, s.block)[None])
+        norm, head, x = grads_at_once((params["final_norm"], params["head"], x))
         with annotate("seq/head_loss"):
-            z = rms_norm(x, params["final_norm"], s.eps) @ params["head"]
+            z = rms_norm(x, norm, s.eps) @ head
         return z, jnp.concatenate(stats)
 
     logits.step_counts = tuple(
@@ -145,7 +175,9 @@ def make_logits(s: Spec):
                      "trainer_moe_held_assignments_total",
                      "trainer_moe_expert_tokens_max")) + tuple(
         ("trainer_seq_attn_pairs_total", {"kind": "window" if k == WINDOW else "full"})
-        for k in kinds)
+        for k in kinds) + (
+        (("trainer_seq_attn_visited_pairs_total", {"kind": "full"}),)
+        if FULL in kinds else ())
     return logits
 
 

@@ -14,12 +14,13 @@ restarts at every packed document's first token:
 ``inv_freq`` is the default rope's ``theta^(-2j/d)`` or HF's YaRN
 (``yarn_inv_freq``); YaRN multiplies cos and sin by its attention factor.
 
-Both cores are blocked XLA forms of ``mla.segment_attention``'s kind: a
-block of queries at a time, its scores made again in the backward pass.
-A full layer's block reads the keys up to its end (``segment_attention``
-itself); a window layer's block of ``B`` queries reads only the ``B +
-ceil((W-1)/B) B`` keys its window can reach (:func:`window_attention`),
-so its work is O(T W), not O(T^2).  The heads run a KV group at a time.
+Both cores are blocked XLA forms: a block of queries at a time, its
+scores made again in the backward pass.  A full layer's block reads the
+key blocks from its first query's document start up to its own
+(``mla.segment_attention`` itself); a window layer's block of ``B``
+queries reads only the ``B + ceil((W-1)/B) B`` keys its window can reach
+(:func:`window_attention`), so its work is O(T W), not O(T^2).  The heads
+run a KV group at a time.
 """
 
 from __future__ import annotations
@@ -31,7 +32,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from lightctr_tpu.nn.mla import BLOCK, _block, segment_attention
+from lightctr_tpu.nn.mla import BLOCK, _block, document_starts, segment_attention
 from lightctr_tpu.utils.profiling import annotate
 
 
@@ -66,11 +67,7 @@ def yarn_inv_freq(dim: int, theta: float, factor: float, original: int,
 def positions(seg: jax.Array) -> jax.Array:
     """A token's offset inside its packed document: ``seg`` [..., T] ->
     int32 [..., T], 0 at every document's first token."""
-    t = jnp.arange(seg.shape[-1], dtype=jnp.int32)
-    prev = jnp.concatenate(
-        [jnp.full(seg.shape[:-1] + (1,), -3, seg.dtype), seg[..., :-1]], axis=-1)
-    start = jax.lax.cummax(jnp.where(seg != prev, t, 0), axis=seg.ndim - 1)
-    return t - start
+    return jnp.arange(seg.shape[-1], dtype=jnp.int32) - document_starts(seg)
 
 
 def rope_table(pos: jax.Array, inv_freq: np.ndarray, factor: float = 1.0):
@@ -142,9 +139,12 @@ def mixer(p: Dict, x: jax.Array, seg: jax.Array, cos: jax.Array,
     ``wv`` [D, Hk d], ``wo`` [H d, D], no biases.  ``window`` 0 is a full
     layer.  The projections take all heads at once; the cores run the
     ``Hk`` KV groups one after another (``lax.map``), a group's scores
-    made again in the backward pass, under a scope of their own
-    (``seq/gqa/window`` or ``seq/gqa/full``) that stays near the front of
-    the name stack: a trace's label keeps its first 120 characters."""
+    made again in the backward pass (the window's by a ``jax.checkpoint``
+    a group, the full core's by its own backward pass: the layer's
+    checkpoint already makes its forward again once), under a scope of
+    their own (``seq/gqa/window`` or ``seq/gqa/full``) that stays near the
+    front of the name stack: a trace's label keeps its first 120
+    characters."""
     b, n_tok, _ = x.shape
     dim = cos.shape[-1]
 
@@ -152,7 +152,7 @@ def mixer(p: Dict, x: jax.Array, seg: jax.Array, cos: jax.Array,
         q, k, v = args
         if window:
             return jax.vmap(lambda *a: window_attention(*a, window, block))(q, k, v, seg)
-        return jax.vmap(lambda *a: segment_attention(*a, block))(q, k, v, seg)
+        return segment_attention(q, k, v, seg, block)
 
     def groups(y):                           # [B, T, H, d] -> [Hk, B, T, H / Hk, d]
         return jnp.moveaxis(y.reshape(b, n_tok, kv_heads, -1, dim), 2, 0)
@@ -163,5 +163,5 @@ def mixer(p: Dict, x: jax.Array, seg: jax.Array, cos: jax.Array,
         v = groups((x @ p["wv"]).reshape(b, n_tok, kv_heads, dim))
         # (a nested scope's name adds to the stack: it reads seq/gqa/window)
         with annotate("window" if window else "full"):
-            o = jax.lax.map(jax.checkpoint(core), (q, k, v))
+            o = jax.lax.map(jax.checkpoint(core) if window else core, (q, k, v))
         return jnp.moveaxis(o, 0, 2).reshape(b, n_tok, -1) @ p["wo"]

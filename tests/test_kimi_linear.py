@@ -131,18 +131,77 @@ def test_state_and_convolution_stop_at_a_document_boundary():
 # -- MLA ------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("block", [16, 20, 48])
-def test_blocked_segment_attention_is_the_full_masked_softmax(block):
-    ks = jax.random.split(jax.random.PRNGKey(5), 3)
-    q = jax.random.normal(ks[0], (T, 2, 24))
-    k = jax.random.normal(ks[1], (T, 2, 24))
-    v = jax.random.normal(ks[2], (T, 2, 16))
-    got = mla.segment_attention(q, k, v, jnp.asarray(SEG), block)
-    scores = jnp.einsum("qhd,khd->hqk", q, k) / np.sqrt(24.0)
-    keep = (SEG[:, None] == SEG[None, :]) & (np.arange(T)[None] <= np.arange(T)[:, None])
-    want = jnp.einsum("hqk,khd->qhd", jax.nn.softmax(
+def masked_softmax(q, k, v, seg):
+    """The whole [T, T] mask, key heads repeated to the query heads."""
+    rep = q.shape[1] // k.shape[1]
+    k, v = jnp.repeat(k, rep, axis=1), jnp.repeat(v, rep, axis=1)
+    i, j = np.arange(len(seg))[:, None], np.arange(len(seg))[None]
+    keep = (seg[:, None] == seg[None]) & (j <= i)
+    scores = jnp.einsum("qhd,khd->hqk", q, k) / np.sqrt(q.shape[-1])
+    return jnp.einsum("hqk,khd->qhd", jax.nn.softmax(
         jnp.where(keep[None], scores, -jnp.inf), axis=-1), v)
-    np.testing.assert_allclose(got, want, atol=2e-6)
+
+
+def visited_blocks(seg, block):
+    """The key blocks the core visits, counted in numpy: query block ``i``
+    from the block holding its first query's document start to itself."""
+    if len(seg) % block:
+        block = len(seg)
+    start = [t if t == 0 or seg[t] != seg[t - 1] else 0 for t in range(len(seg))]
+    start = np.maximum.accumulate(start)
+    return sum(i + 1 - start[i * block] // block for i in range(len(seg) // block)), block
+
+
+@pytest.mark.parametrize("lengths, block, heads, kv_heads", [
+    ((13, 22, 13), 16, 2, 2), ((13, 22, 13), 20, 2, 2), ((13, 22, 13), 48, 2, 2),
+    ((3, 5, 40), 16, 2, 2),                  # two documents shorter than a block
+    ((5, 38, 5), 8, 2, 2),                   # a document over five blocks
+    ((16, 16, 16), 16, 2, 2),                # each document starts on a block edge
+    ((48,), 8, 2, 2),                        # one document: nothing skipped
+    ((13, 22, 13), 8, 4, 2),                 # grouped heads
+    ((13, 22, 13), 16, 4, 1),
+], ids=["16", "20", "48", "short_docs",
+        "spanning", "on_block_edges", "one_doc", "grouped-8", "grouped-16"])
+def test_blocked_segment_attention_is_the_full_masked_softmax(lengths, block, heads,
+                                                               kv_heads):
+    """Values and the gradients of q, k and v are the dense masked softmax's
+    (keys 24 wide, values 16), and the pairs the core counts are the blocks
+    its bounds visit, times ``block**2``."""
+    seg = np.repeat(np.arange(len(lengths)), lengths).astype(np.int32)
+    ks = jax.random.split(jax.random.PRNGKey(5), 4)
+    q = jax.random.normal(ks[0], (T, heads, 24))
+    k = jax.random.normal(ks[1], (T, kv_heads, 24))
+    v = jax.random.normal(ks[2], (T, kv_heads, 16))
+    w = jax.random.normal(ks[3], (T, heads, 16))
+    got = mla.segment_attention(q, k, v, jnp.asarray(seg), block)
+    np.testing.assert_allclose(got, masked_softmax(q, k, v, seg), atol=2e-6)
+    grads = [jax.grad(lambda *a: jnp.sum(w * fn(*a)), argnums=(0, 1, 2))(q, k, v)
+             for fn in (lambda *a: mla.segment_attention(*a, jnp.asarray(seg), block),
+                        lambda *a: masked_softmax(*a, seg))]
+    for g, r in zip(*grads):
+        np.testing.assert_allclose(g, r, atol=5e-6)
+    blocks, fit = visited_blocks(seg, block)
+    assert int(mla.visited_pairs(jnp.asarray(seg), block)) == blocks * fit * fit
+    if len(lengths) == 1:
+        n = T // fit
+        assert blocks == n * (n + 1) // 2
+
+
+def test_the_rows_of_a_batch_attend_each_to_its_own_documents():
+    """A batch runs as one sequence whose documents are each row's own: a
+    row's result is what it gives alone, padding (``seg`` -1) included, and
+    the count is the rows' own."""
+    ks = jax.random.split(jax.random.PRNGKey(6), 3)
+    q = jax.random.normal(ks[0], (2, T, 2, 24))
+    k = jax.random.normal(ks[1], (2, T, 2, 24))
+    v = jax.random.normal(ks[2], (2, T, 2, 16))
+    seg = np.stack([SEG, np.where(np.arange(T) < 40, 0, -1)]).astype(np.int32)
+    got = mla.segment_attention(q, k, v, jnp.asarray(seg), 16)
+    for row in range(2):
+        np.testing.assert_allclose(got[row], masked_softmax(q[row], k[row], v[row], seg[row]),
+                                   atol=2e-6)
+    assert int(mla.visited_pairs(jnp.asarray(seg), 16)) == sum(
+        int(mla.visited_pairs(jnp.asarray(s), 16)) for s in seg)
 
 
 @pytest.mark.parametrize("kind, layer", [("kda", "layer1"), ("mla", "layer4")])
@@ -357,7 +416,15 @@ def test_the_counters_off_the_health_vector_are_a_host_count(ref, setup, trained
     # initial weights
     logits = jax.jit(kimi_linear.make_logits(ref.spec_of(cfg)))
     _, stats = logits(params, {k: jnp.asarray(v) for k, v in batch.items()})
-    for row, layer in zip(np.asarray(stats).reshape(4, 3), ("2", "3", "4", "5")):
+    # the MLA layer's pairs: those in the mask (sum of L (L + 1) / 2) and
+    # those its core multiplies (at blocks of 16: every block under the
+    # diagonal, since the second document starts in the first block)
+    needed = sum(n * (n + 1) // 2 for n in (13, 22, 13))
+    assert stats[12:].tolist() == [needed, 6 * 16 * 16]
+    assert counters[obs.labeled("trainer_seq_attn_pairs_total", kind="mla")] == 3 * needed
+    assert counters[obs.labeled("trainer_seq_attn_visited_pairs_total",
+                                kind="mla")] == 3 * 6 * 16 * 16
+    for row, layer in zip(np.asarray(stats[:12]).reshape(4, 3), ("2", "3", "4", "5")):
         name = obs.labeled("trainer_moe_assignments_total", layer=layer)
         assert counters[name] == 3 * T * 4 and row[0] == T * 4
         held = counters[obs.labeled("trainer_moe_held_assignments_total", layer=layer)]
@@ -383,6 +450,9 @@ def test_metrics_report_seq_prints_held_share_and_load(tmp_path, trained, capsys
     for entry in report["moe_layers"].values():
         assert entry["assignments"] == 3 * T * 4
         assert 0 < entry["held_share"] < 1 and 1.0 <= entry["max_over_mean"] <= 2.0
+    assert report["attention_pairs"] == {"mla": 3 * 435}
+    assert report["attention_visited"] == {
+        "mla": {"pairs": 3 * 1536, "over_needed": 1536 / 435}}
 
 
 # -- packed rows ----------------------------------------------------------------

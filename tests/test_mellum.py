@@ -276,8 +276,10 @@ def test_logits_loss_and_every_leafs_gradient_are_the_references(ref, setup):
         np.testing.assert_allclose(g, want_grads[name], atol=2e-5 * scale + 1e-7,
                                    err_msg=name)
     # the softmax loss's own three, then the layers' routing, then the pairs
+    # of each kind and those the full layer's core multiplies (blocks of
+    # 16: all six under the diagonal)
     assert counts[:3].tolist() == [T, 45, 3]
-    assert counts[-2:].tolist() == [76 + 148 + 76, 91 + 253 + 91]
+    assert counts[-3:].tolist() == [76 + 148 + 76, 91 + 253 + 91, 6 * 16 * 16]
 
 
 @pytest.fixture(scope="module")
@@ -336,6 +338,8 @@ def test_the_counters_off_the_health_vector_are_a_host_count(trained):
     # of L (L + 1) / 2, and under the window of 8 at most 8 a token
     assert counters[full] == 3 * sum(n * (n + 1) // 2 for n in (13, 22, 13))
     assert counters[window] == 3 * sum(36 + (n - 8) * 8 for n in (13, 22, 13))
+    assert counters[obs.labeled("trainer_seq_attn_visited_pairs_total", kind="full")] == (
+        3 * int(mla.visited_pairs(jnp.asarray(SEG), 16)))
     for layer in ("1", "2", "3", "4"):
         assert counters[obs.labeled("trainer_moe_assignments_total", layer=layer)] == 3 * T * 4
 
@@ -349,4 +353,8 @@ def test_metrics_report_seq_prints_the_attention_pairs(tmp_path, trained, capsys
     report = json.loads(capsys.readouterr().out)
     assert report["attention_pairs"] == {"full": 3 * 435, "window": 3 * 300,
                                          "window_over_full": 300 / 435}
+    # the full layer's core at blocks of 16 visits the 6 blocks under the
+    # diagonal: 1,536 pairs for the 435 its mask holds
+    assert report["attention_visited"] == {
+        "full": {"pairs": 3 * 1536, "over_needed": 1536 / 435}}
     assert sorted(report["moe_layers"]) == ["1", "2", "3", "4"]

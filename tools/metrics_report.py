@@ -803,6 +803,12 @@ def summarize_ingest(doc) -> dict:
 
 
 PAIRS = "trainer_seq_attn_pairs_total"
+VISITED = "trainer_seq_attn_visited_pairs_total"
+
+
+def _by_kind(counters: dict, name: str) -> dict:
+    return {key[len(name) + 1:-1].split("=", 1)[1].strip('"'): int(val)
+            for key, val in counters.items() if key.startswith(name + "{")}
 
 
 def summarize_seq(doc, held_experts: int = 0) -> dict:
@@ -817,7 +823,10 @@ def summarize_seq(doc, held_experts: int = 0) -> dict:
     steps, the mean is the held assignments over ``held_experts``, which
     the caller states — the counters do not carry it); and the (query,
     key) pairs one attention layer of each kind attends
-    (``trainer_seq_attn_pairs_total{kind}``, summed over steps)."""
+    (``trainer_seq_attn_pairs_total{kind}``, summed over steps) and, where
+    the core counts them, the pairs it multiplies to do so and their ratio
+    to those (``trainer_seq_attn_visited_pairs_total{kind}``:
+    ``attention_visited``)."""
     snap = doc.get("telemetry", doc) if isinstance(doc, dict) else doc
     counters = snap.get("counters", {})
     report: dict = {}
@@ -847,12 +856,15 @@ def summarize_seq(doc, held_experts: int = 0) -> dict:
                 most / (entry["held"] / held_experts), 3)
     if layers:
         report["moe_layers"] = dict(sorted(layers.items()))
-    pairs = {key[len(PAIRS) + 1:-1].split("=", 1)[1].strip('"'): int(val)
-             for key, val in counters.items() if key.startswith(PAIRS + "{")}
+    pairs = _by_kind(counters, PAIRS)
+    visited = {kind: {"pairs": n, "over_needed": n / pairs[kind]}
+               for kind, n in _by_kind(counters, VISITED).items() if pairs.get(kind)}
     if pairs:
         if pairs.get("window") and pairs.get("full"):
             pairs["window_over_full"] = pairs["window"] / pairs["full"]
         report["attention_pairs"] = pairs
+    if visited:
+        report["attention_visited"] = visited
     return report
 
 
