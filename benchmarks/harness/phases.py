@@ -39,7 +39,7 @@ import functools
 import re
 from typing import Dict, List, Optional, Tuple
 
-from benchmarks.harness.trace_reduce import Event, subtract, total, union
+from benchmarks.harness.trace_reduce import Event, stack_path, subtract, total, union
 
 #: phase -> scope, in the order that decides an operation under several
 SCOPES = {
@@ -55,8 +55,6 @@ PHASES = tuple(SCOPES) + ("model",)
 #: that opens none of them has no split to read
 _NEW = ("dedup", "gather", "expand", "update")
 _STEP = re.compile(r"jit\((?:\w+_)?step\)/")
-#: ``jit(``, ``transpose(jvp(`` ... and their closing brackets
-_TRANSFORM = re.compile(r"[A-Za-z_][\w.\-]*\(|\)")
 
 Interval = Tuple[float, float]
 
@@ -66,7 +64,7 @@ def phase_of(scope: str) -> Optional[str]:
     """The phase of a name stack, or ``None``."""
     if not _STEP.match(scope):
         return None
-    path = "/" + _TRANSFORM.sub("", scope) + "/"
+    path = "/" + stack_path(scope) + "/"
     for phase, name in SCOPES.items():
         if "/" + name + "/" in path:
             return phase

@@ -17,6 +17,7 @@ instruction (``fusion.12``), ``scope`` the JAX name stack that produced it
 
 from __future__ import annotations
 
+import functools
 import re
 from typing import Dict, Iterable, List, Sequence, Tuple
 
@@ -34,6 +35,8 @@ OP_LINES = ("XLA Ops",)
 #: the host does in a training step and in its input pipeline
 SPAN_PREFIXES = ("bench/", "trainer/", "ingest/")
 WINDOW = "bench/window"
+#: ``jit(``, ``transpose(jvp(`` ... in a name stack, and their closing brackets
+TRANSFORM_RE = re.compile(r"[A-Za-z_][\w.\-]*\(|\)")
 
 
 def load_xplane(path: str) -> Dict:
@@ -120,7 +123,13 @@ def _iv(events: Iterable[Event]) -> List[Tuple[float, float]]:
 
 
 def is_collective(ev: Event) -> bool:
-    return bool(COLLECTIVE_RE.search(ev[0]) or COLLECTIVE_RE.search(ev[1]))
+    return _collective(ev[0], ev[1])
+
+
+@functools.lru_cache(maxsize=None)
+def _collective(name: str, scope: str) -> bool:
+    # a trace repeats a few thousand (name, scope) pairs millions of times
+    return bool(COLLECTIVE_RE.search(name) or COLLECTIVE_RE.search(scope))
 
 
 # -- the reduction ------------------------------------------------------------
@@ -173,6 +182,7 @@ def reduce_trace(trace: Dict, top: int = 10) -> Dict:
     }
 
 
+@functools.lru_cache(maxsize=None)
 def op_label(name: str, scope: str) -> str:
     """One label per operation: instruction name plus its name stack, in a
     name's characters."""
@@ -225,12 +235,27 @@ def attribute_gaps(gaps: Sequence[Tuple[float, float]],
     return out
 
 
+@functools.lru_cache(maxsize=None)
+def stack_path(scope: str) -> str:
+    """A name stack with the transforms' wrappers taken off:
+    ``jit(step)/jvp(seq/gqa)/full/dot_general`` is
+    ``step/seq/gqa/full/dot_general``, where a scope a transform closes
+    around reads as it was opened."""
+    return TRANSFORM_RE.sub("", scope)
+
+
 def scope_seconds(reduced: Dict, pattern: str) -> float:
-    """Device seconds (mean over devices) of operations whose label matches
+    """Device seconds (mean over devices) of operations whose label or whole
+    name stack (``stack_path``; the label is cut at 120 characters) matches
     ``pattern`` — found by scope, not by kernel name.  Overlapping matches
     on one device count once."""
-    rx = re.compile(pattern)
     ns = 0.0
     for evs in reduced["per_device"].values():
-        ns += total(union(_iv(e for e in evs if rx.search(e[0]))))
+        ns += total(union(_iv(e for e in evs if _in_scope(pattern, e[0], e[1]))))
     return ns / max(1, len(reduced["per_device"])) / 1e9
+
+
+@functools.lru_cache(maxsize=None)
+def _in_scope(pattern: str, label: str, scope: str) -> bool:
+    rx = re.compile(pattern)
+    return bool(rx.search(label) or rx.search(stack_path(scope)))

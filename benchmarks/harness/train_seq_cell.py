@@ -24,14 +24,25 @@ completed sequences over the window's seconds, tokens a second is printed
 beside it.  A traffic file of this kind states under ``rows`` the
 generator's parameters (``harness/seqgen.py``) and under ``replay`` what
 ``train`` files state.
+
+The window is whole passes over the replayed set (``distinct_batches``
+sequences), each pass in an order ``--seed`` picks: a step's cost follows
+the documents of its sequence (the attention cores visit only the blocks a
+document reaches), so a window that closes at a time trains another
+sample of the set in every run.  Set-up's steps come from the
+stream's first pass and the rest of that pass is skipped, so the window
+opens at a pass boundary; it holds the least number of passes whose steps,
+at the warm-up's step time, cover ``--seconds``.
 """
 
 from __future__ import annotations
 
 import gc
+import math
 import os
 import time
-from typing import Dict, List
+import zlib
+from typing import Dict, List, Tuple
 
 import numpy as np
 
@@ -50,6 +61,14 @@ def feed(ctx: Dict, cache, registry):
     counts tokens here)."""
     cfg = dict(ctx["cfg"], batch=ctx["cfg"]["sequences"])
     return train_cell.feed(dict(ctx, cfg=cfg), cache, registry)
+
+
+def window_plan(pass_steps: int, consumed: int, seconds: float,
+                step_s: float) -> Tuple[int, int]:
+    """``(skip, passes)``: the batches to skip after set-up's ``consumed``
+    so that the window opens at a pass boundary, and the least whole number
+    of passes whose steps, at ``step_s`` a step, cover ``seconds``."""
+    return -consumed % pass_steps, max(1, math.ceil(seconds / (pass_steps * step_s)))
 
 
 def compact_batches(views: List[Dict], union: Dict[str, np.ndarray]) -> List[Dict]:
@@ -145,31 +164,38 @@ def run(ctx: Dict) -> Dict:
             table_rows({k: now[k] for k in model.TABLES}, union, model, pad_to), tables0))
         del now
         mark("checked_steps")
+        t_warm = time.perf_counter()
         for _ in range(WARM_STEPS):
             loss = trainer.train_step(next(stream))
         jax.block_until_ready((loss, trainer.params))
+        step_s = (time.perf_counter() - t_warm) / WARM_STEPS
+        pass_steps = int(traffic["rows"]["distinct_batches"])
+        skip, passes = window_plan(pass_steps, CHECK_STEPS + WARM_STEPS,
+                                   seconds, step_s)
+        for _ in range(skip):
+            next(stream)                   # the rest of the first pass
+        trainer.flush_health()
+        before = trainer.telemetry.snapshot()["counters"]
         gc.collect()
         compiles.reset()
         mark("warm_up")
 
-        # -- the window ----------------------------------------------------
+        # -- the window: whole passes --------------------------------------
         trace_dir = (os.path.join(ctx["cache_root"], "trace", cell["name"])
                      if ctx["trace"] else None)
-        steps, wait_s = 0, 0.0
+        steps, wait_s, trained = passes * pass_steps, 0.0, []
         with common.profiled(trace_dir):
             t0 = time.perf_counter()
             setup_s = t0 - ctx["t_start"]
             with common.span("window"):
-                while True:
+                for _ in range(steps):
                     with common.span("next_batch"):
                         tw = time.perf_counter()
                         batch = next(stream)
                         wait_s += time.perf_counter() - tw
                     with common.span("train_step"):
                         loss = trainer.train_step(batch)
-                    steps += 1
-                    if time.perf_counter() - t0 >= seconds:
-                        break
+                    trained.append(zlib.crc32(np.ascontiguousarray(batch["fids"])))
                 with common.span("final_sync"):
                     jax.block_until_ready((loss, trainer.params))
             window_s = time.perf_counter() - t0
@@ -180,6 +206,9 @@ def run(ctx: Dict) -> Dict:
         stream.close()
     trainer.flush_health()
     counters = trainer.telemetry.snapshot()["counters"]
+    # the window's own held assignments, a step: the cost is the window's
+    held = sum(v - before.get(k, 0) for k, v in counters.items()
+               if k.startswith(MOE_HELD)) / steps
     reduced = common.read_trace(trace_dir) if trace_dir else None
 
     # -- the program's state goes, then the reference runs -----------------
@@ -197,9 +226,11 @@ def run(ctx: Dict) -> Dict:
     v = reference.verdict(numbers, dict(ctx["limits"], last_loss_finite=0.0))
     sequences = steps * cfg["sequences"]
     counted_steps = counters.get("trainer_seq_tokens_total", 0) / tokens_a_step
-    held = sum(v for k, v in counters.items() if k.startswith(MOE_HELD))
     extra = {"compiles_in_window": compiled_in_window, "setup_s": setup_s,
-             "setup_phases_s": phases, "steps": steps,
+             "setup_phases_s": phases, "steps": steps, "passes": passes,
+             "warm_step_s": step_s,
+             # each step's sequence, by a checksum of its token ids
+             "window_sequences": trained,
              "tokens_per_s": steps * tokens_a_step / window_s,
              "memory_in_use_bytes": mem["in_use"],
              # what the program counted, a step (all its steps, set-up's too)
@@ -232,8 +263,7 @@ def run(ctx: Dict) -> Dict:
         "peaks": peaks_for(ctx["device"]["kind"]),
         "counters": counters, "distinct_ids": seq["distinct_rows_per_step"],
         "cost": model.train_step_cost(
-            cfg, seq["distinct_rows_per_step"],
-            held / counted_steps if counted_steps else 0.0,
+            cfg, seq["distinct_rows_per_step"], held,
             seq["attended_pairs_per_step"]),
     }
     return common.attach_trace(out, reduced)

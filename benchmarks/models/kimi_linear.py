@@ -155,14 +155,21 @@ def init_params(cfg: Dict, key) -> Dict:
     same experts —, norm weights one, the selection bias zero, the embedding
     N(0, 1), all by index hash; KDA's decay as the public
     implementation draws it (``A_log = log U(1, 16)``, ``dt_bias`` the inverse
-    softplus of a step log-uniform in [1e-3, 1e-1]).  float32."""
+    softplus of a step log-uniform in [1e-3, 1e-1]).  float32.  They come
+    from ``cfg['weight_seed']``; ``key``, the run's ``--seed``, is not read:
+    the weights decide the routing, and the step's time follows the held
+    experts' load (3.2 us a held assignment; the rate spread 0.0043 over
+    nine draws, PERF.md section 6).  ``--seed`` then picks the replay's
+    order alone."""
     import math
 
     import jax
     import jax.numpy as jnp
 
+    from benchmarks.harness.common import prng_key
     from benchmarks.harness.initializers import hashed_normal
 
+    key = prng_key(cfg["weight_seed"])
     flat = {}
     for n, (path, (shape, rule)) in enumerate(sorted(_leaf_shapes(cfg).items())):
         k = jax.random.fold_in(key, n)
@@ -598,12 +605,15 @@ def build_trainer(cfg: Dict, params: Dict, mesh=None, shardings=None):
 def aot_trainer(cfg: Dict):
     """For ``tools/aot_step.py``: a trainer built on toy weights whose step is
     the real sizes' (its closure reads the model off ``logits_fn`` when the
-    step is built, and shapes when it is lowered)."""
+    step is built, and shapes when it is lowered).  The toy keeps the real
+    row width: the trainer fixes a table's layout (a fused store or not) and
+    its apply's row shape from the weights it is built on."""
     import jax
 
     from lightctr_tpu.models import kimi_linear
 
-    toy = dict(cfg, hidden=16, dim=8, vocab=64)
+    toy = dict(cfg, dim=8, vocab=64, moe_intermediate_size=8, num_experts=1,
+               intermediate_size=8)
     trainer = build_trainer(toy, init_params(toy, jax.random.PRNGKey(0)))
     trainer.logits_fn = kimi_linear.make_logits(spec_of(cfg))
     return trainer

@@ -53,6 +53,17 @@ def tiny_root(tmp: str) -> str:
     return tmp
 
 
+def whole_passes(runner, pass_steps: int) -> bool:
+    """A ``train_seq`` window opens at a pass boundary after set-up's steps
+    and holds a whole number of passes, whatever the step time."""
+    consumed = runner.CHECK_STEPS + runner.WARM_STEPS
+    for seconds, step_s in ((10.0, 1.6), (10.0, 0.3), (10.0, 0.01), (1.0, 5.0)):
+        skip, passes = runner.window_plan(pass_steps, consumed, seconds, step_s)
+        if (consumed + skip) % pass_steps or not 0 <= skip < pass_steps or passes < 1:
+            return False
+    return True
+
+
 def cpu_device(chips: int) -> dict:
     """In place of the harness's look for a chip (tests only)."""
     return {"platform": "cpu", "kind": "TPU v5 lite", "count": chips}

@@ -8,6 +8,7 @@ import pytest
 
 from benchmarks.harness import manifest as mf
 
+import helpers
 from helpers import BENCH, REPO
 
 MAN = mf.Manifest(REPO)
@@ -51,11 +52,15 @@ def test_config_file_states_its_sizes(entry):
 @pytest.mark.parametrize("cell", DOC["workloads"], ids=lambda w: w["name"])
 def test_cell_files_load(cell):
     traffic = MAN.traffic(cell["traffic"])
-    assert traffic["kind"] == "train"
+    assert traffic["kind"] in ("train", "train_seq")
     assert os.path.isfile(os.path.join(BENCH, "harness", traffic["kind"] + "_cell.py"))
     limits = MAN.cell_file(cell["name"])["limits"]
     assert limits and all(v >= 0 for v in limits.values())
-    assert traffic["rows"]["distinct_batches"] >= 64
+    if traffic["kind"] == "train":
+        assert traffic["rows"]["distinct_batches"] >= 64
+    else:
+        assert helpers.whole_passes(MAN.runner("train_seq"),
+                                    traffic["rows"]["distinct_batches"])
 
 
 @pytest.mark.parametrize("entry", DOC["per_layer"], ids=lambda m: m["name"])

@@ -86,7 +86,11 @@ def test_every_cells_files_load(cell):
                                        traffic["kind"] + "_cell.py"))
     limits = MAN.cell_file(cell["name"])["limits"]
     assert limits and all(v >= 0 for v in limits.values())
-    assert traffic["rows"]["distinct_batches"] >= 64
+    if traffic["kind"] == "train":
+        assert traffic["rows"]["distinct_batches"] >= 64
+    else:
+        assert helpers.whole_passes(MAN.runner("train_seq"),
+                                    traffic["rows"]["distinct_batches"])
 
 
 def test_the_configuration_is_the_catalogs_at_published_widths():

@@ -155,8 +155,7 @@ def test_no_step_no_reading():
 def test_the_manifest_holds_with_the_eight_entries():
     assert mf.validate(MAN) == []
     entries = [m for m in MAN.doc["per_layer"] if m["name"] in READERS]
-    assert [m["name"] for m in entries] == list(READERS)
-    assert [m["name"] for m in MAN.doc["per_layer"][-8:]] == list(READERS)
+    assert sorted(m["name"] for m in entries) == sorted(READERS)
     for m in entries:
         assert m["workloads"] == [w["name"] for w in MAN.doc["workloads"]]
         assert m["moves"] == "train_examples_per_s_per_chip"

@@ -31,8 +31,9 @@ def test_the_manifest_validates_with_the_new_entries():
     assert (cell["config"], cell["traffic"], cell["chips"]) == (
         "kimi-linear-48b-a3b-ep16", "train-packed8k", 1)
     assert MAN.workload("fm-train-zipf")["config"] == "criteo-fm-k64"
-    assert len(MAN.doc["workloads"]) == 5
-    assert sum(w["chips"] == 4 for w in MAN.doc["workloads"]) == 1
+    cells = MAN.doc["workloads"]
+    assert CELL in [w["name"] for w in cells]
+    assert sum(w["chips"] == 4 for w in cells) <= max(1, len(cells) // 4)
     per_layer = {m["name"] for m in MAN.metrics_for(CELL, "per_layer")}
     assert {"train_kda_device_share", "kda_scan_roofline", "train_moe_device_share",
             "moe_experts_roofline", "moe_expert_load_max_over_mean",
@@ -148,3 +149,19 @@ def test_a_program_without_the_model_fails_at_once(root):
                        timeout=300, env=dict(os.environ, JAX_PLATFORMS="cpu"))
     assert p.returncode != 0
     assert "cannot run 'kimi_linear'" in p.stderr and "nothing was run" in p.stderr
+
+
+def test_the_weights_come_from_the_config_and_not_from_the_seed():
+    """The routing the weights make sets the step's time, so the cell draws
+    them from ``weight_seed``; ``--seed`` (the key) picks the order alone."""
+    import jax
+
+    model = MAN.model("kimi_linear")
+    small = dict(CFG, hidden=16, dim=8, vocab=64, moe_intermediate_size=8, num_experts=2,
+                 intermediate_size=8, kv_lora_rank=8, qk_nope_head_dim=8,
+                 qk_rope_head_dim=8, v_head_dim=8, kda_gate_rank=8)
+    leaves = lambda cfg, k: jax.tree_util.tree_leaves(  # noqa: E731
+        model.init_params(cfg, jax.random.PRNGKey(k)))
+    assert all(np.array_equal(a, b) for a, b in zip(leaves(small, 1), leaves(small, 2)))
+    other = dict(small, weight_seed=small["weight_seed"] + 1)
+    assert not np.array_equal(leaves(small, 1)[0], leaves(other, 1)[0])

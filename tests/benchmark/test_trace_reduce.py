@@ -149,3 +149,76 @@ def test_union_merges_touching_and_nested():
 def test_a_trace_without_the_window_span_is_an_error():
     with pytest.raises(ValueError):
         tr.reduce_trace({"devices": {}, "spans": []})
+
+
+# -- scopes are matched over the whole name stack --------------------------------
+
+
+def label_only_seconds(reduced, pattern):
+    """The reader before it matched the whole stack: the label alone, which is
+    cut at 120 characters and keeps a transform's brackets as ``_``."""
+    import re
+
+    rx = re.compile(pattern)
+    ns = sum(tr.total(tr.union((s, s + d) for lab, _, s, d in evs if rx.search(lab)))
+             for evs in reduced["per_device"].values())
+    return ns / max(1, len(reduced["per_device"])) / 1e9
+
+
+def one_op(scope, name="fusion.1"):
+    trace = {"devices": {"/device:TPU:0": [(name, scope, 0, 100)]},
+             "spans": [("bench/window", 0, 100)]}
+    return tr.reduce_trace(trace)
+
+
+@pytest.mark.parametrize("scope, pattern, want_ns", [
+    # the primal forward: a transform closes round the scope's first part
+    ("jit(step)/jvp(seq/gqa)/full/while/body/dot_general", r"seq/gqa/full", 100),
+    ("jit(step)/transpose(jvp(jvp()))/checkpoint/seq/kda/seq/kda/scan/while", r"seq/kda/scan", 100),
+    # a scope past the label's 120 characters
+    ("jit(step)/" + "/".join(f"jit(a_long_function_{i:02d})" for i in range(6))
+     + "/seq/mla/attention/dot_general", r"seq/mla/attention", 100),
+    # another scope
+    ("jit(step)/jvp(seq/gqa)/window/dot_general", r"seq/gqa/full", 0),
+])
+def test_a_scope_is_found_in_the_whole_name_stack(scope, pattern, want_ns):
+    assert tr.scope_seconds(one_op(scope), pattern) == pytest.approx(want_ns * 1e-9)
+
+
+def test_an_instruction_name_still_matches():
+    assert tr.scope_seconds(one_op("jit(step)/add", "sort.3"), r"sort") == pytest.approx(1e-7)
+
+
+def test_a_scope_past_the_label_counts_on_the_phase_trace():
+    """``phase_trace.json``'s ``fusion.31`` sits under ``sparse_tables/apply``
+    past its label's 120 characters: the whole stack finds it, as the phase
+    split does (device 0: 100 + 80 + 50 ns; the label alone saw 150)."""
+    doc = json.load(open(os.path.join(HERE, "phase_trace.json")))
+    device0 = [tuple(e) for e in doc["devices"]["/device:TPU:0"]]
+    one = tr.reduce_trace({"devices": {"/device:TPU:0": device0},
+                           "spans": [tuple(s) for s in doc["spans"]]})
+    assert tr.scope_seconds(one, r"sparse_tables/apply") == pytest.approx(230e-9)
+    assert label_only_seconds(one, r"sparse_tables/apply") == pytest.approx(150e-9)
+
+
+CRITEO_CELLS = ("wd-train-zipf", "wd-x4-train-zipf", "fm-train-tail", "fm-train-zipf",
+                "wd-train-tail")
+
+
+@pytest.mark.parametrize("reader", ["sparse_apply_roofline", "train_sparse_device_share"])
+@pytest.mark.parametrize("cell", CRITEO_CELLS)
+def test_the_criteo_readers_read_the_recorded_trace_as_before(reader, cell, monkeypatch):
+    from benchmarks.harness.manifest import Manifest
+    from benchmarks.harness.peaks import peaks_for
+
+    man = Manifest(os.path.dirname(os.path.dirname(HERE)))
+    w = man.workload(cell)
+    cfg = man.config(w["config"])
+    ctx = {"reduced": tr.reduce_trace(recorded()), "steps": 3, "cfg": cfg,
+           "chips": w["chips"], "peaks": peaks_for("TPU v5 lite"),
+           "cost": man.model(cfg["model"]).train_step_cost(cfg, 45000.0)}
+    read = man.metric_reader(reader).read
+    now = read(ctx)
+    monkeypatch.setattr(tr, "scope_seconds", label_only_seconds)
+    before = read(ctx)
+    assert now is not None and now == pytest.approx(before, rel=1e-9, abs=1e-12)
