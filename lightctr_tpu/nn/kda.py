@@ -17,22 +17,24 @@ log-sum inside a chunk and ``S_0`` the state the chunk starts from, write
     O = Q+ S_0 + P N                                      P_ts = sum_c q_tc k_sc exp(G_tc - G_sc),  s <= t
     S_C = Diag(exp(G_C)) S_0 + (K exp(G_C - G))^T N       K+ = K exp(G),  Q+ = Q exp(G)
 
-Every exponent is a decay between two tokens of one chunk taken as a
-difference before ``exp``, so none is positive whatever the decay's
-strength (``K exp(-G)`` as a factor of its own overflows float32 at the
-published ``A_log`` range).  A document boundary is a mask: pairs of
-tokens of two documents drop out of ``A`` and ``P``, and ``S_0`` reaches
-only the tokens of the document the chunk began in.
+No exponent is positive whatever the decay's strength (``K exp(-G)`` as a
+factor of its own overflows float32 at the published ``A_log`` range):
+``A`` and ``P`` go by sub-blocks of ``SUB`` tokens, a difference before
+``exp`` inside one and a product through its first token across
+(:func:`_pairwise`); the others are decays from a chunk's start or to its
+end.  A document boundary is a mask: pairs of tokens of two documents drop
+out of ``A`` and ``P``, and ``S_0`` reaches only the tokens of the
+document the chunk began in.
 
-Three phases: ``A`` and ``P`` a chunk at a time (``lax.map``; the pairwise
-decay is ``[C, C, H, d_k]``, 67 MB a chunk at the published sizes, and is
-made again in the backward pass, never kept); one batched unit-triangular
-solve for all chunks at once, which does not need ``S_0`` (``U = T V``,
-``W = T K+`` with ``T = (I + Diag(beta) A)^-1 Diag(beta)``); then the
-``lax.scan`` over chunks that carries ``S``: three ``C x d x d`` products
-and ``P N`` a head.  The backward pass is autodiff through this form: the
-scan keeps ``S_0`` and ``N`` of every chunk (268 + 134 MB a layer at 8,192
-tokens, 32 heads).
+Three phases: ``A`` and ``P`` a chunk at a time (``lax.map``, made again
+in the backward pass, never kept; the decay inside the sub-blocks is
+``[C / SUB, SUB, SUB, H, d_k]``, 4.2 MB a chunk of 8 heads); one batched
+unit-triangular solve for all chunks at once, which does not need ``S_0``
+(``U = T V``, ``W = T K+`` with ``T = (I + Diag(beta) A)^-1 Diag(beta)``);
+then the ``lax.scan`` over chunks that carries ``S``: three ``C x d x d``
+products and ``P N`` a head.  The backward pass is autodiff through this
+form: the scan keeps ``S_0`` and ``N`` of every chunk (268 + 134 MB a
+layer at 8,192 tokens, 32 heads).
 """
 
 from __future__ import annotations
@@ -45,6 +47,7 @@ import jax.numpy as jnp
 from lightctr_tpu.utils.profiling import annotate
 
 CHUNK = 64
+SUB = 16
 
 
 def rms_norm(x: jax.Array, weight: jax.Array, eps: float) -> jax.Array:
@@ -73,14 +76,31 @@ def short_conv(x: jax.Array, w: jax.Array, seg: jax.Array) -> jax.Array:
 def _pairwise(q, k, G, same):
     """One chunk's ``A`` (strictly lower) and ``P`` (lower, diagonal
     included), ``[H, C, C]`` each: ``q``, ``k``, ``G`` ``[C, H, d_k]``,
-    ``same`` ``[C, C]`` (the two tokens share a document)."""
-    c = q.shape[0]
+    ``same`` ``[C, C]`` (the two tokens share a document).
+
+    By sub-blocks of ``SUB`` tokens.  Inside one, the decay is a difference
+    before ``exp``; a column ``s`` before sub-block ``b``'s first token
+    ``f`` meets row ``t`` of it through ``f``:
+    ``exp(G_t - G_s) = exp(G_t - G_f) exp(G_f - G_s)``, one matrix product
+    a head, and both exponents are <= 0 since ``G`` does not increase."""
+    c, heads, dk = q.shape
+    sub = SUB if c % SUB == 0 else c
+    nb = c // sub
+    qb, kb, Gb = (x.reshape(nb, sub, heads, dk) for x in (q, k, G))
+    rows = jnp.stack([kb, qb])                             # A's rows, P's
+    i, j = jnp.arange(sub)[:, None], jnp.arange(sub)[None, :]
+    decay = jnp.exp(jnp.where((j <= i)[None, :, :, None, None],
+                              Gb[:, :, None] - Gb[:, None, :], -jnp.inf))
+    inside = jnp.einsum("xbthc,bshc,btshc->xbhts", rows, kb, decay)
+    first = Gb[:, :1]                                      # [nb, 1, H, dk]: G at f
+    before = jnp.arange(c)[None, :] < (jnp.arange(nb) * sub)[:, None]   # [nb, C]: s < f
+    cols = k * jnp.exp(jnp.where(before[:, :, None, None], first - G, -jnp.inf))
+    outside = jnp.einsum("xbthc,bshc->xbhts", rows * jnp.exp(Gb - first), cols)
+    on_diag = jnp.eye(nb, dtype=q.dtype)[:, None, None, :, None]
+    whole = outside + (inside[..., None, :] * on_diag).reshape(2, nb, heads, sub, c)
+    a, p = whole.transpose(0, 2, 1, 3, 4).reshape(2, heads, c, c)
     t, s = jnp.arange(c)[:, None], jnp.arange(c)[None, :]
-    keep = (same & (s <= t))[:, :, None, None]
-    decay = jnp.exp(jnp.where(keep, G[:, None] - G[None, :], -jnp.inf))
-    a = jnp.einsum("thc,shc,tshc->hts", k, k, decay)
-    p = jnp.einsum("thc,shc,tshc->hts", q, k, decay)
-    return a * (s < t), p
+    return a * (same & (s < t)), p * (same & (s <= t))
 
 
 def _delta_rule_one(q, k, v, g, beta, seg, chunk):

@@ -80,6 +80,30 @@ _WORKER = textwrap.dedent(
         f.write(str(ops_port))
     os.replace(pf + ".tmp", pf)
 
+    held = set()
+
+    class LevelStartClient(HierExchangeClient):
+        # rounds 0 and 1 compile the step's two programs, which takes each
+        # host its own time: hold a host's first push of those rounds until
+        # the other host is there too, so the one straggler the timeline
+        # can name is the planted sleeper
+        def _hold(self, epoch):
+            if epoch in (0, 1) and epoch not in held:
+                held.add(epoch)
+                open(os.path.join(run_dir, "at_%d_%d" % (epoch, host_id)),
+                     "w").close()
+                other = os.path.join(run_dir, "at_%d_%d" % (epoch, 1 - host_id))
+                while not os.path.exists(other):
+                    time.sleep(0.002)
+
+        def push_async(self, table, uids, rows, epoch, exact=False):
+            self._hold(epoch)
+            return super().push_async(table, uids, rows, epoch, exact)
+
+        def push_group_async(self, tables, uids, rows_list, epoch):
+            self._hold(epoch)
+            return super().push_group_async(tables, uids, rows_list, epoch)
+
     rng = np.random.default_rng(host_id)
     fids = rng.integers(1, 256, size=(64, 4)).astype(np.int32)
     batch = {
@@ -89,7 +113,7 @@ _WORKER = textwrap.dedent(
         "labels": (np.arange(64) % 2).astype(np.float32),
     }
     params = fm.init(jax.random.PRNGKey(0), 256, 4)
-    client = HierExchangeClient(
+    client = LevelStartClient(
         [("127.0.0.1", port)], host_id=host_id, n_hosts=2,
         pull_timeout_s=300.0)
     tr = SparseTableCTRTrainer(

@@ -90,12 +90,13 @@ def test_chunked_kda_is_the_token_recurrence(ref, chunk):
     np.testing.assert_allclose(got, want, atol=2e-6)
 
 
-def test_chunked_kda_gradients_are_the_recurrences(ref):
+@pytest.mark.parametrize("chunk", [16, 64])
+def test_chunked_kda_gradients_are_the_recurrences(ref, chunk):
     x = kda_inputs(jax.random.PRNGKey(1))
     w = jax.random.normal(jax.random.PRNGKey(2), (T, 2, 16))
     seg = jnp.asarray(SEG)
     got = jax.grad(lambda *a: jnp.sum(w * kda.chunked_delta_rule(
-        *(z[None] for z in a), seg[None], chunk=16)[0]), argnums=range(5))(*x)
+        *(z[None] for z in a), seg[None], chunk=chunk)[0]), argnums=range(5))(*x)
     want = jax.grad(lambda *a: jnp.sum(w * ref._recurrence(*a, seg)),
                     argnums=range(5))(*x)
     for g, r in zip(got, want):
@@ -103,15 +104,67 @@ def test_chunked_kda_gradients_are_the_recurrences(ref):
         np.testing.assert_allclose(g, r, atol=5e-6)
 
 
-def test_a_decay_too_strong_for_a_factored_form_stays_finite(ref):
+@pytest.mark.parametrize("chunk", [16, 64])
+def test_a_decay_too_strong_for_a_factored_form_stays_finite(ref, chunk):
     """exp(-G) over a chunk overflows float32 at the published A_log range;
-    the pairwise form takes differences first."""
+    the pairwise form takes differences first, or factors through a
+    sub-block's first token, where no exponent is positive."""
     q, k, v, g, beta = kda_inputs(jax.random.PRNGKey(4), scale=40.0)
     got = kda.chunked_delta_rule(*(a[None] for a in (q, k, v, g, beta)),
-                                 SEG[None], chunk=16)[0]
+                                 SEG[None], chunk=chunk)[0]
     assert np.all(np.isfinite(got))
     np.testing.assert_allclose(got, ref._recurrence(q, k, v, g, beta, jnp.asarray(SEG)),
                                atol=2e-6)
+
+
+def pairs_by_differences(q, k, G, same):
+    """One chunk's ``A`` and ``P`` from the whole pairwise decay
+    ``[C, C, H, d_k]``, each exponent a difference before ``exp``."""
+    c = q.shape[0]
+    t, s = jnp.arange(c)[:, None], jnp.arange(c)[None, :]
+    keep = (same & (s <= t))[:, :, None, None]
+    decay = jnp.exp(jnp.where(keep, G[:, None] - G[None, :], -jnp.inf))
+    a = jnp.einsum("thc,shc,tshc->hts", k, k, decay)
+    p = jnp.einsum("thc,shc,tshc->hts", q, k, decay)
+    return a * (s < t), p
+
+
+@pytest.mark.parametrize("scale", [0.1, 40.0], ids=["weak", "strong"])
+@pytest.mark.parametrize("chunk", [16, 64])
+@pytest.mark.parametrize("lengths", [
+    (13, 30, 50, 35),                        # boundaries inside sub-blocks
+    (16, 32, 48, 32),                        # on sub-block edges
+    (64, 64),                                # on the chunk edge
+    (20, 1, 43, 1, 63),                      # one-token documents
+    (128,),                                  # a chunk that holds one document
+], ids=["inside", "sub_block_edges", "chunk_edge", "one_token", "one_doc"])
+def test_blocked_pairs_are_the_difference_form(lengths, chunk, scale):
+    """``A`` and ``P`` by sub-blocks of 16 are those of the whole pairwise
+    decay, over 128 tokens cut as ``_delta_rule_one`` cuts them; at chunk 16
+    only the diagonal form runs."""
+    seg = np.repeat(np.arange(len(lengths)), lengths).astype(np.int32)
+    ks = jax.random.split(jax.random.PRNGKey(11), 3)
+    q = kda.l2_norm(jax.random.normal(ks[0], (128, 2, 16)))
+    k = kda.l2_norm(jax.random.normal(ks[1], (128, 2, 16)))
+    g = -jax.nn.softplus(jax.random.normal(ks[2], (128, 2, 16))) * scale
+    g = jnp.where((seg != np.pad(seg, (1, 0), constant_values=-1)[:-1])[:, None, None],
+                  0.0, g)
+
+    def chunks(x):
+        return jnp.asarray(x).reshape((-1, chunk) + x.shape[1:])
+
+    sc = chunks(seg)
+    args = (chunks(q), chunks(k), jnp.cumsum(chunks(g), axis=1),
+            sc[:, :, None] == sc[:, None, :])
+    got = jax.jit(jax.vmap(kda._pairwise))(*args)
+    want = jax.jit(jax.vmap(pairs_by_differences))(*args)
+    for x, y in zip(got, want):
+        assert np.all(np.isfinite(x))
+        np.testing.assert_allclose(x, y, atol=1e-6)
+    t, s = np.arange(chunk)[:, None], np.arange(chunk)[None, :]
+    across = np.asarray(args[3]) & (t // 16 > s // 16)     # pairs of two sub-blocks
+    if scale < 1 and across.any():           # ... weigh in the weak decay
+        assert float(jnp.max(jnp.abs(want[0]) * across[:, None])) > 1e-3
 
 
 def test_state_and_convolution_stop_at_a_document_boundary():
